@@ -12,8 +12,8 @@ Before anything is timed, each case's halo forces are checked against the
 single-device reference schedule on the same positions — a benchmark that
 silently drifted from the oracle would be worse than no benchmark.
 
-On emulated host devices (``--devices N`` respawns the process with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``) all shards share
+On emulated host devices (``--devices N`` asks the CPU backend for N
+devices through ``XLA_FLAGS`` before JAX is imported) all shards share
 one physical core, so absolute efficiency is pessimistic — the committed
 ``benchmarks/BENCH_halo.json`` is the *record structure* the perf
 trajectory tracks per commit, not a hardware claim. On a real mesh the
@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import argparse
 import os
-import subprocess
+import re
 import sys
 from typing import List, Optional, Sequence
 
@@ -118,11 +118,25 @@ def run(csv: bool = True, json_path: Optional[str] = None,
     return rows
 
 
+def host_device_flags(devices: int, xla_flags: str) -> str:
+    """``XLA_FLAGS`` asking the CPU backend for ``devices`` emulated
+    devices (any earlier count is replaced).
+
+    XLA reads the flag once, when JAX starts, so it is set before JAX is
+    imported, in this process: nothing is re-executed, and on a TPU host
+    the flag only shapes the unused CPU backend while the chips are the
+    devices."""
+    kept = re.sub(r"\s*--xla_force_host_platform_device_count=\S*", "",
+                  xla_flags).strip()
+    return (kept + f" --xla_force_host_platform_device_count={devices}"
+            ).strip()
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=0,
-                    help="emulated host devices (respawns the process with "
-                         "XLA_FLAGS; 0 = use the devices already visible)")
+                    help="shard counts up to this many devices (emulated "
+                         "host devices on the CPU; 0 = the devices visible)")
     ap.add_argument("--division", type=int, default=6,
                     help="cells per axis of one shard's slab")
     ap.add_argument("--ppc", type=int, default=4, help="particles per cell")
@@ -133,21 +147,16 @@ def main(argv=None):
 
     shard_counts = None
     if args.devices:
+        if "jax" not in sys.modules:
+            os.environ["XLA_FLAGS"] = host_device_flags(
+                args.devices, os.environ.get("XLA_FLAGS", ""))
         import jax
 
         if jax.device_count() < args.devices:
-            # too late to grow this process's device set: respawn with the
-            # flag in place and without --devices (so the child runs)
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                                f" --xla_force_host_platform_device_count="
-                                f"{args.devices}")
-            cmd = [sys.executable, "-m", "benchmarks.fig_halo",
-                   "--division", str(args.division), "--ppc", str(args.ppc),
-                   "--strategy", args.strategy]
-            if args.json:
-                cmd += ["--json", args.json]
-            raise SystemExit(subprocess.run(cmd, env=env).returncode)
+            raise SystemExit(
+                f"fig_halo: --devices {args.devices} but "
+                f"{jax.device_count()} {jax.default_backend()} device(s) "
+                "are visible (JAX was started before the flag could be set)")
         # more devices visible than asked for: honour the request anyway
         # by capping the sweep instead of silently using them all
         shard_counts = _viable_shard_counts(args.devices)

@@ -48,8 +48,7 @@ def main():
             layout = ("dense" if supports_layout(backend, strategy, "dense")
                       else "sfc")
             p = plan(domain, kernel, m_c=auto.m_c, strategy=strategy,
-                     backend=backend, layout=layout, positions=positions,
-                     interpret=True)
+                     backend=backend, layout=layout, positions=positions)
             forces, pot = p.execute(state)
             err = float(jnp.max(jnp.abs(forces - f_ref))) / fscale
             tag = strategy if layout == "dense" else f"{strategy}/{layout}"

@@ -322,6 +322,20 @@ class InteractionPlan:
     def __call__(self, state: ParticleState) -> Tuple[Array, Array]:
         return self.execute(state)
 
+    def compile(self, state: ParticleState):
+        """Lower and compile the executor :meth:`execute` runs for
+        ``state``'s structure and shapes; returns the
+        ``jax.stages.Compiled`` (``.as_text()`` shows the program, with a
+        ``tpu_custom_call`` per Pallas kernel on a TPU). A lowering or
+        compile error raises here. ``execute`` reuses the executable."""
+        return compile_ahead(_executor(self, tuple(sorted(state.fields))),
+                             state)
+
+    def compile_batch(self, states: ParticleState):
+        """:meth:`compile` for the :meth:`execute_batch` executor."""
+        return compile_ahead(
+            _batch_executor(self, tuple(sorted(states.fields))), states)
+
     # -- M_C safety net ----------------------------------------------------
 
     def check_overflow(self, state: ParticleState) -> bool:
@@ -503,8 +517,10 @@ class InteractionPlan:
                         max_retries: Optional[int] = None,
                         sleep=None
                         ) -> Tuple[Tuple[Array, Array], "ExecutionReport"]:
-        """Guarded execute: never raises, always terminates, and tells you
-        what happened. Returns ``((forces, potential), report)`` where the
+        """Guarded execute: absorbs every runtime fault, always
+        terminates, and tells you what happened. A lowering or compile
+        error is not a fault: it raises (:func:`compile_ahead`). Returns
+        ``((forces, potential), report)`` where the
         :class:`ExecutionReport` carries the overflow class, the
         non-finite output count (one fused ``jnp.isfinite`` reduction),
         the out-of-domain particle count, and the degradation-ladder /
@@ -816,6 +832,11 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
         # fail at plan time, not execute time (halo validates the
         # per-shard backend the slab schedule will actually dispatch to)
         get_backend(inner_backend, strategy, layout)
+        if inner_backend == "pallas":
+            # a kernel whose staging cannot fit VMEM/SMEM is refused here,
+            # never left to fail (or be degraded around) at execute time
+            from ..kernels import check_budget
+            check_budget(p)
     return p
 
 
@@ -1129,10 +1150,41 @@ _executor = _LRU(128, _build_executor)
 _batch_executor = _LRU(32, _build_batch_executor)
 
 
+_AHEAD: "collections.OrderedDict" = collections.OrderedDict()
+_AHEAD_MAX = 256
+
+
+def compile_ahead(fn: Callable, *args):
+    """Lower and compile the jitted ``fn`` for ``args``' shapes before it
+    runs; returns the ``jax.stages.Compiled`` executable.
+
+    The guarded loops (``execute_checked``, trajectory segments, serving
+    dispatch) call this before each run and let its errors through their
+    fault handlers. A lowering or compile error is a defect of the
+    program on this platform — a kernel Mosaic refuses, a program that
+    does not fit the device — not a runtime fault, so it raises to the
+    caller instead of being absorbed by the degradation ladder. Memoized
+    per (fn, argument structure and
+    shapes); jit shares the executable, so the call that follows does not
+    compile again."""
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    key = (fn, tree, tuple((getattr(x, "shape", ()),
+                            str(getattr(x, "dtype", type(x))))
+                           for x in leaves))
+    compiled = _AHEAD.get(key)
+    if compiled is None:
+        compiled = fn.lower(*args).compile()
+        _AHEAD[key] = compiled
+        while len(_AHEAD) > _AHEAD_MAX:
+            _AHEAD.popitem(last=False)
+    return compiled
+
+
 def clear_executor_cache() -> None:
     """Drop every cached executor trace (single and batched)."""
     _executor.cache_clear()
     _batch_executor.cache_clear()
+    _AHEAD.clear()
 
 
 def set_executor_cache_size(single: Optional[int] = None,
@@ -1160,10 +1212,10 @@ def executor_cache_info() -> Dict[str, "_CacheInfo"]:
 # --------------------------------------------------------------------------
 
 # The resilience layer's core contract: ``plan.execute_checked`` never
-# raises and never hangs. Failures (transient backend errors, non-finite
-# outputs, injected chaos — repro.testing.chaos) are absorbed by a
-# per-plan circuit breaker with hysteresis: _FAILURE_THRESHOLD consecutive
-# failures step one rung DOWN the degradation ladder
+# hangs and absorbs every runtime fault. Failures (transient backend
+# errors, non-finite outputs, injected chaos — repro.testing.chaos) are
+# absorbed by a per-plan circuit breaker with hysteresis:
+# _FAILURE_THRESHOLD consecutive failures step one rung DOWN the ladder
 # (pallas -> reference backend, then packed -> compact -> dense layout);
 # _RECOVERY_THRESHOLD consecutive clean executions step one rung back UP.
 # Every rung is bit-identical to the healthy path by construction (the
@@ -1366,6 +1418,7 @@ def _execute_checked_impl(base: InteractionPlan, state: ParticleState, *,
     forces = pot = None
     while True:
         rung = rungs[level]
+        compiling = False
         try:
             if sleep is None:
                 chaos.maybe_delay("core.dispatch")
@@ -1374,6 +1427,9 @@ def _execute_checked_impl(base: InteractionPlan, state: ParticleState, *,
             if rung._multi_shard:
                 chaos.maybe_raise("dist.exchange")
             chaos.maybe_raise("core.dispatch")
+            compiling = True
+            rung.compile(state)
+            compiling = False
             f, u = rung.execute(state)
             f = chaos.corrupt("core.dispatch", f)
             bad, ood = _output_check(f, u, state.positions, state.valid,
@@ -1406,6 +1462,10 @@ def _execute_checked_impl(base: InteractionPlan, state: ParticleState, *,
                            layout=rungs[level].layout, fault=str(e))
         except (chaos.TransientBackendError, _NonFiniteOutput,
                 RuntimeError, ValueError) as e:
+            if compiling:
+                # a program the compiler refuses is a defect, not a
+                # runtime fault: raise it, never degrade around it
+                raise
             report.faults.append(f"{type(e).__name__}: {e}")
             if health.note_failure(len(rungs)):
                 report.breaker_trips += 1

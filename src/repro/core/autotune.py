@@ -802,6 +802,16 @@ def tune(domain: Domain, kernel: Optional[PairKernel] = None,
     for cand in kept:
         try:
             p = cand.plan(domain, kernel, interpret)
+        except ValueError as e:       # refused by design (cannot fit)
+            print(f"autotune: candidate {cand} refused: {e}",
+                  file=sys.stderr)
+            _obs_event("autotune.candidate_refused", backend=cand.backend,
+                       strategy=cand.strategy, layout=cand.layout)
+            continue
+        # a compile error is a defect of the program, not a slow candidate:
+        # it raises instead of quietly handing the win to another backend
+        p.compile(state)
+        try:
             _obs_metrics.registry.counter(
                 TIMING_RUNS_TOTAL, backend=cand.backend,
                 strategy=cand.strategy, layout=cand.layout).inc()

@@ -43,8 +43,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.binning import (EMPTY_POS, bin_particles, build_sfc_clusters,
                             cell_counts, pack_rows, sfc_pair_count,
@@ -231,12 +230,17 @@ def halo_impl(plan):
             gather_idx, pos_part, fields_part = H.partition_by_shard(
                 dom, state.positions, state.fields, n_shards, cap)
         in_specs = (P(axis), {k: P(axis) for k in fields_part})
-        sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=(P(axis), P(axis)), check_rep=False)
+        sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                out_specs=(P(axis), P(axis)),
+                                check_vma=False)
         with _obs_trace("dist.shard_dispatch", phase="trace",
                         n_shards=n_shards, strategy=plan.strategy,
                         layout=plan.layout):
             f_part, pot_part = sharded(pos_part, fields_part)
+        # the scatter-back reads every shard's rows: replicate them first
+        # (explicit-axis meshes, jax.make_mesh's default, require it)
+        f_part, pot_part = jax.sharding.reshard(
+            (f_part, pot_part), NamedSharding(mesh, P()))
         forces = H.scatter_from_shards(gather_idx, n, f_part)
         pot = H.scatter_from_shards(gather_idx, n, pot_part)
         return forces, pot

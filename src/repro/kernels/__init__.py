@@ -17,17 +17,66 @@ oracles, so
 
 runs the Pallas X-pencil kernel (natively on TPU, interpret mode elsewhere)
 through exactly the API users already select strategies with.
+
+Each kernel stages what one grid step needs in VMEM and its scalar tables
+in SMEM; :func:`kernel_budget` says how much for a plan, and ``plan()``
+refuses a pallas plan that cannot fit the chip (:func:`check_budget`).
 """
 
+from typing import Tuple
+
 from ..core.api import InteractionPlan, ParticleState, register_backend
-from ..core.binning import CellBins, PackedRows, SfcClusters
+from ..core.binning import (DEFAULT_CSIZE, CellBins, PackedRows, SfcClusters,
+                            sfc_cluster_tables)
+from ..core.domain import slab_domain
+from . import _lanes
+from ._platform import resolve_interpret
+from .allin import allin_vmem_bytes
 from .ops import (allin_interactions, cell_sfc_interactions, prefix_sum,
                   window_attention, xpencil_interactions,
                   xpencil_packed_interactions, xpencil_sparse_interactions)
+from .sfc import sfc_budget
+from .xpencil import packed_vmem_bytes, xpencil_vmem_bytes
 
-__all__ = ["allin_interactions", "cell_sfc_interactions", "prefix_sum",
-           "window_attention", "xpencil_interactions",
-           "xpencil_packed_interactions", "xpencil_sparse_interactions"]
+__all__ = ["allin_interactions", "cell_sfc_interactions", "check_budget",
+           "kernel_budget", "prefix_sum", "window_attention",
+           "xpencil_interactions", "xpencil_packed_interactions",
+           "xpencil_sparse_interactions"]
+
+
+def kernel_budget(plan: InteractionPlan) -> Tuple[str, int, int]:
+    """``(kernel name, VMEM bytes per grid step, SMEM bytes)`` of the
+    Pallas kernel a pallas (or pallas-inner halo) plan dispatches to,
+    for the domain one shard runs on."""
+    dom = plan.domain
+    if plan.backend == "halo" and (plan.n_shards or 1) > 1:
+        dom = slab_domain(dom, plan.n_shards)
+    nx, ny, nz = dom.ncells
+    m_c = plan.m_c
+    if plan.layout == "sfc":
+        n_clusters = sfc_cluster_tables(dom, DEFAULT_CSIZE).n_clusters
+        n_pcells = (nx + 2) * (ny + 2) * (nz + 2)
+        vmem, smem = sfc_budget(n_pcells, n_clusters, DEFAULT_CSIZE, m_c,
+                                plan.pair_cap)
+        return "sfc", vmem, smem
+    if plan.layout == "packed":
+        rows = plan.max_active if plan.compact else nz * ny
+        return ("xpencil_packed",
+                packed_vmem_bytes(nx, m_c, plan.row_cap), 4 * rows)
+    if plan.strategy == "allin":
+        return "allin", allin_vmem_bytes(plan.box, m_c), 0
+    if plan.compact:
+        return "xpencil_compact", xpencil_vmem_bytes(nx, m_c), \
+            4 * plan.max_active
+    return "xpencil", xpencil_vmem_bytes(nx, m_c), 0
+
+
+def check_budget(plan: InteractionPlan) -> None:
+    """Raise ``ValueError`` naming the VMEM or SMEM limit when the plan's
+    Pallas kernel runs natively and cannot fit the chip's memories (the
+    interpreter stages nothing in VMEM, so it is never refused)."""
+    if not resolve_interpret(plan.interpret):
+        _lanes.check_budget(*kernel_budget(plan))
 
 
 # -- plan/execute backend registration (normalized signature) ---------------

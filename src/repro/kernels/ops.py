@@ -17,7 +17,7 @@ import numpy as np
 from ..core.binning import (EMPTY_POS, CellBins, PackedRows, SfcClusters,
                             dense_to_particles, full_pencil_occupancy,
                             packed_to_particles, pencil_occupancy,
-                            sfc_cluster_tables, sfc_slot_tables,
+                            sfc_cluster_tables,
                             sfc_to_particles)
 from ..core.domain import Domain
 from ..core.interactions import PairKernel
@@ -111,49 +111,38 @@ def cell_sfc_interactions(domain: Domain, sfc: SfcClusters,
     """SFC cluster-pair kernel -> per-particle (forces (N,3), potential (N,)).
 
     Gathers the cluster target tiles (plus one all-sentinel ghost row the
-    pair-list padding decodes to), stages the flattened padded planes with
-    one appended sentinel cell, and runs the compressed-pair-list Pallas
-    kernel (``kernels.sfc``). Clusters with no kept pair are never visited
-    by the grid, so their output rows are explicitly zeroed from the kept
-    mask before scattering back to particle order — identical to the
-    reference runner, whose fully-masked stencil terms accumulate exact
-    (+0.0) zeros.
+    pair-list padding decodes to), stages the padded planes as cell rows
+    with one appended sentinel cell, and runs the compressed-pair-list
+    Pallas kernel (``kernels.sfc``). Clusters with no kept pair are never
+    visited by the grid, so their output rows are explicitly zeroed from
+    the kept mask before scattering back to particle order — identical to
+    the reference runner, whose fully-masked stencil terms accumulate
+    exact (+0.0) zeros.
     """
     bins = sfc.bins
     m_c, csize = bins.m_c, sfc.csize
     tables = sfc_cluster_tables(domain, csize, sfc.curve)
-    tgt_base, src_base = sfc_slot_tables(domain, m_c, csize, sfc.curve)
     n_clusters = tables.n_clusters
-    total = bins.slot_id.size
-    tile_w = csize * m_c
+    n_pcells = bins.slot_id.size // m_c
 
-    def ext(plane: Array, fill) -> Array:   # flatten + one sentinel cell
-        flat = plane.reshape(-1)
-        return jnp.concatenate(
-            [flat, jnp.full((m_c,), fill, flat.dtype)])[None, :]
+    def cell_rows(plane: Array, fill) -> Array:   # + one sentinel cell
+        return jnp.concatenate([plane.reshape(n_pcells, m_c),
+                                jnp.full((1, m_c), fill, plane.dtype)])
 
-    flats = {"x": ext(bins.planes["x"], EMPTY_POS),
-             "y": ext(bins.planes["y"], EMPTY_POS),
-             "z": ext(bins.planes["z"], EMPTY_POS),
-             "id": ext(bins.slot_id, -1)}
+    rows = {"x": cell_rows(bins.planes["x"], EMPTY_POS),
+            "y": cell_rows(bins.planes["y"], EMPTY_POS),
+            "z": cell_rows(bins.planes["z"], EMPTY_POS),
+            "id": cell_rows(bins.slot_id, -1)}
 
-    rank = jnp.arange(m_c, dtype=jnp.int32)
-    tidx = (jnp.asarray(tgt_base)[:, :, None] + rank).reshape(
-        n_clusters, tile_w)
+    def tile(r: Array) -> Array:    # (n_clusters + 1, m_c, csize) + ghost
+        t = jnp.concatenate([jnp.asarray(tables.tgt_pcell),
+                             jnp.full((1, csize), n_pcells, jnp.int32)])
+        return jnp.swapaxes(r[t], 1, 2)
 
-    def tile(flat: Array, fill) -> Array:   # gather tiles + ghost row
-        rows = flat[0][tidx]
-        ghost = jnp.full((1, tile_w), fill, rows.dtype)
-        return jnp.concatenate([rows, ghost], axis=0)
-
-    tiles = {"x": tile(flats["x"], EMPTY_POS),
-             "y": tile(flats["y"], EMPTY_POS),
-             "z": tile(flats["z"], EMPTY_POS),
-             "id": tile(flats["id"], -1)}
-
-    src_off = np.concatenate(
-        [np.asarray(src_base).reshape(-1),
-         np.full((27 * csize,), total, np.int32)]).astype(np.int32)
+    tiles = {f: tile(r) for f, r in rows.items()}
+    src_cell = np.concatenate(
+        [np.asarray(tables.src_pcell).reshape(-1),
+         np.full((27 * csize,), n_pcells, np.int32)]).astype(np.int32)
 
     codes = sfc.codes.astype(jnp.int32)
     first = jnp.concatenate(
@@ -161,14 +150,16 @@ def cell_sfc_interactions(domain: Domain, sfc: SfcClusters,
          ((codes[1:] >> 5) != (codes[:-1] >> 5)).astype(jnp.int32)])
 
     fx, fy, fz, pot = cell_sfc_forces(
-        tiles, flats, codes, first, jnp.asarray(src_off), csize=csize,
+        tiles, rows, codes, first, jnp.asarray(src_cell), csize=csize,
         m_c=m_c, kernel=kernel, cutoff2=float(domain.cutoff) ** 2,
         interpret=_interpret(interpret))
 
     kept = jnp.zeros((n_clusters + 1,), jnp.int32).at[codes >> 5].add(1)
     has = (kept[:n_clusters] > 0)[:, None]
-    fx, fy, fz, pot = (jnp.where(has, o[:n_clusters], 0.0)
-                       for o in (fx, fy, fz, pot))
+    fx, fy, fz, pot = (
+        jnp.where(has, jnp.swapaxes(o[:n_clusters], 1, 2).reshape(
+            n_clusters, csize * m_c), 0.0)
+        for o in (fx, fy, fz, pot))
     return sfc_to_particles(domain, sfc, fx, fy, fz, pot)
 
 

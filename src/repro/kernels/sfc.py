@@ -15,19 +15,23 @@ list** of the SFC layout (``binning.SfcClusters``) directly:
     association of the dense Par-Cell sweep, which is what makes the
     kernel bit-identical to ``cell_dense`` (see strategies.cell_sfc).
 
-  Source staging: the padded SoA planes are staged whole (flattened, plus
-  one appended always-empty sentinel cell); per stencil slot k and cluster
-  cell j, the scalar-prefetched slot-offset table gives the flat base of
-  the k-shifted cell and a dynamic ``pl.ds`` slice reads its ``m_c`` slots
-  from the staged block — the cluster-tile-from-shared-memory evaluation
-  of the CSCS follow-up. Sentinel pair codes (pair-list padding) decode to
-  the ghost cluster row, whose targets and sources are all sentinels, so
-  they accumulate exact zeros and the row is stripped by the wrapper.
+  Source staging: the padded SoA planes are staged whole as cell rows
+  (``(n_pcells + 1, m_c)``: one row per padded cell, plus one appended
+  always-empty sentinel cell); per stencil slot k and cluster cell j, the
+  scalar-prefetched table gives the row of the k-shifted cell and a
+  dynamic ``pl.ds`` row read fetches its ``m_c`` slots from the staged
+  block — the cluster-tile-from-shared-memory evaluation of the CSCS
+  follow-up. Target tiles arrive transposed (``(m_c, csize)``: slot on
+  sublanes), so cell j's targets are a column that meets the source row
+  by broadcasting alone. Sentinel pair codes (pair-list padding) decode
+  to the ghost cluster row, whose targets and sources are all sentinels,
+  so they accumulate exact zeros and the row is stripped by the wrapper.
 
-VMEM note: staging the whole padded planes costs ``4 * total`` floats —
-fine at the repo's benchmark scales (a division-12 box at m_c=16 is
-~700 KB); a production-scale TPU variant would DMA per-cluster halo tiles
-instead. Interpret mode (CPU tests) is unaffected.
+Memory: the staged planes occupy VMEM whole and the cell table lives in
+SMEM (``sfc_budget``), so the kernel serves small and medium boxes; a
+box whose staging would overflow either memory is refused when it is
+planned. A production-scale TPU variant would DMA per-cluster halo tiles
+instead.
 """
 
 from __future__ import annotations
@@ -41,14 +45,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.interactions import PairKernel
+from ._lanes import compiler_params, pair_terms, tile_bytes
 from ._platform import resolve_interpret
 
 Array = jnp.ndarray
 
 
-def _sfc_kernel(codes_ref, first_ref, off_ref,       # scalar-prefetched
+def sfc_budget(n_pcells: int, n_clusters: int, csize: int, m_c: int,
+               pair_cap: int) -> Tuple[int, int]:
+    """(VMEM, SMEM) bytes of one SFC kernel call: the four staged cell-row
+    planes (double-buffered) plus target/output tiles, and the
+    scalar-prefetched codes/first/cell tables."""
+    vmem = (2 * 4 * tile_bytes(n_pcells + 1, m_c)
+            + 2 * 8 * tile_bytes(m_c, csize)
+            + 16 * tile_bytes(m_c, m_c))
+    smem = 4 * (2 * pair_cap + (n_clusters + 1) * 27 * csize)
+    return vmem, smem
+
+
+def _sfc_kernel(codes_ref, first_ref, cell_ref,      # scalar-prefetched
                 xt_ref, yt_ref, zt_ref, it_ref,      # target cluster tile
-                xs_ref, ys_ref, zs_ref, is_ref,      # staged flat planes
+                xs_ref, ys_ref, zs_ref, is_ref,      # staged cell rows
                 fx_ref, fy_ref, fz_ref, pot_ref,
                 *, csize: int, m_c: int, kernel: PairKernel,
                 cutoff2: float):
@@ -56,92 +73,77 @@ def _sfc_kernel(codes_ref, first_ref, off_ref,       # scalar-prefetched
     code = codes_ref[p]
     a = code >> 5
     k = code & 31
+    outs = (fx_ref, fy_ref, fz_ref, pot_ref)
 
     @pl.when(first_ref[p] == 1)
     def _init():                 # first pair of this cluster: zero the tile
-        fx_ref[...] = jnp.zeros_like(fx_ref)
-        fy_ref[...] = jnp.zeros_like(fy_ref)
-        fz_ref[...] = jnp.zeros_like(fz_ref)
-        pot_ref[...] = jnp.zeros_like(pot_ref)
+        for o in outs:
+            o[...] = jnp.zeros_like(o)
 
     for j in range(csize):       # static unroll over the cluster's cells
-        base = off_ref[(a * 27 + k) * csize + j]
-        sx = xs_ref[0, pl.ds(base, m_c)]
-        sy = ys_ref[0, pl.ds(base, m_c)]
-        sz = zs_ref[0, pl.ds(base, m_c)]
-        sid = is_ref[0, pl.ds(base, m_c)]
-        lo = j * m_c
-        tx = xt_ref[0, lo:lo + m_c]
-        ty = yt_ref[0, lo:lo + m_c]
-        tz = zt_ref[0, lo:lo + m_c]
-        tid = it_ref[0, lo:lo + m_c]
-
-        ddx = tx[:, None] - sx[None, :]
-        ddy = ty[:, None] - sy[None, :]
-        ddz = tz[:, None] - sz[None, :]
-        r2 = ddx * ddx + ddy * ddy + ddz * ddz
-        mask = ((sid[None, :] != tid[:, None]) & (sid[None, :] >= 0)
-                & (tid[:, None] >= 0) & (r2 < cutoff2) & (r2 > 0.0))
-        r2s = jnp.where(mask, r2, 1.0)
-        w = mask.astype(ddx.dtype)
-        s = kernel.coeff(r2s) * w
-        pot = kernel.potential(r2s) * w
-        fx_ref[0, lo:lo + m_c] += (s * ddx).sum(-1)
-        fy_ref[0, lo:lo + m_c] += (s * ddy).sum(-1)
-        fz_ref[0, lo:lo + m_c] += (s * ddz).sum(-1)
-        pot_ref[0, lo:lo + m_c] += pot.sum(-1)
+        row = pl.ds(cell_ref[(a * 27 + k) * csize + j], 1)
+        col = slice(j, j + 1)
+        terms = pair_terms(
+            xt_ref[:, col], yt_ref[:, col], zt_ref[:, col], it_ref[:, col],
+            xs_ref[row, :], ys_ref[row, :], zs_ref[row, :], is_ref[row, :],
+            kernel=kernel, cutoff2=cutoff2, axis=1)
+        for o, v in zip(outs, terms):
+            o[:, col] += v
 
 
 @functools.partial(jax.jit, static_argnames=("csize", "m_c", "kernel",
                                              "cutoff2", "interpret"))
-def cell_sfc_forces(tiles: dict, flats: dict, codes: Array, first: Array,
-                    src_off: Array, *, csize: int, m_c: int,
+def cell_sfc_forces(tiles: dict, rows: dict, codes: Array, first: Array,
+                    src_cell: Array, *, csize: int, m_c: int,
                     kernel: PairKernel, cutoff2: float,
                     interpret: Optional[bool] = None
                     ) -> Tuple[Array, Array, Array, Array]:
     """Run the SFC pair-list kernel over the compressed codes.
 
     Args:
-      tiles: field name ("x","y","z","id") -> ``(n_clusters + 1,
-        csize * m_c)`` target cluster tiles, last row the all-sentinel
-        ghost cluster the pair-list padding decodes to.
-      flats: same fields -> ``(1, total + m_c)`` flattened padded planes
-        with one appended sentinel cell.
+      tiles: field name ("x","y","z","id") -> ``(n_clusters + 1, m_c,
+        csize)`` transposed target cluster tiles, last row the
+        all-sentinel ghost cluster the pair-list padding decodes to.
+      rows: same fields -> ``(n_pcells + 1, m_c)`` padded planes as cell
+        rows, with one appended sentinel cell.
       codes: (pair_cap,) int32 sorted compressed pair codes.
       first: (pair_cap,) int32, 1 where a program is its cluster's first
         pair (zero-initializes the resident output tile).
-      src_off: ((n_clusters + 1) * 27 * csize,) int32 flat slot base of
-        cell j of cluster a shifted by stencil k (ghost row -> sentinel).
+      src_cell: ((n_clusters + 1) * 27 * csize,) int32 cell row of cell
+        j of cluster a shifted by stencil k (ghost row -> sentinel).
     Returns:
-      (fx, fy, fz, pot), each ``(n_clusters + 1, csize * m_c)`` — rows of
+      (fx, fy, fz, pot), each ``(n_clusters + 1, m_c, csize)`` — tiles of
       clusters with no kept pair are *unwritten* (the wrapper masks them).
     """
     interpret = resolve_interpret(interpret)
     xt = tiles["x"]
-    n_rows, tile_w = xt.shape
-    flat_w = flats["x"].shape[-1]
+    n_rows = xt.shape[0]
+    n_cells = rows["x"].shape[0]
 
-    def tile_map(p, codes, first, off):
-        return (codes[p] >> 5, 0)
+    def tile_map(p, codes, first, cells):
+        return (codes[p] >> 5, 0, 0)
 
-    tile_block = pl.BlockSpec((1, tile_w), tile_map)
-    flat_block = pl.BlockSpec((1, flat_w), lambda p, codes, first, off: (0, 0))
-    out_shape = jax.ShapeDtypeStruct((n_rows, tile_w), xt.dtype)
+    tile_block = pl.BlockSpec((None, m_c, csize), tile_map)
+    row_block = pl.BlockSpec((n_cells, m_c),
+                             lambda p, codes, first, cells: (0, 0))
+    out_shape = jax.ShapeDtypeStruct((n_rows, m_c, csize), xt.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(codes.shape[0],),
-        in_specs=[tile_block] * 4 + [flat_block] * 4,
+        in_specs=[tile_block] * 4 + [row_block] * 4,
         out_specs=[tile_block] * 4,
     )
     body = functools.partial(_sfc_kernel, csize=csize, m_c=m_c,
                              kernel=kernel, cutoff2=float(cutoff2))
+    vmem, _ = sfc_budget(n_cells - 1, n_rows - 1, csize, m_c, codes.shape[0])
     return pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[out_shape] * 4,
+        compiler_params=compiler_params(("arbitrary",), vmem),
         interpret=interpret,
     )(codes.astype(jnp.int32), first.astype(jnp.int32),
-      src_off.astype(jnp.int32),
+      src_cell.astype(jnp.int32),
       tiles["x"], tiles["y"], tiles["z"], tiles["id"],
-      flats["x"], flats["y"], flats["z"], flats["id"])
+      rows["x"], rows["y"], rows["z"], rows["id"])

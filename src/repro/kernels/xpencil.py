@@ -10,20 +10,20 @@ Schedule (mirrors Algorithm 5, adapted per DESIGN.md §2):
               HBM->VMEM DMA double-buffered by the Pallas pipeline — the TPU
               version of overlapping the next pencil's copy with compute).
 
-  BlockSpec staging:
-    target pencil  block (1, 1, (nx+2)*m_c) at (z+1, y+1)      — "registers"
-    source pencil  block (1, 1, (nx+2)*m_c) at (z+k/3, y+k%3)  — "shared mem"
-    outputs        block (1, 1, nx*m_c), revisited across k, accumulated.
+  BlockSpec staging, on the lane layout of ``_lanes`` (slot on sublanes,
+  padded cell on lanes; planes ``(nz+2, ny+2, m_c, L)``):
+    target pencil  tile (m_c, L) at (z+1, y+1)       — "registers"
+    source pencil  tile (m_c, L) at (z+k//3, y+k%3)  — "shared mem"
+    outputs        tile (m_c, L) at (z, y), revisited across k, accumulated.
 
-  The contiguous 3*m_c X-window of each target cell is built from three
-  static slices of the staged source row (the dense slot layout makes the
-  window contiguous — the paper needs its local-offset prefix sum for this).
+  The 3*m_c X-window of every target cell is three lane rotations of the
+  staged source tile (the dense slot layout makes the window contiguous —
+  the paper needs its local-offset prefix sum for this).
 
-VMEM per step: 8 pencil rows + 4 output rows ~ (12*nx + 16)*m_c*4 bytes
-(nx=32, m_c=128 -> ~200 KB), far under budget: exactly the paper's point that
-pencils, unlike sub-boxes, leave head-room (occupancy there, double-buffering
-here). Lane alignment: rows are contiguous f32 vectors; choosing m_c as a
-multiple of 8 keeps slices sublane-aligned (``suggest_m_c`` does this).
+VMEM per step: 12 double-buffered (m_c, L) tiles plus the (3*m_c, m_c, L)
+pair temporaries (``xpencil_vmem_bytes``) — a few MiB at m_c = 16, far
+under budget: exactly the paper's point that pencils, unlike sub-boxes,
+leave head-room.
 
 ``xpencil_sparse_forces`` below is the occupancy-compacted variant: its grid
 runs over the *active* pencils only, with the active-index list
@@ -46,77 +46,50 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.binning import EMPTY_POS
 from ..core.interactions import PairKernel
+from ._lanes import (LANES, compiler_params, from_lanes, lane_gather,
+                     lane_width, pair_terms, shift_lanes, tile_bytes,
+                     to_lanes, window_terms)
 from ._platform import resolve_interpret
 
 Array = jnp.ndarray
 
 
-def _window3(row: Array, nx: int, m_c: int) -> Array:
-    """(nx+2)*m_c source row -> (nx, 3*m_c) per-cell contiguous windows."""
-    cells = row.reshape(nx + 2, m_c)
-    return jnp.concatenate(
-        [cells[0:nx], cells[1:nx + 1], cells[2:nx + 2]], axis=-1)
+def _lane_planes(planes: dict, slot_id: Array, m_c: int):
+    """The four input planes of a dense kernel on the lane layout."""
+    return (to_lanes(planes["x"], m_c, EMPTY_POS),
+            to_lanes(planes["y"], m_c, EMPTY_POS),
+            to_lanes(planes["z"], m_c, EMPTY_POS),
+            to_lanes(slot_id, m_c, -1))
 
 
-def _pencil_contrib(trows: Tuple[Array, Array, Array, Array],
-                    srows: Tuple[Array, Array, Array, Array],
-                    *, nx: int, m_c: int, kernel: PairKernel,
-                    cutoff2: float):
-    """One (dz, dy) step: target pencil rows x one staged source pencil row.
-
-    ``trows``/``srows`` are the raw padded rows (length ``(nx+2)*m_c``) of
-    x, y, z, slot_id. Returns 4 flat ``(nx*m_c,)`` contributions. Shared by
-    the dense and compacted kernel bodies so compaction cannot change a
-    computed value.
-    """
-    lo, hi = m_c, (nx + 1) * m_c
-    xt, yt, zt, it = trows
-    tx = xt[lo:hi].reshape(nx, m_c, 1)
-    ty = yt[lo:hi].reshape(nx, m_c, 1)
-    tz = zt[lo:hi].reshape(nx, m_c, 1)
-    tid = it[lo:hi].reshape(nx, m_c, 1)
-
-    xs, ys, zs, is_ = srows
-    sx = _window3(xs, nx, m_c).reshape(nx, 1, 3 * m_c)
-    sy = _window3(ys, nx, m_c).reshape(nx, 1, 3 * m_c)
-    sz = _window3(zs, nx, m_c).reshape(nx, 1, 3 * m_c)
-    sid = _window3(is_, nx, m_c).reshape(nx, 1, 3 * m_c)
-
-    ddx, ddy, ddz = tx - sx, ty - sy, tz - sz
-    r2 = ddx * ddx + ddy * ddy + ddz * ddz
-    mask = (sid != tid) & (sid >= 0) & (tid >= 0) & (r2 < cutoff2) & (r2 > 0.0)
-    r2s = jnp.where(mask, r2, 1.0)
-    w = mask.astype(ddx.dtype)
-    s = kernel.coeff(r2s) * w
-    pot = kernel.potential(r2s) * w
-    return ((s * ddx).sum(-1).reshape(nx * m_c),
-            (s * ddy).sum(-1).reshape(nx * m_c),
-            (s * ddz).sum(-1).reshape(nx * m_c),
-            pot.sum(-1).reshape(nx * m_c))
+def xpencil_vmem_bytes(nx: int, m_c: int) -> int:
+    """VMEM one dense/compacted X-pencil grid step holds: 12 pipelined
+    (m_c, L) tiles, double-buffered, plus ~16 live pair temporaries."""
+    tile = tile_bytes(m_c, nx + 2)
+    return 2 * 12 * tile + 16 * 3 * m_c * tile
 
 
-def _kernel(xt_ref, yt_ref, zt_ref, it_ref,
-            xs_ref, ys_ref, zs_ref, is_ref,
-            fx_ref, fy_ref, fz_ref, pot_ref,
-            *, nx: int, m_c: int, kernel: PairKernel, cutoff2: float):
-    k = pl.program_id(2)
+def _pencil_step(k, t_refs, s_refs, o_refs, *, m_c: int, kernel: PairKernel,
+                 cutoff2: float):
+    """One (dz, dy) step: the staged target pencil x one source pencil,
+    accumulated into the resident output tiles. Shared by the dense and
+    compacted kernels so compaction cannot change a computed value."""
 
     @pl.when(k == 0)
     def _init():
-        fx_ref[...] = jnp.zeros_like(fx_ref)
-        fy_ref[...] = jnp.zeros_like(fy_ref)
-        fz_ref[...] = jnp.zeros_like(fz_ref)
-        pot_ref[...] = jnp.zeros_like(pot_ref)
+        for o in o_refs:
+            o[...] = jnp.zeros_like(o)
 
-    fx, fy, fz, pot = _pencil_contrib(
-        (xt_ref[0, 0, :], yt_ref[0, 0, :], zt_ref[0, 0, :], it_ref[0, 0, :]),
-        (xs_ref[0, 0, :], ys_ref[0, 0, :], zs_ref[0, 0, :], is_ref[0, 0, :]),
-        nx=nx, m_c=m_c, kernel=kernel, cutoff2=cutoff2)
+    tgt = tuple(shift_lanes(r[...], 1) for r in t_refs)   # lane x = cell x
+    src = tuple(r[...] for r in s_refs)
+    terms = window_terms(tgt, src, m_c=m_c, kernel=kernel, cutoff2=cutoff2)
+    for o, v in zip(o_refs, terms):
+        o[...] += v
 
-    fx_ref[...] += fx.reshape(1, 1, nx * m_c)
-    fy_ref[...] += fy.reshape(1, 1, nx * m_c)
-    fz_ref[...] += fz.reshape(1, 1, nx * m_c)
-    pot_ref[...] += pot.reshape(1, 1, nx * m_c)
+
+def _kernel(*refs, m_c: int, kernel: PairKernel, cutoff2: float):
+    _pencil_step(pl.program_id(2), refs[0:4], refs[4:8], refs[8:12],
+                 m_c=m_c, kernel=kernel, cutoff2=cutoff2)
 
 
 @functools.partial(jax.jit, static_argnames=("nx", "m_c", "kernel", "cutoff2", "interpret"))
@@ -135,27 +108,30 @@ def xpencil_forces(planes: dict, slot_id: Array, *, nx: int, m_c: int,
       (fx, fy, fz, pot), each (nz, ny, nx*m_c) over interior slots.
     """
     interpret = resolve_interpret(interpret)
-    x = planes["x"]
-    nzp, nyp, w = x.shape
+    lanes = _lane_planes(planes, slot_id, m_c)
+    nzp, nyp, _, width = lanes[0].shape
     nz, ny = nzp - 2, nyp - 2
-    row_block = pl.BlockSpec((1, 1, w), lambda z, y, k: (z + 1, y + 1, 0))
-    nbr_block = pl.BlockSpec((1, 1, w), lambda z, y, k: (z + k // 3, y + k % 3, 0))
-    out_block = pl.BlockSpec((1, 1, nx * m_c), lambda z, y, k: (z, y, 0))
-    out_shape = jax.ShapeDtypeStruct((nz, ny, nx * m_c), x.dtype)
+    tile = (None, None, m_c, width)
+    row_block = pl.BlockSpec(tile, lambda z, y, k: (z + 1, y + 1, 0, 0))
+    nbr_block = pl.BlockSpec(tile, lambda z, y, k: (z + k // 3, y + k % 3,
+                                                    0, 0))
+    out_block = pl.BlockSpec(tile, lambda z, y, k: (z, y, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((nz, ny, m_c, width), lanes[0].dtype)
 
-    body = functools.partial(_kernel, nx=nx, m_c=m_c, kernel=kernel,
+    body = functools.partial(_kernel, m_c=m_c, kernel=kernel,
                              cutoff2=float(cutoff2))
-    fx, fy, fz, pot = pl.pallas_call(
+    outs = pl.pallas_call(
         body,
         grid=(nz, ny, 9),
         in_specs=[row_block] * 4 + [nbr_block] * 4,
         out_specs=[out_block] * 4,
-        out_shape=[out_shape] * 3 + [jax.ShapeDtypeStruct(
-            (nz, ny, nx * m_c), x.dtype)],
+        out_shape=[out_shape] * 4,
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            xpencil_vmem_bytes(nx, m_c)),
         interpret=interpret,
-    )(x, planes["y"], planes["z"], slot_id,
-      x, planes["y"], planes["z"], slot_id)
-    return fx, fy, fz, pot
+    )(*lanes, *lanes)
+    return tuple(from_lanes(o, nx) for o in outs)
 
 
 # --------------------------------------------------------------------------
@@ -173,30 +149,11 @@ def xpencil_forces(planes: dict, slot_id: Array, *, nx: int, m_c: int,
 # pencil 0 and are dropped by the scatter).
 
 
-def _sparse_kernel(act_ref,                         # scalar-prefetched ids
-                   xt_ref, yt_ref, zt_ref, it_ref,
-                   xs_ref, ys_ref, zs_ref, is_ref,
-                   fx_ref, fy_ref, fz_ref, pot_ref,
-                   *, nx: int, m_c: int, kernel: PairKernel, cutoff2: float):
+def _sparse_kernel(act_ref, *refs, m_c: int, kernel: PairKernel,
+                   cutoff2: float):
     del act_ref  # consumed by the BlockSpec index maps, not the body
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        fx_ref[...] = jnp.zeros_like(fx_ref)
-        fy_ref[...] = jnp.zeros_like(fy_ref)
-        fz_ref[...] = jnp.zeros_like(fz_ref)
-        pot_ref[...] = jnp.zeros_like(pot_ref)
-
-    fx, fy, fz, pot = _pencil_contrib(
-        (xt_ref[0, 0, :], yt_ref[0, 0, :], zt_ref[0, 0, :], it_ref[0, 0, :]),
-        (xs_ref[0, 0, :], ys_ref[0, 0, :], zs_ref[0, 0, :], is_ref[0, 0, :]),
-        nx=nx, m_c=m_c, kernel=kernel, cutoff2=cutoff2)
-
-    fx_ref[...] += fx.reshape(1, nx * m_c)
-    fy_ref[...] += fy.reshape(1, nx * m_c)
-    fz_ref[...] += fz.reshape(1, nx * m_c)
-    pot_ref[...] += pot.reshape(1, nx * m_c)
+    _pencil_step(pl.program_id(1), refs[0:4], refs[4:8], refs[8:12],
+                 m_c=m_c, kernel=kernel, cutoff2=cutoff2)
 
 
 @functools.partial(jax.jit, static_argnames=("nx", "ny", "m_c", "kernel",
@@ -218,37 +175,39 @@ def xpencil_sparse_forces(planes: dict, slot_id: Array, active_zy: Array, *,
       holds the interior forces of pencil ``active_zy[a]``.
     """
     interpret = resolve_interpret(interpret)
-    x = planes["x"]
-    w = x.shape[-1]
+    lanes = _lane_planes(planes, slot_id, m_c)
+    width = lanes[0].shape[-1]
     max_active = active_zy.shape[0]
 
     def tgt_map(a, k, act):
-        return (act[a] // ny + 1, act[a] % ny + 1, 0)
+        return (act[a] // ny + 1, act[a] % ny + 1, 0, 0)
 
     def nbr_map(a, k, act):
-        return (act[a] // ny + k // 3, act[a] % ny + k % 3, 0)
+        return (act[a] // ny + k // 3, act[a] % ny + k % 3, 0, 0)
 
-    row_block = pl.BlockSpec((1, 1, w), tgt_map)
-    nbr_block = pl.BlockSpec((1, 1, w), nbr_map)
-    out_block = pl.BlockSpec((1, nx * m_c), lambda a, k, act: (a, 0))
-    out_shape = jax.ShapeDtypeStruct((max_active, nx * m_c), x.dtype)
+    tile = (None, None, m_c, width)
+    out_block = pl.BlockSpec((None, m_c, width), lambda a, k, act: (a, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((max_active, m_c, width),
+                                     lanes[0].dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(max_active, 9),
-        in_specs=[row_block] * 4 + [nbr_block] * 4,
+        in_specs=([pl.BlockSpec(tile, tgt_map)] * 4
+                  + [pl.BlockSpec(tile, nbr_map)] * 4),
         out_specs=[out_block] * 4,
     )
-    body = functools.partial(_sparse_kernel, nx=nx, m_c=m_c, kernel=kernel,
+    body = functools.partial(_sparse_kernel, m_c=m_c, kernel=kernel,
                              cutoff2=float(cutoff2))
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[out_shape] * 4,
+        compiler_params=compiler_params(("parallel", "arbitrary"),
+                                        xpencil_vmem_bytes(nx, m_c)),
         interpret=interpret,
-    )(active_zy.astype(jnp.int32),
-      x, planes["y"], planes["z"], slot_id,
-      x, planes["y"], planes["z"], slot_id)
+    )(active_zy.astype(jnp.int32), *lanes, *lanes)
+    return tuple(from_lanes(o, nx) for o in outs)
 
 
 # --------------------------------------------------------------------------
@@ -265,81 +224,58 @@ def xpencil_sparse_forces(planes: dict, slot_id: Array, active_zy: Array, *,
 # data-dependent staging, composed with the packed rows' own CSR offsets,
 # which stay *row-local* so a DMA'd row is self-describing); inside the
 # body each target slot's 3-cell X-window is re-expanded to the dense
-# (3*m_c,) shape by offset/length, so every pair term, mask and reduction
-# is elementwise identical to the dense kernel's — bit-identical results.
+# (3*m_c,) shape by offset/length with in-vreg lane gathers, so every pair
+# term, mask and reduction is elementwise identical to the dense kernel's
+# — bit-identical results. Packed slots sit on lanes, window slots on the
+# leading axis: the (3*m_c, row_cap) pair tile needs no relayout.
 
-def _packed_contrib(trows, srow, soff, *, nx: int, m_c: int, row_cap: int,
-                    kernel: PairKernel, cutoff2: float):
-    """One (dz, dy) step over packed rows.
-
-    ``trows`` = (tx, ty, tz, tid, tcell) packed target row vectors, each
-    ``(row_cap,)``; ``srow`` = (xs, ys, zs, ids) packed source row;
-    ``soff`` = the source row's ``(nx+3,)`` cell offsets. Returns 4 flat
-    ``(row_cap,)`` contributions, elementwise equal to what the dense
-    body computes for the same particles.
-    """
-    tx, ty, tz, tid, tc = trows
-    xs, ys, zs, ids = srow
-    tcell = jnp.clip(tc, 1, nx)          # pad/ghost targets never unpacked
-
-    j = jnp.arange(3 * m_c, dtype=jnp.int32)
-    wcell = tcell[:, None] - 1 + j // m_c            # (row_cap, 3*m_c)
-    rank = j % m_c
-    start = jnp.take(soff, wcell.reshape(-1)).reshape(wcell.shape)
-    cnt = jnp.take(soff, (wcell + 1).reshape(-1)).reshape(wcell.shape) - start
-    valid = rank < cnt
-    src = jnp.where(valid, start + rank, 0).reshape(-1)
-
-    def expand(row, fill):
-        vals = jnp.take(row, src).reshape(wcell.shape)
-        return jnp.where(valid, vals, fill)
-
-    sx = expand(xs, EMPTY_POS)
-    sy = expand(ys, EMPTY_POS)
-    sz = expand(zs, EMPTY_POS)
-    sid = expand(ids, jnp.int32(-1))
-
-    ddx = tx[:, None] - sx
-    ddy = ty[:, None] - sy
-    ddz = tz[:, None] - sz
-    r2 = ddx * ddx + ddy * ddy + ddz * ddz
-    mask = ((sid != tid[:, None]) & (sid >= 0) & (tid[:, None] >= 0)
-            & (r2 < cutoff2) & (r2 > 0.0))
-    r2s = jnp.where(mask, r2, 1.0)
-    w = mask.astype(ddx.dtype)
-    s = kernel.coeff(r2s) * w
-    pot = kernel.potential(r2s) * w
-    return ((s * ddx).sum(-1), (s * ddy).sum(-1), (s * ddz).sum(-1),
-            pot.sum(-1))
+def packed_vmem_bytes(nx: int, m_c: int, row_cap: int) -> int:
+    """VMEM one packed X-pencil grid step holds: 14 pipelined one-row
+    tiles, double-buffered, plus ~24 live (3*m_c, row_cap) temporaries."""
+    row = tile_bytes(1, row_cap)
+    return (2 * 14 * row + tile_bytes(1, nx + 3) * 2
+            + 24 * tile_bytes(3 * m_c, row_cap))
 
 
 def _packed_kernel(act_ref,                          # scalar-prefetched ids
                    xt_ref, yt_ref, zt_ref, it_ref, ct_ref,
                    xs_ref, ys_ref, zs_ref, is_ref, os_ref,
                    fx_ref, fy_ref, fz_ref, pot_ref,
-                   *, nx: int, m_c: int, row_cap: int, kernel: PairKernel,
-                   cutoff2: float):
+                   *, nx: int, m_c: int, kernel: PairKernel, cutoff2: float):
     del act_ref  # consumed by the BlockSpec index maps, not the body
     k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
-        fx_ref[...] = jnp.zeros_like(fx_ref)
-        fy_ref[...] = jnp.zeros_like(fy_ref)
-        fz_ref[...] = jnp.zeros_like(fz_ref)
-        pot_ref[...] = jnp.zeros_like(pot_ref)
+        for o in (fx_ref, fy_ref, fz_ref, pot_ref):
+            o[...] = jnp.zeros_like(o)
 
-    fx, fy, fz, pot = _packed_contrib(
-        (xt_ref[0, 0, :], yt_ref[0, 0, :], zt_ref[0, 0, :], it_ref[0, 0, :],
-         ct_ref[0, 0, :]),
-        (xs_ref[0, 0, :], ys_ref[0, 0, :], zs_ref[0, 0, :], is_ref[0, 0, :]),
-        os_ref[0, 0, :],
-        nx=nx, m_c=m_c, row_cap=row_cap, kernel=kernel, cutoff2=cutoff2)
+    rank = jax.lax.broadcasted_iota(jnp.int32, (m_c, LANES), 0)
+    for c0 in range(0, xt_ref.shape[-1], LANES):   # one vreg of targets
+        sl = slice(c0, c0 + LANES)
+        # pad/ghost targets are never unpacked
+        tcell = jnp.clip(ct_ref[:, sl], 1, nx)
+        # per window cell dc: where its slots start in the source row, and
+        # how many there are — (1, 128) per target slot
+        starts, counts = [], []
+        for dc in range(3):
+            lo = lane_gather(os_ref, tcell - 1 + dc)
+            starts.append(lo)
+            counts.append(lane_gather(os_ref, tcell + dc) - lo)
+        valid = jnp.concatenate([rank < c for c in counts], axis=0)
+        src = jnp.concatenate([s + rank for s in starts], axis=0)
+        src = jnp.where(valid, src, 0)                  # (3*m_c, 128)
 
-    fx_ref[...] += fx.reshape(1, row_cap)
-    fy_ref[...] += fy.reshape(1, row_cap)
-    fz_ref[...] += fz.reshape(1, row_cap)
-    pot_ref[...] += pot.reshape(1, row_cap)
+        def expand(ref, fill):
+            return jnp.where(valid, lane_gather(ref, src), fill)
+
+        terms = pair_terms(
+            xt_ref[:, sl], yt_ref[:, sl], zt_ref[:, sl], it_ref[:, sl],
+            expand(xs_ref, EMPTY_POS), expand(ys_ref, EMPTY_POS),
+            expand(zs_ref, EMPTY_POS), expand(is_ref, jnp.int32(-1)),
+            kernel=kernel, cutoff2=cutoff2, axis=0)
+        for o, v in zip((fx_ref, fy_ref, fz_ref, pot_ref), terms):
+            o[:, sl] += v
 
 
 @functools.partial(jax.jit, static_argnames=("nx", "ny", "m_c", "row_cap",
@@ -366,35 +302,45 @@ def xpencil_packed_forces(planes: dict, slot_id: Array, slot_cell: Array,
       holds the packed-slot forces of pencil ``active_zy[a]``.
     """
     interpret = resolve_interpret(interpret)
-    x = planes["x"]
     n_rows = active_zy.shape[0]
+    width = lane_width(row_cap)
+
+    def row(plane, fill, w=width):     # (..., n) -> (..., 1, w) one-row tiles
+        n = plane.shape[-1]
+        pad = [(0, 0)] * (plane.ndim - 1) + [(0, w - n)]
+        return jnp.pad(plane, pad, constant_values=fill)[..., None, :]
+
+    tgt = (row(planes["x"], EMPTY_POS), row(planes["y"], EMPTY_POS),
+           row(planes["z"], EMPTY_POS), row(slot_id, -1), row(slot_cell, 0))
+    offs = row(cell_offsets, 0, lane_width(nx + 3))
 
     def tgt_map(a, k, act):
-        return (act[a] // ny + 1, act[a] % ny + 1, 0)
+        return (act[a] // ny + 1, act[a] % ny + 1, 0, 0)
 
     def nbr_map(a, k, act):
-        return (act[a] // ny + k // 3, act[a] % ny + k % 3, 0)
+        return (act[a] // ny + k // 3, act[a] % ny + k % 3, 0, 0)
 
-    row_block = pl.BlockSpec((1, 1, row_cap), tgt_map)
-    nbr_block = pl.BlockSpec((1, 1, row_cap), nbr_map)
-    off_block = pl.BlockSpec((1, 1, nx + 3), nbr_map)
-    out_block = pl.BlockSpec((1, row_cap), lambda a, k, act: (a, 0))
-    out_shape = jax.ShapeDtypeStruct((n_rows, row_cap), x.dtype)
+    row_tile = (None, None, 1, width)
+    off_tile = (None, None, 1, offs.shape[-1])
+    out_block = pl.BlockSpec((None, 1, width), lambda a, k, act: (a, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((n_rows, 1, width), tgt[0].dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_rows, 9),
-        in_specs=[row_block] * 5 + [nbr_block] * 4 + [off_block],
+        in_specs=([pl.BlockSpec(row_tile, tgt_map)] * 5
+                  + [pl.BlockSpec(row_tile, nbr_map)] * 4
+                  + [pl.BlockSpec(off_tile, nbr_map)]),
         out_specs=[out_block] * 4,
     )
-    body = functools.partial(_packed_kernel, nx=nx, m_c=m_c,
-                             row_cap=row_cap, kernel=kernel,
+    body = functools.partial(_packed_kernel, nx=nx, m_c=m_c, kernel=kernel,
                              cutoff2=float(cutoff2))
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[out_shape] * 4,
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary"), packed_vmem_bytes(nx, m_c, row_cap)),
         interpret=interpret,
-    )(active_zy.astype(jnp.int32),
-      x, planes["y"], planes["z"], slot_id, slot_cell,
-      x, planes["y"], planes["z"], slot_id, cell_offsets)
+    )(active_zy.astype(jnp.int32), *tgt, *tgt[:4], offs)
+    return tuple(o[:, 0, :row_cap] for o in outs)

@@ -411,6 +411,9 @@ class ServingEngine:
         t0 = _time.perf_counter()
         fault: Optional[BaseException] = None
         forces = potential = None
+        # a compile error raises here, outside the fault handler: it is a
+        # defect of the program, never grounds to quarantine the class
+        p.compile_batch(batched)
         try:
             # the serve-dispatch fault point: straggler latency rides the
             # engine clock, transient errors / shard loss raise, and a

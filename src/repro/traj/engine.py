@@ -615,6 +615,10 @@ def run_trajectory(base: InteractionPlan, state, n_steps: int, dt: float, *,
         exec_fn = _segment_exec(rung, integrator, this_len,
                                 float(eff_skin), mass, field_names,
                                 has_valid)
+        # compile errors raise here, outside the fault handler: a kernel
+        # the platform refuses is a defect, not a fault to degrade around
+        api.compile_ahead(exec_fn, carry, dt_arr, gamma_arr, kT_arr, fields,
+                          valid)
         st = chaos.state()
         fires_before = (st.fire_count("traj.step", "nonfinite")
                         if st is not None else 0)

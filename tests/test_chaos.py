@@ -221,6 +221,39 @@ def test_breaker_trips_down_ladder_and_parity_holds():
     _assert_bitwise(u, u_ref)
 
 
+@pytest.fixture
+def lowering_fails(monkeypatch):
+    """The pallas dense xpencil backend replaced by one whose lowering
+    fails, as a kernel the chip's compiler refuses would."""
+    import repro.kernels  # noqa: F401  (register the real kernels first)
+
+    def refused(p, bins, state):
+        raise ValueError("Mosaic failed to lower the kernel body")
+    api.clear_executor_cache()
+    monkeypatch.setitem(api._BACKENDS, ("pallas", "xpencil", "dense"),
+                        refused)
+    yield
+    api.clear_executor_cache()
+
+
+@pytest.mark.parametrize("entry", ["execute_checked", "trajectory"])
+def test_lowering_error_escapes_instead_of_degrading(lowering_fails, entry):
+    """A program the compiler refuses is a defect, not a runtime fault:
+    it raises out of the guarded entry points and never steps down the
+    ladder to the reference rung."""
+    dom = _dom()
+    state = _state(dom)
+    # a kernel no other test plans with: no cached trace can hide the error
+    p = plan(dom, make_lennard_jones(sigma=0.21), positions=state.positions,
+             strategy="xpencil", backend="pallas", interpret=True)
+    with pytest.raises(ValueError, match="failed to lower"):
+        if entry == "execute_checked":
+            p.execute_checked(state)
+        else:
+            p.trajectory(state, n_steps=2, dt=1e-3)
+    assert plan_health(p).level == 0
+
+
 def test_breaker_recovers_after_clean_streak():
     dom = _dom()
     state = _state(dom)

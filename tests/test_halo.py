@@ -121,6 +121,27 @@ def test_single_shard_fallback_bit_identical():
     np.testing.assert_array_equal(np.asarray(q_r), np.asarray(q_h))
 
 
+def test_fig_halo_sets_host_devices_before_jax():
+    """``fig_halo --devices N`` decides its device flags before JAX is
+    imported and runs in one process: importing the module loads no JAX,
+    and the flag replaces any earlier device count."""
+    from benchmarks.fig_halo import host_device_flags
+    assert (host_device_flags(4, "")
+            == "--xla_force_host_platform_device_count=4")
+    assert (host_device_flags(
+        8, "--xla_foo=1 --xla_force_host_platform_device_count=2")
+        == "--xla_foo=1 --xla_force_host_platform_device_count=8")
+    root = pathlib.Path(__file__).resolve().parents[1]
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, benchmarks.fig_halo as f; "
+         "print('jax' in sys.modules, hasattr(f, 'subprocess'))"],
+        capture_output=True, text=True, cwd=root, timeout=60,
+        env=dict(os.environ, PYTHONPATH=SRC))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == ["False", "False"]
+
+
 def test_halo_plan_validation():
     dom = Domain.cubic(8, cutoff=1.0)
     pos = dom.sample_uniform(jax.random.PRNGKey(0), 100)
