@@ -62,6 +62,9 @@ VARIANTS = {
     "allin": {"strategy": "allin"},
     "sfc": {"strategy": "cell_dense", "layout": "sfc"},
 }
+# the variants plan() may refuse for the chip's memories; any other
+# refusal fails the smoke
+REFUSABLE = {"sfc", "xpencil_packed"}
 
 
 class SmokeFailure(AssertionError):
@@ -158,7 +161,7 @@ def _steady(fn, reps: int = 3) -> float:
 
 
 def _budget(p) -> dict:
-    from repro.kernels import kernel_budget
+    from repro.core import kernel_budget
     name, vmem, smem = kernel_budget(p)
     return {"kernel": name, "vmem_bytes": vmem, "smem_bytes": smem}
 
@@ -205,8 +208,10 @@ def one_shot(domain, kernel, positions, refs: dict,
             p = plan(domain, kernel, positions=positions, m_c=M_C,
                      backend="pallas", interpret=interpret, **kw)
         except ValueError as e:
-            # only a kernel that cannot fit the chip may be refused, and
-            # only by name of the memory it would overflow
+            # only a kernel that cannot fit the chip by design may be
+            # refused, and only by name of the memory it would overflow
+            if name not in REFUSABLE:
+                raise
             check("VMEM" in str(e) or "SMEM" in str(e),
                   f"{name}: plan refused for another reason: {e}")
             emit(phase=name, n=n, m_c=M_C, refused=str(e))
@@ -241,19 +246,17 @@ def trajectory(domain, kernel, positions, velocities, *, steps: int = STEPS,
     Verlet skin ``SKIN``; status, rung, finiteness and energy drift."""
     import jax.numpy as jnp
     import numpy as np
-    from repro.core import ParticleState, api, plan
+    from repro.core import ParticleState, plan
 
     p = plan(domain, kernel, positions=positions, m_c=TRAJ_M_C,
              strategy="xpencil", backend="pallas", interpret=interpret)
-    seen = set(api._AHEAD)          # segment programs compiled by this run
     t0 = time.perf_counter()
     res = p.trajectory(ParticleState(positions), n_steps=steps, dt=DT,
                        velocities=velocities, skin=SKIN,
                        segment_len=min(SEGMENT, steps))
     wall = time.perf_counter() - t0
-    new = [exe for key, exe in api._AHEAD.items() if key not in seen]
-    native = bool(new) and all("tpu_custom_call" in exe.as_text()
-                               for exe in new)
+    native = bool(res.executables) and all(
+        "tpu_custom_call" in exe.as_text() for exe in res.executables)
     total = np.asarray(res.traces["total"], np.float64)
     e0 = float(total[0])
     drift = float(np.abs(total - e0).max() / max(abs(e0), 1.0))
@@ -311,7 +314,9 @@ def four_chips(domain, kernel, positions, n_chips: int = 4,
         spans = len(f.sharding.device_set)
         emit(phase=name, n=n, m_c=p.m_c, n_shards=p.n_shards,
              shard_cap=p.shard_cap, halo_inner=p.halo_inner,
-             output_devices=spans, compile_s=t_compile,
+             output_devices=spans,
+             output_replicated=f.sharding.is_fully_replicated,
+             compile_s=t_compile,
              steady_s=_steady(lambda: p.execute(state)),
              status=report.status, tpu_custom_call=native,
              rel_dF=err_f, rel_dU=err_u,

@@ -11,7 +11,8 @@ from .domain import Domain
 from .api import (ExecutionReport, InteractionPlan, ParticleState, PlanHealth,
                   active_unit_count, backend_matrix, choose_strategy,
                   clear_executor_cache, degradation_ladder, dispatch_count,
-                  executor_cache_info, fallback_plan, plan, plan_health,
+                  executor_cache_info, fallback_plan, kernel_budget, plan,
+                  plan_health,
                   recompile_count, register_backend, reset_counters,
                   reset_health, set_executor_cache_size, suggest_max_active,
                   suggest_pair_cap, suggest_row_cap, supports_compact,
@@ -56,7 +57,7 @@ __all__ = [
     "decode_pair_codes", "morton_encode", "morton_decode",
     "hilbert_encode", "hilbert_decode", "suggest_pair_cap",
     "ExecutionReport", "InteractionPlan", "ParticleState", "PlanHealth",
-    "plan", "register_backend",
+    "plan", "register_backend", "kernel_budget",
     "backend_matrix", "choose_strategy", "clear_executor_cache",
     "degradation_ladder", "fallback_plan", "plan_health", "reset_health",
     "dispatch_count", "recompile_count", "reset_counters",

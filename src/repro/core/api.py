@@ -36,6 +36,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import weakref
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -109,9 +110,14 @@ LAYOUT_NAMES = ("dense", "packed", "sfc")
 # ``plan.compact`` (occupancy-compacted iteration). By register_backend.
 _COMPACT_OK: set = set()
 
+# (backend, strategy, layout) -> budget(plan, domain) for implementations
+# that stage data in the chip's on-chip memories. By register_backend.
+_BUDGETS: Dict[Tuple[str, str, str], Callable] = {}
+
 
 def register_backend(backend: str, strategy: str, compact: bool = False,
-                     layout: str = "dense"):
+                     layout: str = "dense",
+                     budget: Optional[Callable] = None):
     """Register an implementation under ``(backend, strategy, layout)``.
 
     The implementation receives the (static) plan, the binned layout
@@ -121,6 +127,10 @@ def register_backend(backend: str, strategy: str, compact: bool = False,
     one normalized signature both the reference schedules and the Pallas
     kernels conform to. ``compact=True`` declares that the implementation
     also honours ``plan.compact`` (occupancy-compacted iteration).
+    ``budget(plan, domain)`` is given by an implementation that stages
+    data on-chip: it returns ``(kernel name, VMEM bytes per grid step,
+    SMEM bytes)`` for the plan on ``domain`` and raises ``ValueError``,
+    naming the memory, when the plan cannot fit (:func:`kernel_budget`).
     """
     if layout not in LAYOUT_NAMES:
         raise ValueError(f"unknown layout {layout!r}; have {LAYOUT_NAMES}")
@@ -129,8 +139,27 @@ def register_backend(backend: str, strategy: str, compact: bool = False,
         _BACKENDS[(backend, strategy, layout)] = fn
         if compact:
             _COMPACT_OK.add((backend, strategy, layout))
+        if budget is not None:
+            _BUDGETS[(backend, strategy, layout)] = budget
         return fn
     return deco
+
+
+def kernel_budget(p: "InteractionPlan") -> Optional[Tuple[str, int, int]]:
+    """``(kernel name, VMEM bytes per grid step, SMEM bytes)`` of the
+    implementation ``p`` dispatches to, on the domain one shard runs on,
+    from the budget it registered; None when it stages nothing on-chip.
+    Raises ``ValueError`` naming the VMEM or SMEM limit when the plan
+    cannot fit the chip; ``plan()`` calls it, so such a plan is refused
+    before it is built."""
+    inner = p.halo_inner if p.backend == "halo" else p.backend
+    budget = _BUDGETS.get((inner, p.strategy, p.layout))
+    if budget is None:
+        return None
+    dom = p.domain
+    if p.backend == "halo" and (p.n_shards or 1) > 1:
+        dom = slab_domain(dom, p.n_shards)
+    return budget(p, dom)
 
 
 def supports_compact(backend: str, strategy: str,
@@ -832,11 +861,9 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
         # fail at plan time, not execute time (halo validates the
         # per-shard backend the slab schedule will actually dispatch to)
         get_backend(inner_backend, strategy, layout)
-        if inner_backend == "pallas":
-            # a kernel whose staging cannot fit VMEM/SMEM is refused here,
-            # never left to fail (or be degraded around) at execute time
-            from ..kernels import check_budget
-            check_budget(p)
+        # a kernel whose staging cannot fit VMEM/SMEM is refused here,
+        # never left to fail (or be degraded around) at execute time
+        kernel_budget(p)
     return p
 
 
@@ -1150,8 +1177,9 @@ _executor = _LRU(128, _build_executor)
 _batch_executor = _LRU(32, _build_batch_executor)
 
 
-_AHEAD: "collections.OrderedDict" = collections.OrderedDict()
-_AHEAD_MAX = 256
+# jitted fn -> {argument signature: Compiled}; entries go with the jitted
+# function, so the caches that hold the functions bound this one too
+_COMPILED: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
 def compile_ahead(fn: Callable, *args):
@@ -1164,27 +1192,22 @@ def compile_ahead(fn: Callable, *args):
     program on this platform — a kernel Mosaic refuses, a program that
     does not fit the device — not a runtime fault, so it raises to the
     caller instead of being absorbed by the degradation ladder. Memoized
-    per (fn, argument structure and
-    shapes); jit shares the executable, so the call that follows does not
-    compile again."""
+    per (fn, argument structure and shapes) for as long as ``fn`` lives;
+    jit shares the executable, so the call that follows does not compile
+    again."""
     leaves, tree = jax.tree_util.tree_flatten(args)
-    key = (fn, tree, tuple((getattr(x, "shape", ()),
-                            str(getattr(x, "dtype", type(x))))
-                           for x in leaves))
-    compiled = _AHEAD.get(key)
-    if compiled is None:
-        compiled = fn.lower(*args).compile()
-        _AHEAD[key] = compiled
-        while len(_AHEAD) > _AHEAD_MAX:
-            _AHEAD.popitem(last=False)
-    return compiled
+    key = (tree, tuple((getattr(x, "shape", ()),
+                        str(getattr(x, "dtype", type(x)))) for x in leaves))
+    programs = _COMPILED.setdefault(fn, {})
+    if key not in programs:
+        programs[key] = fn.lower(*args).compile()
+    return programs[key]
 
 
 def clear_executor_cache() -> None:
     """Drop every cached executor trace (single and batched)."""
     _executor.cache_clear()
     _batch_executor.cache_clear()
-    _AHEAD.clear()
 
 
 def set_executor_cache_size(single: Optional[int] = None,

@@ -238,7 +238,8 @@ def halo_impl(plan):
                         layout=plan.layout):
             f_part, pot_part = sharded(pos_part, fields_part)
         # the scatter-back reads every shard's rows: replicate them first
-        # (explicit-axis meshes, jax.make_mesh's default, require it)
+        # (explicit-axis meshes, jax.make_mesh's default, require it), so
+        # every device ends up holding the whole (N, 3) / (N,) output
         f_part, pot_part = jax.sharding.reshard(
             (f_part, pot_part), NamedSharding(mesh, P()))
         forces = H.scatter_from_shards(gather_idx, n, f_part)

@@ -19,16 +19,19 @@ runs the Pallas X-pencil kernel (natively on TPU, interpret mode elsewhere)
 through exactly the API users already select strategies with.
 
 Each kernel stages what one grid step needs in VMEM and its scalar tables
-in SMEM; :func:`kernel_budget` says how much for a plan, and ``plan()``
-refuses a pallas plan that cannot fit the chip (:func:`check_budget`).
+in SMEM. It registers a budget with its backend entry, which
+``core.api.kernel_budget`` reads: ``plan()`` refuses a pallas plan that
+runs natively and cannot fit the chip. The estimates count the staged
+blocks (double-buffered) plus a guessed number of live pair temporaries;
+they were not checked against what Mosaic allocates.
 """
 
-from typing import Tuple
+from typing import Callable, Tuple
 
 from ..core.api import InteractionPlan, ParticleState, register_backend
 from ..core.binning import (DEFAULT_CSIZE, CellBins, PackedRows, SfcClusters,
                             sfc_cluster_tables)
-from ..core.domain import slab_domain
+from ..core.domain import Domain
 from . import _lanes
 from ._platform import resolve_interpret
 from .allin import allin_vmem_bytes
@@ -38,50 +41,54 @@ from .ops import (allin_interactions, cell_sfc_interactions, prefix_sum,
 from .sfc import sfc_budget
 from .xpencil import packed_vmem_bytes, xpencil_vmem_bytes
 
-__all__ = ["allin_interactions", "cell_sfc_interactions", "check_budget",
-           "kernel_budget", "prefix_sum", "window_attention",
-           "xpencil_interactions", "xpencil_packed_interactions",
-           "xpencil_sparse_interactions"]
+__all__ = ["allin_interactions", "cell_sfc_interactions", "prefix_sum",
+           "window_attention", "xpencil_interactions",
+           "xpencil_packed_interactions", "xpencil_sparse_interactions"]
+
+Budget = Tuple[str, int, int]          # (kernel name, VMEM B, SMEM B)
 
 
-def kernel_budget(plan: InteractionPlan) -> Tuple[str, int, int]:
-    """``(kernel name, VMEM bytes per grid step, SMEM bytes)`` of the
-    Pallas kernel a pallas (or pallas-inner halo) plan dispatches to,
-    for the domain one shard runs on."""
-    dom = plan.domain
-    if plan.backend == "halo" and (plan.n_shards or 1) > 1:
-        dom = slab_domain(dom, plan.n_shards)
-    nx, ny, nz = dom.ncells
-    m_c = plan.m_c
-    if plan.layout == "sfc":
-        n_clusters = sfc_cluster_tables(dom, DEFAULT_CSIZE).n_clusters
-        n_pcells = (nx + 2) * (ny + 2) * (nz + 2)
-        vmem, smem = sfc_budget(n_pcells, n_clusters, DEFAULT_CSIZE, m_c,
-                                plan.pair_cap)
-        return "sfc", vmem, smem
-    if plan.layout == "packed":
-        rows = plan.max_active if plan.compact else nz * ny
-        return ("xpencil_packed",
-                packed_vmem_bytes(nx, m_c, plan.row_cap), 4 * rows)
-    if plan.strategy == "allin":
-        return "allin", allin_vmem_bytes(plan.box, m_c), 0
+def _fits(estimate: Callable[[InteractionPlan, Domain], Budget]):
+    """A ``register_backend`` budget from an estimate: a plan that runs
+    natively and cannot fit the chip's memories is refused (the
+    interpreter stages nothing in VMEM, so it never is)."""
+    def budget(plan: InteractionPlan, dom: Domain) -> Budget:
+        need = estimate(plan, dom)
+        if not resolve_interpret(plan.interpret):
+            _lanes.check_budget(*need)
+        return need
+    return budget
+
+
+def _xpencil_budget(plan: InteractionPlan, dom: Domain) -> Budget:
+    vmem = xpencil_vmem_bytes(dom.nx, plan.m_c)
     if plan.compact:
-        return "xpencil_compact", xpencil_vmem_bytes(nx, m_c), \
-            4 * plan.max_active
-    return "xpencil", xpencil_vmem_bytes(nx, m_c), 0
+        return "xpencil_compact", vmem, 4 * plan.max_active
+    return "xpencil", vmem, 0
 
 
-def check_budget(plan: InteractionPlan) -> None:
-    """Raise ``ValueError`` naming the VMEM or SMEM limit when the plan's
-    Pallas kernel runs natively and cannot fit the chip's memories (the
-    interpreter stages nothing in VMEM, so it is never refused)."""
-    if not resolve_interpret(plan.interpret):
-        _lanes.check_budget(*kernel_budget(plan))
+def _allin_budget(plan: InteractionPlan, dom: Domain) -> Budget:
+    return "allin", allin_vmem_bytes(plan.box, plan.m_c), 0
+
+
+def _packed_budget(plan: InteractionPlan, dom: Domain) -> Budget:
+    rows = plan.max_active if plan.compact else dom.nz * dom.ny
+    return ("xpencil_packed", packed_vmem_bytes(dom.nx, plan.m_c,
+                                                plan.row_cap), 4 * rows)
+
+
+def _sfc_budget(plan: InteractionPlan, dom: Domain) -> Budget:
+    n_clusters = sfc_cluster_tables(dom, DEFAULT_CSIZE).n_clusters
+    nx, ny, nz = dom.ncells
+    vmem, smem = sfc_budget((nx + 2) * (ny + 2) * (nz + 2), n_clusters,
+                            DEFAULT_CSIZE, plan.m_c, plan.pair_cap)
+    return "sfc", vmem, smem
 
 
 # -- plan/execute backend registration (normalized signature) ---------------
 
-@register_backend("pallas", "xpencil", compact=True)
+@register_backend("pallas", "xpencil", compact=True,
+                  budget=_fits(_xpencil_budget))
 def _pallas_xpencil(plan: InteractionPlan, bins: CellBins,
                     state: ParticleState):
     if plan.compact:
@@ -92,14 +99,15 @@ def _pallas_xpencil(plan: InteractionPlan, bins: CellBins,
                                 interpret=plan.interpret)
 
 
-@register_backend("pallas", "allin")
+@register_backend("pallas", "allin", budget=_fits(_allin_budget))
 def _pallas_allin(plan: InteractionPlan, bins: CellBins,
                   state: ParticleState):
     return allin_interactions(plan.domain, bins, plan.kernel, plan.box,
                               interpret=plan.interpret)
 
 
-@register_backend("pallas", "xpencil", compact=True, layout="packed")
+@register_backend("pallas", "xpencil", compact=True, layout="packed",
+                  budget=_fits(_packed_budget))
 def _pallas_xpencil_packed(plan: InteractionPlan, packed: PackedRows,
                            state: ParticleState):
     return xpencil_packed_interactions(
@@ -108,7 +116,8 @@ def _pallas_xpencil_packed(plan: InteractionPlan, packed: PackedRows,
         interpret=plan.interpret)
 
 
-@register_backend("pallas", "cell_dense", compact=True, layout="sfc")
+@register_backend("pallas", "cell_dense", compact=True, layout="sfc",
+                  budget=_fits(_sfc_budget))
 def _pallas_cell_sfc(plan: InteractionPlan, sfc: SfcClusters,
                      state: ParticleState):
     # compact=True is a no-op for the SFC layout: the compressed pair list

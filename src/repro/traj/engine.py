@@ -135,6 +135,9 @@ class TrajectoryResult:
     faults: List[str] = dataclasses.field(default_factory=list)
     ladder_level: int = 0              # rung that produced the final state
     eff_skin: float = 0.0              # measured skin margin of the grid
+    # the compiled segment programs this call ran (jax.stages.Compiled;
+    # .as_text() holds a tpu_custom_call per Pallas kernel on a TPU)
+    executables: List[Any] = dataclasses.field(default_factory=list)
 
 
 # --------------------------------------------------------------------------
@@ -617,8 +620,10 @@ def run_trajectory(base: InteractionPlan, state, n_steps: int, dt: float, *,
                                 has_valid)
         # compile errors raise here, outside the fault handler: a kernel
         # the platform refuses is a defect, not a fault to degrade around
-        api.compile_ahead(exec_fn, carry, dt_arr, gamma_arr, kT_arr, fields,
-                          valid)
+        compiled = api.compile_ahead(exec_fn, carry, dt_arr, gamma_arr,
+                                     kT_arr, fields, valid)
+        if all(c is not compiled for c in result.executables):
+            result.executables.append(compiled)
         st = chaos.state()
         fires_before = (st.fire_count("traj.step", "nonfinite")
                         if st is not None else 0)

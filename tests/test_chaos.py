@@ -254,6 +254,26 @@ def test_lowering_error_escapes_instead_of_degrading(lowering_fails, entry):
     assert plan_health(p).level == 0
 
 
+def test_compiled_program_goes_with_its_executor():
+    """The program compiled ahead of an execute lives only as long as the
+    cached executor it belongs to: evicting the executor frees it, so the
+    executor cache's bound is the only one."""
+    import gc
+    import weakref
+    dom = _dom()
+    state = _state(dom)
+    p = plan(dom, make_lennard_jones(sigma=0.23), positions=state.positions,
+             strategy="xpencil")
+    compiled = p.compile(state)
+    fn = api._executor(p, tuple(sorted(state.fields)))
+    assert api._COMPILED[fn][next(iter(api._COMPILED[fn]))] is compiled
+    gone = weakref.ref(fn)
+    del fn, compiled
+    api.clear_executor_cache()
+    gc.collect()
+    assert gone() is None
+
+
 def test_breaker_recovers_after_clean_streak():
     dom = _dom()
     state = _state(dom)

@@ -62,6 +62,24 @@ def test_one_chip_phases_interpreted(scene, capsys):
     assert rows["trajectory"]["steps"] == 4
 
 
+@pytest.mark.parametrize("variant", sorted(cs.VARIANTS))
+def test_only_designed_refusals_pass(scene, monkeypatch, capsys, variant):
+    """With every kernel over its VMEM limit, the smoke records sfc and
+    packed as refused and fails on any other variant: a kernel it must run
+    on the chip can never be refused quietly."""
+    from repro.kernels import _lanes
+    dom, kern, pos, _ = scene
+    monkeypatch.setattr(_lanes, "VMEM_LIMIT_BYTES", 0)
+    monkeypatch.setattr(cs, "VARIANTS", {variant: cs.VARIANTS[variant]})
+    if variant in cs.REFUSABLE:
+        cs.one_shot(dom, kern, pos, {}, interpret=False)
+        (row,) = _rows(capsys.readouterr().out)
+        assert "VMEM" in row["refused"], row
+    else:
+        with pytest.raises(ValueError, match="VMEM"):
+            cs.one_shot(dom, kern, pos, {}, interpret=False)
+
+
 def test_four_chip_phase_on_host_devices():
     code = textwrap.dedent(f"""
         import os

@@ -9,7 +9,12 @@ with the memory it would overflow named.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
-imports this file.
+imports this file. The tests skip only where the TPU library is not
+installed; a topology that cannot be described fails them.
+
+The VMEM estimates ``plan()`` refuses by are not checked against what
+Mosaic allocates (the compiled program does not report it); each compile
+here does run with the scoped-VMEM limit the estimate requests.
 """
 
 import os
@@ -30,14 +35,12 @@ DOM = Domain.cubic(DIVISION, cutoff=1.0, periodic=True)
 
 @pytest.fixture(scope="module")
 def topo():
+    pytest.importorskip("libtpu")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
     # these compiles cannot be read back without a chip: keep them out of
     # any persistent cache the environment names
     was = jax.config.jax_enable_compilation_cache
@@ -143,7 +146,7 @@ def test_interpreted_plan_is_never_refused():
 
 
 def test_smoke_plans_fit_their_budgets():
-    from repro.kernels import kernel_budget
+    from repro.core import kernel_budget
     for kw in ({"strategy": "xpencil"},
                {"strategy": "xpencil", "compact": True,
                 "max_active": DIVISION ** 2},
