@@ -1,0 +1,9 @@
+"""Device time of the periodic ghost ring (scope ``ghost``) per unit of
+the window (a force call or an MD step), in ms; None where the trace
+holds no such scope."""
+
+from bench.scopes import per_unit_ms
+
+
+def read(run):
+    return per_unit_ms(run, "ghost")

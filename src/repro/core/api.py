@@ -55,8 +55,8 @@ from .interactions import PairKernel, make_lennard_jones
 # obs imports only its own trace/metrics modules eagerly (no core imports),
 # so the dependency is acyclic: core.api -> obs.{trace,metrics}
 from ..obs import metrics as _obs_metrics
-from ..obs.trace import (event as _obs_event, trace as _obs_trace,
-                         tracing_enabled as _tracing_enabled)
+from ..obs.trace import (active as _obs_active, event as _obs_event,
+                         trace as _obs_trace)
 
 Array = jnp.ndarray
 
@@ -323,7 +323,7 @@ class InteractionPlan:
         ``0.5 * potential.sum()`` (each pair counted twice, the paper's
         convention)."""
         _count_dispatch(self)
-        if not _tracing_enabled():       # zero-overhead disabled path
+        if not _obs_active():            # zero-overhead disabled path
             return _executor(self, tuple(sorted(state.fields)))(state)
         with _obs_trace("plan.execute", backend=self.backend,
                         strategy=self.strategy, layout=self.layout,
@@ -341,7 +341,7 @@ class InteractionPlan:
         Returns ``(forces (B, N, 3), potential (B, N))``, bit-identical to
         executing each system separately."""
         _count_dispatch(self)
-        if not _tracing_enabled():       # zero-overhead disabled path
+        if not _obs_active():            # zero-overhead disabled path
             return _batch_executor(self, tuple(sorted(states.fields)))(states)
         with _obs_trace("plan.execute_batch", backend=self.backend,
                         strategy=self.strategy, layout=self.layout,
@@ -1082,8 +1082,10 @@ def _impl(p: InteractionPlan) -> Callable:
                 raise ValueError(
                     "naive_n2 bypasses binning and cannot mask padded "
                     "(valid=) rows; use a cell schedule")
-            fx, fy, fz, pot = S.naive_n2(p.domain, state.positions, p.kernel)
-            return jnp.stack([fx, fy, fz], axis=-1), pot
+            with jax.named_scope("pair"):
+                fx, fy, fz, pot = S.naive_n2(p.domain, state.positions,
+                                             p.kernel)
+                return jnp.stack([fx, fy, fz], axis=-1), pot
         bins = bin_particles(p.domain, state.positions, state.fields,
                              m_c=p.m_c, valid=state.valid)
         if p.layout == "packed":
@@ -1527,9 +1529,10 @@ def _execute_checked_impl(base: InteractionPlan, state: ParticleState, *,
 
 @register_backend("reference", "par_part")
 def _ref_par_part(p: InteractionPlan, bins: CellBins, state: ParticleState):
-    fx, fy, fz, pot = S.par_part(p.domain, bins, state.positions, p.kernel,
-                                 p.batch_size)
-    return jnp.stack([fx, fy, fz], axis=-1), pot
+    with jax.named_scope("pair"):       # per particle: no scatter-back
+        fx, fy, fz, pot = S.par_part(p.domain, bins, state.positions,
+                                     p.kernel, p.batch_size)
+        return jnp.stack([fx, fy, fz], axis=-1), pot
 
 
 def _ref_dense(name):
@@ -1538,23 +1541,25 @@ def _ref_dense(name):
     dense_fn = S.STRATEGIES[name]
     sparse_fn = S.SPARSE_STRATEGIES[name]
 
-    def impl(p: InteractionPlan, bins: CellBins, state: ParticleState):
+    def schedule(p: InteractionPlan, bins: CellBins):
         if p.compact:
             if name == "allin":
                 box = S.shrink_to_divisors(p.domain, p.box)
                 occ = subbox_occupancy(p.domain, bins.counts, box,
                                        p.max_active)
-                out = sparse_fn(p.domain, bins, p.kernel, occ, box,
-                                batch_size=p.batch_size)
-            else:
-                occ = pencil_occupancy(p.domain, bins.counts, p.max_active)
-                out = sparse_fn(p.domain, bins, p.kernel, occ,
-                                batch_size=p.batch_size)
-        else:
-            kwargs = {"batch_size": p.batch_size}
-            if name == "allin":
-                kwargs["box"] = p.box
-            out = dense_fn(p.domain, bins, p.kernel, **kwargs)
+                return sparse_fn(p.domain, bins, p.kernel, occ, box,
+                                 batch_size=p.batch_size)
+            occ = pencil_occupancy(p.domain, bins.counts, p.max_active)
+            return sparse_fn(p.domain, bins, p.kernel, occ,
+                             batch_size=p.batch_size)
+        kwargs = {"batch_size": p.batch_size}
+        if name == "allin":
+            kwargs["box"] = p.box
+        return dense_fn(p.domain, bins, p.kernel, **kwargs)
+
+    def impl(p: InteractionPlan, bins: CellBins, state: ParticleState):
+        with jax.named_scope("pair"):
+            out = schedule(p, bins)
         return dense_to_particles(p.domain, bins, *out)
     return impl
 
@@ -1570,10 +1575,11 @@ def _ref_xpencil_packed(p: InteractionPlan, packed: PackedRows,
                         state: ParticleState):
     """Packed-row reference backend: CSR rows, active-list iteration when
     the plan is compacted, identity active list otherwise."""
-    occ = (pencil_occupancy(p.domain, packed.counts, p.max_active)
-           if p.compact else full_pencil_occupancy(p.domain))
-    out = S.xpencil_packed(p.domain, packed, p.kernel, occ,
-                           batch_size=p.batch_size)
+    with jax.named_scope("pair"):
+        occ = (pencil_occupancy(p.domain, packed.counts, p.max_active)
+               if p.compact else full_pencil_occupancy(p.domain))
+        out = S.xpencil_packed(p.domain, packed, p.kernel, occ,
+                               batch_size=p.batch_size)
     return packed_to_particles(p.domain, packed, *out)
 
 
@@ -1584,5 +1590,6 @@ def _ref_cell_sfc(p: InteractionPlan, sfc: SfcClusters,
     no-op: the compressed pair list *is* the occupancy compaction (empty
     neighborhoods never enter ``codes``), so the compacted plan runs the
     same schedule and stays bit-identical by construction."""
-    out = S.cell_sfc(p.domain, sfc, p.kernel, batch_size=p.batch_size)
+    with jax.named_scope("pair"):
+        out = S.cell_sfc(p.domain, sfc, p.kernel, batch_size=p.batch_size)
     return sfc_to_particles(p.domain, sfc, *out)

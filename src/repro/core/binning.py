@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import device_scope
 from .domain import Domain
 from .prefix import exclusive_prefix_sum
 
@@ -96,65 +97,75 @@ def bin_particles(domain: Domain, positions: Array,
       m_c: static max-particles-per-cell bound (paper's M_C).
       valid: optional (N,) bool mask; False rows (e.g. the sentinel padding a
         halo shard carries) are excluded from counts and never land in a slot.
+
+    Device scopes: ``bin/sort`` (cell ids, counts, prefix, stable sort,
+    rank), ``bin/scatter`` (the slot planes, ``slot_id``,
+    ``particle_slot``), then ``ghost`` for a periodic domain.
     """
     n = positions.shape[0]
     nx, ny, nz = domain.ncells
     n_cells = domain.n_cells
 
-    coords = domain.cell_coords(positions)          # (N, 3) int32
-    cids = domain.linearize(coords)                 # (N,)
+    with jax.named_scope("bin/sort"):
+        coords = domain.cell_coords(positions)      # (N, 3) int32
+        cids = domain.linearize(coords)             # (N,)
 
-    if valid is None:
-        weights = jnp.ones((n,), jnp.int32)
-        sort_key = cids
-    else:
-        # invalid rows carry weight 0 in cell 0 and sort past every real cell
-        weights = valid.astype(jnp.int32)
-        cids = jnp.where(valid, cids, 0)
-        sort_key = jnp.where(valid, cids, n_cells)
+        if valid is None:
+            weights = jnp.ones((n,), jnp.int32)
+            sort_key = cids
+        else:
+            # invalid rows carry weight 0 in cell 0 and sort past every
+            # real cell
+            weights = valid.astype(jnp.int32)
+            cids = jnp.where(valid, cids, 0)
+            sort_key = jnp.where(valid, cids, n_cells)
 
-    counts = jax.ops.segment_sum(weights, cids, num_segments=n_cells)
-    offsets = exclusive_prefix_sum(counts)          # (n_cells,)
+        counts = jax.ops.segment_sum(weights, cids, num_segments=n_cells)
+        offsets = exclusive_prefix_sum(counts)      # (n_cells,)
 
-    # Rank of each particle within its cell via one stable sort (the paper's
-    # atomic slot-grab, determinized).
-    order = jnp.argsort(sort_key, stable=True)      # (N,) particle ids, sorted
-    sorted_key = sort_key[order]
-    rank = jnp.arange(n, dtype=jnp.int32) - offsets[
-        jnp.clip(sorted_key, 0, n_cells - 1)]
+        # Rank of each particle within its cell via one stable sort (the
+        # paper's atomic slot-grab, determinized).
+        order = jnp.argsort(sort_key, stable=True)  # (N,) ids, sorted
+        sorted_key = sort_key[order]
+        rank = jnp.arange(n, dtype=jnp.int32) - offsets[
+            jnp.clip(sorted_key, 0, n_cells - 1)]
 
-    # Flat index into the padded planes; ranks >= m_c fall off the end of the
-    # cell's slot range — push them fully out of bounds so 'drop' removes them.
-    cxyz = coords[order]
-    row_len = (nx + 2) * m_c
-    slot_col = (cxyz[:, 0] + 1) * m_c + rank
-    flat = ((cxyz[:, 2] + 1) * (ny + 2) + (cxyz[:, 1] + 1)) * row_len + slot_col
-    total = (nz + 2) * (ny + 2) * row_len
-    keep = (rank < m_c) & (sorted_key < n_cells)
-    flat = jnp.where(keep, flat, total)             # out of range -> dropped
+    with jax.named_scope("bin/scatter"):
+        # Flat index into the padded planes; ranks >= m_c fall off the end
+        # of the cell's slot range — push them fully out of bounds so
+        # 'drop' removes them.
+        cxyz = coords[order]
+        row_len = (nx + 2) * m_c
+        slot_col = (cxyz[:, 0] + 1) * m_c + rank
+        flat = (((cxyz[:, 2] + 1) * (ny + 2) + (cxyz[:, 1] + 1)) * row_len
+                + slot_col)
+        total = (nz + 2) * (ny + 2) * row_len
+        keep = (rank < m_c) & (sorted_key < n_cells)
+        flat = jnp.where(keep, flat, total)         # out of range -> dropped
 
-    shape = padded_shape(domain, m_c)
+        shape = padded_shape(domain, m_c)
 
-    def scatter(values: Array, fill: float) -> Array:
-        plane = jnp.full((total,), fill, dtype=values.dtype)
-        plane = plane.at[flat].set(values[order], mode="drop")
-        return plane.reshape(shape)
+        def scatter(values: Array, fill: float) -> Array:
+            plane = jnp.full((total,), fill, dtype=values.dtype)
+            plane = plane.at[flat].set(values[order], mode="drop")
+            return plane.reshape(shape)
 
-    planes = {
-        "x": scatter(positions[:, 0], EMPTY_POS),
-        "y": scatter(positions[:, 1], EMPTY_POS),
-        "z": scatter(positions[:, 2], EMPTY_POS),
-    }
-    if fields:
-        for k, v in fields.items():
-            planes[k] = scatter(v, 0.0)
+        planes = {
+            "x": scatter(positions[:, 0], EMPTY_POS),
+            "y": scatter(positions[:, 1], EMPTY_POS),
+            "z": scatter(positions[:, 2], EMPTY_POS),
+        }
+        if fields:
+            for k, v in fields.items():
+                planes[k] = scatter(v, 0.0)
 
-    slot_flat = jnp.full((total,), -1, dtype=jnp.int32)
-    slot_flat = slot_flat.at[flat].set(order.astype(jnp.int32), mode="drop")
-    slot_id = slot_flat.reshape(shape)
+        slot_flat = jnp.full((total,), -1, dtype=jnp.int32)
+        slot_flat = slot_flat.at[flat].set(order.astype(jnp.int32),
+                                           mode="drop")
+        slot_id = slot_flat.reshape(shape)
 
-    particle_slot = jnp.zeros((n,), dtype=jnp.int32).at[order].set(
-        flat.astype(jnp.int32), mode="drop")
+        particle_slot = jnp.zeros((n,), dtype=jnp.int32).at[order].set(
+            flat.astype(jnp.int32), mode="drop")
 
     bins = CellBins(planes=planes, slot_id=slot_id, counts=counts,
                     offsets=offsets, particle_slot=particle_slot, m_c=m_c)
@@ -163,9 +174,11 @@ def bin_particles(domain: Domain, positions: Array,
     return bins
 
 
+@device_scope("ghost")
 def _fill_periodic_ghosts(domain: Domain, bins: CellBins) -> CellBins:
     """Copy wrapped interior slabs into the ghost ring (minimum image),
-    per periodic axis."""
+    per periodic axis. Callers keep it outside their ``bin`` /
+    ``bin_refresh`` scope: a sibling, not a child."""
     nx, ny, nz = domain.ncells
     m_c = bins.m_c
     lx, ly, lz = domain.box
@@ -289,26 +302,26 @@ def refresh_bins(domain: Domain, bins: CellBins, positions: Array,
     masked by ``slot_id == -1`` (open boundaries) — harmless either way,
     and an overflowed binning is flagged for replan before results are
     trusted. Padding rows (``valid`` False) are routed out of range and
-    dropped.
+    dropped. Device scope ``bin_refresh``, then ``ghost``.
     """
     total = bins.slot_id.size
-    idx = bins.particle_slot
-    if valid is not None:
-        idx = jnp.where(valid, idx, total)
-
     planes = {}
-    for name, plane in bins.planes.items():
-        if name == "x":
-            vals = positions[:, 0]
-        elif name == "y":
-            vals = positions[:, 1]
-        elif name == "z":
-            vals = positions[:, 2]
-        else:
-            vals = (fields or {})[name]
-        flat = plane.reshape(-1).at[idx].set(
-            vals.astype(plane.dtype), mode="drop")
-        planes[name] = flat.reshape(plane.shape)
+    with jax.named_scope("bin_refresh"):
+        idx = bins.particle_slot
+        if valid is not None:
+            idx = jnp.where(valid, idx, total)
+        for name, plane in bins.planes.items():
+            if name == "x":
+                vals = positions[:, 0]
+            elif name == "y":
+                vals = positions[:, 1]
+            elif name == "z":
+                vals = positions[:, 2]
+            else:
+                vals = (fields or {})[name]
+            flat = plane.reshape(-1).at[idx].set(
+                vals.astype(plane.dtype), mode="drop")
+            planes[name] = flat.reshape(plane.shape)
 
     out = dataclasses.replace(bins, planes=planes)
     if domain.any_periodic:
@@ -545,6 +558,7 @@ def padded_row_counts(domain: Domain, counts: Array) -> Array:
     return per_row
 
 
+@device_scope("bin/pack")
 def pack_rows(domain: Domain, bins: CellBins, row_cap: int) -> PackedRows:
     """Compact a dense :class:`CellBins` into the packed-row (CSR) layout.
 
@@ -635,6 +649,7 @@ def unpack_scatter(domain: Domain, packed: PackedRows,
     return padded.reshape(-1)[packed.particle_slot]
 
 
+@device_scope("scatter_back")
 def packed_to_particles(domain: Domain, packed: PackedRows, fx: Array,
                         fy: Array, fz: Array, pot: Array
                         ) -> Tuple[Array, Array]:
@@ -664,6 +679,7 @@ def interior_to_padded(domain: Domain, plane: Array, m_c: int) -> Array:
         plane.reshape(nz, ny, nx * m_c))
 
 
+@device_scope("scatter_back")
 def dense_to_particles(domain: Domain, bins: CellBins, fx: Array, fy: Array,
                        fz: Array, pot: Array) -> Tuple[Array, Array]:
     """Normalize dense (nz, ny, nx, m_c) schedule outputs to per-particle
@@ -949,6 +965,7 @@ class SfcClusters:
         return self.n_pairs > self.pair_cap
 
 
+@device_scope("bin/sfc")
 def build_sfc_clusters(domain: Domain, bins: CellBins, pair_cap: int,
                        csize: int = DEFAULT_CSIZE,
                        curve: str = DEFAULT_CURVE) -> SfcClusters:
@@ -1031,6 +1048,7 @@ def sfc_pair_count(domain: Domain, positions: Array | None = None, *,
     return int(((cc[:, None] > 0) & (sc > 0)).sum())
 
 
+@device_scope("scatter_back")
 def sfc_to_particles(domain: Domain, sfc: SfcClusters, fx: Array, fy: Array,
                      fz: Array, pot: Array) -> Tuple[Array, Array]:
     """Normalize SFC cluster-tile outputs ``(n_clusters, csize * m_c)`` to

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from ..core.binning import (EMPTY_POS, cell_counts, shard_pencil_active,
                             shard_slab_counts)
 from ..core.domain import Domain
+from ..obs.trace import device_scope
 
 Array = jnp.ndarray
 
@@ -140,6 +141,7 @@ def scatter_from_shards(gather_idx: Array, n: int, values: Array) -> Array:
 # the ghost-plane exchange (inside shard_map)
 # --------------------------------------------------------------------------
 
+@device_scope("exchange")
 def exchange_halo(plane: Array, *, axis: str, n_shards: int, nz_loc: int,
                   shard_index: Array, periodic_z: bool, fill,
                   coord_shift: float = 0.0) -> Array:

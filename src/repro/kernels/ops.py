@@ -4,12 +4,17 @@ Backend dispatch: ``interpret=None`` (default) runs the kernel body natively
 on TPU and in interpret mode everywhere else — so the same call sites work in
 CPU tests/dry-runs and on real hardware. The model/engine layers default to
 the pure-JAX paths and opt into these kernels via ``implementation="pallas"``.
+
+Device scopes: each ``*_interactions`` entry runs its occupancy, lane
+staging (``to_lanes`` / ``from_lanes``) and ``pallas_call`` under ``pair``,
+and the way back to particle order under ``scatter_back``.
 """
 
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 import numpy as np
@@ -21,6 +26,7 @@ from ..core.binning import (EMPTY_POS, CellBins, PackedRows, SfcClusters,
                             sfc_to_particles)
 from ..core.domain import Domain
 from ..core.interactions import PairKernel
+from ..obs.trace import device_scope
 from ._platform import resolve_interpret as _interpret
 from .allin import allin_forces
 from .prefix_sum import prefix_sum as _prefix_sum
@@ -36,9 +42,11 @@ def xpencil_interactions(domain: Domain, bins: CellBins, kernel: PairKernel,
                          interpret: Optional[bool] = None
                          ) -> Tuple[Array, Array]:
     """X-pencil kernel -> per-particle (forces (N,3), potential (N,))."""
-    fx, fy, fz, pot = xpencil_forces(
-        bins.planes, bins.slot_id, nx=domain.nx, m_c=bins.m_c, kernel=kernel,
-        cutoff2=float(domain.cutoff) ** 2, interpret=_interpret(interpret))
+    with jax.named_scope("pair"):
+        fx, fy, fz, pot = xpencil_forces(
+            bins.planes, bins.slot_id, nx=domain.nx, m_c=bins.m_c,
+            kernel=kernel, cutoff2=float(domain.cutoff) ** 2,
+            interpret=_interpret(interpret))
     return _to_particles(domain, bins, fx, fy, fz, pot)
 
 
@@ -56,19 +64,22 @@ def xpencil_sparse_interactions(domain: Domain, bins: CellBins,
     exactly like an overflowing ``m_c``.
     """
     nx, ny, nz = domain.ncells
-    occ = pencil_occupancy(domain, bins.counts, max_active)
-    compact = xpencil_sparse_forces(
-        bins.planes, bins.slot_id, occ.active, nx=nx, ny=ny, m_c=bins.m_c,
-        kernel=kernel, cutoff2=float(domain.cutoff) ** 2,
-        interpret=_interpret(interpret))
-    idx = occ.scatter_indices()
+    with jax.named_scope("pair"):
+        occ = pencil_occupancy(domain, bins.counts, max_active)
+        compact = xpencil_sparse_forces(
+            bins.planes, bins.slot_id, occ.active, nx=nx, ny=ny,
+            m_c=bins.m_c, kernel=kernel, cutoff2=float(domain.cutoff) ** 2,
+            interpret=_interpret(interpret))
 
-    def scatter(rows: Array) -> Array:      # (max_active, nx*m_c) -> dense
-        dense = jnp.zeros((nz * ny, nx * bins.m_c), rows.dtype)
-        return dense.at[idx].set(rows, mode="drop").reshape(
-            nz, ny, nx * bins.m_c)
+    with jax.named_scope("scatter_back"):
+        idx = occ.scatter_indices()
 
-    fx, fy, fz, pot = (scatter(r) for r in compact)
+        def scatter(rows: Array) -> Array:  # (max_active, nx*m_c) -> dense
+            dense = jnp.zeros((nz * ny, nx * bins.m_c), rows.dtype)
+            return dense.at[idx].set(rows, mode="drop").reshape(
+                nz, ny, nx * bins.m_c)
+
+        fx, fy, fz, pot = (scatter(r) for r in compact)
     return _to_particles(domain, bins, fx, fy, fz, pot)
 
 
@@ -87,20 +98,24 @@ def xpencil_packed_interactions(domain: Domain, packed: PackedRows,
     contract (``InteractionPlan.check_overflow``).
     """
     nx, ny, nz = domain.ncells
-    occ = (full_pencil_occupancy(domain) if max_active is None
-           else pencil_occupancy(domain, packed.counts, max_active))
-    compact = xpencil_packed_forces(
-        packed.planes, packed.slot_id, packed.slot_cell,
-        packed.cell_offsets, occ.active, nx=nx, ny=ny, m_c=packed.m_c,
-        row_cap=packed.row_cap, kernel=kernel,
-        cutoff2=float(domain.cutoff) ** 2, interpret=_interpret(interpret))
-    idx = occ.scatter_indices()
+    with jax.named_scope("pair"):
+        occ = (full_pencil_occupancy(domain) if max_active is None
+               else pencil_occupancy(domain, packed.counts, max_active))
+        compact = xpencil_packed_forces(
+            packed.planes, packed.slot_id, packed.slot_cell,
+            packed.cell_offsets, occ.active, nx=nx, ny=ny, m_c=packed.m_c,
+            row_cap=packed.row_cap, kernel=kernel,
+            cutoff2=float(domain.cutoff) ** 2,
+            interpret=_interpret(interpret))
 
-    def scatter(rows: Array) -> Array:      # (n_rows, row_cap) -> packed
-        dense = jnp.zeros((nz * ny, packed.row_cap), rows.dtype)
-        return dense.at[idx].set(rows, mode="drop")
+    with jax.named_scope("scatter_back"):
+        idx = occ.scatter_indices()
 
-    fx, fy, fz, pot = (scatter(r) for r in compact)
+        def scatter(rows: Array) -> Array:  # (n_rows, row_cap) -> packed
+            dense = jnp.zeros((nz * ny, packed.row_cap), rows.dtype)
+            return dense.at[idx].set(rows, mode="drop")
+
+        fx, fy, fz, pot = (scatter(r) for r in compact)
     return packed_to_particles(domain, packed, fx, fy, fz, pot)
 
 
@@ -119,6 +134,13 @@ def cell_sfc_interactions(domain: Domain, sfc: SfcClusters,
     the reference runner, whose fully-masked stencil terms accumulate
     exact (+0.0) zeros.
     """
+    return sfc_to_particles(domain, sfc,
+                            *_sfc_pair(domain, sfc, kernel, interpret))
+
+
+@device_scope("pair")
+def _sfc_pair(domain: Domain, sfc: SfcClusters, kernel: PairKernel,
+              interpret: Optional[bool]) -> Tuple[Array, ...]:
     bins = sfc.bins
     m_c, csize = bins.m_c, sfc.csize
     tables = sfc_cluster_tables(domain, csize, sfc.curve)
@@ -156,21 +178,21 @@ def cell_sfc_interactions(domain: Domain, sfc: SfcClusters,
 
     kept = jnp.zeros((n_clusters + 1,), jnp.int32).at[codes >> 5].add(1)
     has = (kept[:n_clusters] > 0)[:, None]
-    fx, fy, fz, pot = (
+    return tuple(
         jnp.where(has, jnp.swapaxes(o[:n_clusters], 1, 2).reshape(
             n_clusters, csize * m_c), 0.0)
         for o in (fx, fy, fz, pot))
-    return sfc_to_particles(domain, sfc, fx, fy, fz, pot)
 
 
 def allin_interactions(domain: Domain, bins: CellBins, kernel: PairKernel,
                        box, interpret: Optional[bool] = None
                        ) -> Tuple[Array, Array]:
     """All-in-SM kernel -> per-particle (forces, potential)."""
-    fx, fy, fz, pot = allin_forces(
-        bins.planes, bins.slot_id, box=tuple(box), m_c=bins.m_c,
-        kernel=kernel, cutoff2=float(domain.cutoff) ** 2,
-        interpret=_interpret(interpret))
+    with jax.named_scope("pair"):
+        fx, fy, fz, pot = allin_forces(
+            bins.planes, bins.slot_id, box=tuple(box), m_c=bins.m_c,
+            kernel=kernel, cutoff2=float(domain.cutoff) ** 2,
+            interpret=_interpret(interpret))
     return _to_particles(domain, bins, fx, fy, fz, pot)
 
 

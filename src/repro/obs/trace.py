@@ -5,10 +5,23 @@ the ``obs.tracing()`` context manager, or the ``REPRO_OBS_TRACE``
 environment variable), instrumented code records *spans* — named,
 attributed durations from ``with obs.trace(name, **attrs):`` — and
 instantaneous *events* (``obs.event(name, **attrs)``) into a bounded
-in-memory ring buffer. When disabled, ``trace()`` returns a shared no-op
-span and ``event()`` returns immediately: the hot path
-(``InteractionPlan.execute``) pays one predicate per dispatch and records
-nothing — the zero-overhead contract ``tests/test_obs.py`` asserts.
+in-memory ring buffer.
+
+Independently of the ring, while a ``jax.profiler`` session records
+(``jax.profiler.trace(dir)``, ``start_trace``), every span and event also
+enters a ``jax.profiler.TraceAnnotation`` of the same name and attributes,
+so the engine's host spans land in the profiler's XSpace beside the device
+ops, on its clock. With the ring off and no profiler recording,
+``trace()`` returns a shared no-op span and ``event()`` returns at once:
+the hot path (``InteractionPlan.execute``) pays one predicate per dispatch
+(:func:`active`) and records nothing — the zero-overhead contract
+``tests/test_obs.py`` asserts.
+
+Clock: the profiler's, wall-clock nanoseconds (``time.time_ns()``; the
+profiler stamps its events from the same clock). Ring records hold seconds
+since the ring's origin, and ``origin_ns`` (in :func:`stats` and in both
+exports) is that origin in absolute nanoseconds: ``origin_ns + ts * 1e9``
+is the record's place on an XSpace's timeline.
 
 Exports: :func:`export_jsonl` (one JSON object per record) and
 :func:`export_chrome_trace` (Chrome ``trace_event`` JSON — load it at
@@ -18,9 +31,10 @@ converts and summarizes the JSONL form offline.
 Record schema (the JSONL form)::
 
     {"name": "plan.execute", "ph": "X",     # "X" span | "i" instant
-     "ts": 0.0123,                          # seconds since enable()
+     "ts": 0.0123,                          # seconds since the origin
      "dur": 0.0004,                         # seconds (spans only)
-     "tid": 140023, "attrs": {...}}
+     "tid": 140023, "attrs": {...},
+     "origin_ns": 1792306374538984122}      # the origin (exports only)
 
 The buffer is a ``collections.deque(maxlen=capacity)``: a long run keeps
 the newest ``capacity`` records and counts what it dropped
@@ -31,6 +45,7 @@ unbounded memory.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import pathlib
@@ -38,21 +53,30 @@ import threading
 import time
 from typing import Deque, Dict, List, Optional
 
-__all__ = ["trace", "event", "enable", "disable", "tracing",
-           "tracing_enabled", "spans", "clear", "stats",
+import jax
+from jax.profiler import TraceAnnotation as _Annotation
+
+__all__ = ["trace", "event", "device_scope", "enable", "disable", "tracing",
+           "tracing_enabled", "active", "spans", "clear", "stats",
            "export_jsonl", "export_chrome_trace", "DEFAULT_CAPACITY"]
 
 DEFAULT_CAPACITY = 65536
 
 _enabled = False
 _buf: Deque[dict] = collections.deque(maxlen=DEFAULT_CAPACITY)
-_t0 = 0.0
+_t0 = 0                    # the ring's origin, time.time_ns(); 0 = unset
 _total = 0                 # records ever offered (drops = _total - len(_buf))
 
 
 def tracing_enabled() -> bool:
-    """True while the tracer records (the one predicate hot paths pay)."""
+    """True while the ring buffer records."""
     return _enabled
+
+
+def active() -> bool:
+    """True while spans go anywhere: the ring records or a profiler
+    session does (the one predicate hot paths pay)."""
+    return _enabled or _Annotation.is_enabled()
 
 
 def enable(capacity: Optional[int] = None) -> None:
@@ -62,8 +86,8 @@ def enable(capacity: Optional[int] = None) -> None:
     global _enabled, _buf, _t0
     if capacity is not None and capacity != _buf.maxlen:
         _buf = collections.deque(_buf, maxlen=int(capacity))
-    if not _enabled and _t0 == 0.0:
-        _t0 = time.perf_counter()
+    if not _enabled and _t0 == 0:
+        _t0 = time.time_ns()
     _enabled = True
 
 
@@ -78,7 +102,7 @@ def clear() -> None:
     global _total, _t0
     _buf.clear()
     _total = 0
-    _t0 = time.perf_counter() if _enabled else 0.0
+    _t0 = time.time_ns() if _enabled else 0
 
 
 def spans() -> List[dict]:
@@ -87,9 +111,11 @@ def spans() -> List[dict]:
 
 
 def stats() -> Dict[str, int]:
-    """Ring-buffer accounting: recorded / capacity / dropped."""
+    """Ring-buffer accounting: recorded / capacity / dropped, and the
+    origin of ``ts`` in absolute nanoseconds (``origin_ns``)."""
     return {"recorded": len(_buf), "capacity": int(_buf.maxlen or 0),
-            "dropped": _total - len(_buf), "enabled": int(_enabled)}
+            "dropped": _total - len(_buf), "enabled": int(_enabled),
+            "origin_ns": _t0}
 
 
 class tracing:
@@ -123,14 +149,19 @@ def _record(rec: dict) -> None:
 
 class _Span:
     """A live span: ``with obs.trace(name, **attrs) as sp: sp.set(...)``.
-    Recorded at exit; an exception inside marks ``attrs["error"]``."""
+    Enters a profiler annotation when a profiler session records (with the
+    attributes given at creation; ``set`` reaches the ring record only),
+    and is recorded in the ring at exit when ``ring``; an exception inside
+    marks ``attrs["error"]``."""
 
-    __slots__ = ("name", "attrs", "_start")
+    __slots__ = ("name", "attrs", "_start", "_ring", "_annotation")
 
-    def __init__(self, name: str, attrs: dict):
+    def __init__(self, name: str, attrs: dict, ring: bool):
         self.name = name
         self.attrs = attrs
-        self._start = 0.0
+        self._start = 0
+        self._ring = ring
+        self._annotation = None
 
     def set(self, **attrs) -> "_Span":
         """Annotate the span mid-flight (no-op on the disabled tracer)."""
@@ -138,16 +169,25 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
-        self._start = time.perf_counter()
+        if _Annotation.is_enabled():
+            self._annotation = _Annotation(self.name, **self.attrs)
+            self._annotation.__enter__()
+        self._start = time.time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        end = time.perf_counter()
+        end = time.time_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
+        if not self._ring:
+            return False
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
-        _record({"name": self.name, "ph": "X", "ts": self._start - _t0,
-                 "dur": end - self._start, "tid": threading.get_ident(),
-                 "attrs": self.attrs})
+        _record({"name": self.name, "ph": "X",
+                 "ts": (self._start - _t0) * 1e-9,
+                 "dur": (end - self._start) * 1e-9,
+                 "tid": threading.get_ident(), "attrs": self.attrs})
         return False
 
 
@@ -170,22 +210,44 @@ _NULL = _NullSpan()
 
 
 def trace(name: str, **attrs):
-    """A span context manager around a named operation.
+    """A span context manager around a named operation, recorded in the
+    ring when it is on and in the profiler's trace while one records.
 
-    Cheap by construction: when tracing is disabled this returns one
-    shared no-op object — no allocation, no clock read, nothing recorded.
+    Cheap by construction: when neither records this returns one shared
+    no-op object — no allocation, no clock read, nothing recorded.
     Attribute values should be JSON-able scalars (str/int/float/bool)."""
-    if not _enabled:
-        return _NULL
-    return _Span(name, attrs)
+    if _enabled:
+        return _Span(name, attrs, ring=True)
+    if _Annotation.is_enabled():
+        return _Span(name, attrs, ring=False)
+    return _NULL
 
 
 def event(name: str, **attrs) -> None:
-    """Record one instantaneous event (Chrome ``ph: "i"``)."""
+    """Record one instantaneous event (Chrome ``ph: "i"``); a profiler
+    session sees it as a span of no length."""
+    if _Annotation.is_enabled():
+        with _Annotation(name, **attrs):
+            pass
     if not _enabled:
         return
-    _record({"name": name, "ph": "i", "ts": time.perf_counter() - _t0,
+    _record({"name": name, "ph": "i", "ts": (time.time_ns() - _t0) * 1e-9,
              "tid": threading.get_ident(), "attrs": attrs})
+
+
+def device_scope(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    the device ops it issues carry ``name`` in their ``op_name`` (the
+    engine's layer names, ARCHITECTURE.md "Device scopes"). A fresh scope
+    per call: one ``named_scope`` used as a decorator is shared by every
+    call and holds state between its enter and exit."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 # --------------------------------------------------------------------------
@@ -193,13 +255,14 @@ def event(name: str, **attrs) -> None:
 # --------------------------------------------------------------------------
 
 def export_jsonl(path) -> int:
-    """Write the buffer as JSON Lines (one record per line). -> count."""
+    """Write the buffer as JSON Lines (one record per line, each with the
+    ring's ``origin_ns``). -> count."""
     p = pathlib.Path(path)
     p.parent.mkdir(parents=True, exist_ok=True)
     recs = spans()
     with open(p, "w") as f:
         for rec in recs:
-            f.write(json.dumps(rec, default=str) + "\n")
+            f.write(json.dumps(dict(rec, origin_ns=_t0), default=str) + "\n")
     return len(recs)
 
 
@@ -224,13 +287,16 @@ def chrome_events(records: Optional[List[dict]] = None) -> List[dict]:
 def export_chrome_trace(path, records: Optional[List[dict]] = None) -> int:
     """Write the buffer (or ``records``) as a Chrome ``trace_event`` file
     (``{"traceEvents": [...]}``) viewable at ``chrome://tracing`` or
-    https://ui.perfetto.dev. -> event count."""
+    https://ui.perfetto.dev; ``otherData.origin_ns`` is the origin of
+    ``ts``. -> event count."""
     p = pathlib.Path(path)
     p.parent.mkdir(parents=True, exist_ok=True)
     evs = chrome_events(records)
+    origin = _t0 if records is None else (
+        records[0].get("origin_ns") if records else None)
     with open(p, "w") as f:
-        json.dump({"traceEvents": evs,
-                   "displayTimeUnit": "ms"}, f, default=str)
+        json.dump({"traceEvents": evs, "displayTimeUnit": "ms",
+                   "otherData": {"origin_ns": origin}}, f, default=str)
     return len(evs)
 
 

@@ -328,8 +328,9 @@ def _segment_exec(p: InteractionPlan, integrator: str, seg_len: int,
     def make_body(dt, gamma, kT, fields, valid):
         def body(carry: TrajCarry, _):
             md = carry.md
-            pos, v_staged, rng = _integ_drift(integrator, dom, mass, md,
-                                              carry.rng, dt, gamma, kT)
+            with jax.named_scope("integrate"):
+                pos, v_staged, rng = _integ_drift(integrator, dom, mass, md,
+                                                  carry.rng, dt, gamma, kT)
 
             disp = max_displacement(dom, pos, carry.ref, valid)
             step_disp = max_displacement(dom, pos, md.positions, valid)
@@ -349,7 +350,8 @@ def _segment_exec(p: InteractionPlan, integrator: str, seg_len: int,
             # nearest the binned reference — exactly ``pos`` after a rebin
             img = image_positions(dom, pos, ref)
             forces, pot = _forces(p, bins, img, fields, valid)
-            vel = _integ_kick(integrator, mass, v_staged, forces, dt)
+            with jax.named_scope("integrate"):
+                vel = _integ_kick(integrator, mass, v_staged, forces, dt)
 
             md2 = MDState(pos, vel, forces, pot, md.step + 1)
             ke, pe = _masked_energies(vel, pot, valid, mass)

@@ -1,4 +1,4 @@
-"""Observability layer: tracer, metrics registry, audit, profile, shims.
+"""Observability layer: tracer, profiler spans, metrics registry, audit, shims.
 
 The contracts under test, in the order the module docstrings state them:
 
@@ -7,6 +7,8 @@ The contracts under test, in the order the module docstrings state them:
 * enabled tracing records spans/events with attrs and exports both JSONL
   and Chrome ``trace_event`` JSON that parse and carry the span names the
   instrumented subsystems emit;
+* while a ``jax.profiler`` session records, the same spans land in its
+  XSpace, and the ring's absolute origin puts them on its clock;
 * the metrics registry is the one counter store: the historical
   ``dispatch_count`` / ``recompile_count`` / ``replan_count`` /
   ``timing_run_count`` functions are shims over it, ``render_prom``
@@ -291,16 +293,67 @@ def test_tune_audits_pruned_candidates(tmp_path, monkeypatch):
     assert any(abs(v) > 0.3 for v in snap.values()), snap
 
 
-# --------------------------------------------------------------- profile
+# -------------------------------------------------------------- profiler
 
-def test_profile_times_and_audits(tiny):
+def _xspace_host_events(trace_dir) -> dict:
+    """name -> [(start_ns, end_ns)] of the host events of the one XSpace
+    under ``trace_dir``, in absolute nanoseconds (the XSpace holds times
+    from its ``profile_start_time``)."""
+    import glob
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    env, = [dict(pl.stats) for pl in pd.planes
+            if pl.name == "Task Environment"]
+    t0 = int(env["profile_start_time"])
+    out = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (t0 + e.start_ns, t0 + e.end_ns))
+    return out
+
+
+def test_span_lands_in_the_profiler_trace_on_its_clock(tiny, tmp_path):
     _, _, p, state = tiny
-    rep = obs.profile(p, state, budget_s=0.02)
-    assert rep.seconds_per_call > 0 and rep.reps >= 1
-    assert rep.strategy == p.strategy and rep.layout == p.layout
-    assert math.isfinite(rep.drift)
-    # one histogram observation per profile() call (seconds_per_call)
-    assert obs.registry.total("repro_execute_seconds") >= 1
+    obs.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(p.execute(state))
+    host = _xspace_host_events(tmp_path)
+    (start, end), = host["plan.execute"]
+    rec, = [r for r in obs.spans() if r["name"] == "plan.execute"]
+    origin = obs.stats()["origin_ns"]
+    assert abs(origin + rec["ts"] * 1e9 - start) < 1e6        # 1 ms
+    assert abs(origin + (rec["ts"] + rec["dur"]) * 1e9 - end) < 1e6
+
+
+def test_profiler_alone_records_spans_and_leaves_the_ring_empty(tiny,
+                                                                 tmp_path):
+    _, _, p, state = tiny
+    assert not obs.active()
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs.active() and not obs.tracing_enabled()
+        jax.block_until_ready(p.execute(state))
+        obs.event("marker", k="v")
+    assert not obs.active()
+    host = _xspace_host_events(tmp_path)
+    assert len(host["plan.execute"]) == 1 and "marker" in host
+    assert obs.stats()["recorded"] == 0 and obs.spans() == []
+
+
+def test_exports_carry_the_ring_origin(tmp_path):
+    obs.enable()
+    obs.event("marker")
+    origin = obs.stats()["origin_ns"]
+    assert origin > 0
+    obs.export_jsonl(tmp_path / "t.jsonl")
+    obs.export_chrome_trace(tmp_path / "t.json")
+    rec, = [json.loads(l) for l in
+            (tmp_path / "t.jsonl").read_text().splitlines()]
+    assert rec["origin_ns"] == origin
+    payload = json.loads((tmp_path / "t.json").read_text())
+    assert payload["otherData"]["origin_ns"] == origin
 
 
 # ------------------------------------------------------------- sidecars
