@@ -4,7 +4,10 @@ Pipeline (paper order, atomic-free):
   1. per-particle cell index (parallel),
   2. per-cell counts      -> ``jax.ops.segment_sum`` (replaces atomics),
   3. cell start offsets   -> the paper's prefix sum (``core.prefix``),
-  4. out-of-place reorder -> stable argsort by cell id + rank-in-cell,
+  4. out-of-place reorder -> one sort by (cell id, particle id) that carries
+     x/y/z and every field along (no gather by its permutation); cell
+     coordinates are decoded from the sorted id, rank-in-cell from the
+     offsets,
   5. **dense cell-slot layout**: every cell owns exactly ``m_c`` contiguous
      slots in SoA planes of shape ``(nz+2, ny+2, (nx+2)*m_c)``.
 
@@ -98,8 +101,14 @@ def bin_particles(domain: Domain, positions: Array,
       valid: optional (N,) bool mask; False rows (e.g. the sentinel padding a
         halo shard carries) are excluded from counts and never land in a slot.
 
-    Device scopes: ``bin/sort`` (cell ids, counts, prefix, stable sort,
-    rank), ``bin/scatter`` (the slot planes, ``slot_id``,
+    The sort by (cell id, particle id) carries x/y/z and every field as
+    payload, so the sorted columns come out of the sort and no column is
+    gathered by its permutation; each row's cell coordinates are decoded
+    from its sorted cell id. Slot order is that of a stable argsort by
+    cell id.
+
+    Device scopes: ``bin/sort`` (cell ids, counts, prefix, the payload
+    sort, rank), ``bin/scatter`` (the slot planes, ``slot_id``,
     ``particle_slot``), then ``ghost`` for a periodic domain.
     """
     n = positions.shape[0]
@@ -107,8 +116,9 @@ def bin_particles(domain: Domain, positions: Array,
     n_cells = domain.n_cells
 
     with jax.named_scope("bin/sort"):
-        coords = domain.cell_coords(positions)      # (N, 3) int32
-        cids = domain.linearize(coords)             # (N,)
+        cids = domain.cell_ids(positions)           # (N,)
+        columns = [positions[:, 0], positions[:, 1], positions[:, 2],
+                   *(fields or {}).values()]
 
         if valid is None:
             weights = jnp.ones((n,), jnp.int32)
@@ -123,22 +133,24 @@ def bin_particles(domain: Domain, positions: Array,
         counts = jax.ops.segment_sum(weights, cids, num_segments=n_cells)
         offsets = exclusive_prefix_sum(counts)      # (n_cells,)
 
-        # Rank of each particle within its cell via one stable sort (the
-        # paper's atomic slot-grab, determinized).
-        order = jnp.argsort(sort_key, stable=True)  # (N,) ids, sorted
-        sorted_key = sort_key[order]
-        rank = jnp.arange(n, dtype=jnp.int32) - offsets[
-            jnp.clip(sorted_key, 0, n_cells - 1)]
+        # Rank of each particle within its cell via one sort (the paper's
+        # atomic slot-grab, determinized). The columns ride along: on a
+        # v5e a gather by the sort's permutation costs ~16x a sorted column.
+        sorted_key, order, *sorted_cols = jax.lax.sort(
+            (sort_key, jnp.arange(n, dtype=jnp.int32), *columns),
+            num_keys=2)
+        key = jnp.clip(sorted_key, 0, n_cells - 1)
+        rank = jnp.arange(n, dtype=jnp.int32) - offsets[key]
 
     with jax.named_scope("bin/scatter"):
-        # Flat index into the padded planes; ranks >= m_c fall off the end
-        # of the cell's slot range — push them fully out of bounds so
-        # 'drop' removes them.
-        cxyz = coords[order]
+        # Flat index into the padded planes, with the cell coordinates
+        # decoded from the sorted key; ranks >= m_c fall off the end of the
+        # cell's slot range — push them fully out of bounds so 'drop'
+        # removes them.
+        ix, iy, iz = key % nx, (key // nx) % ny, key // (nx * ny)
         row_len = (nx + 2) * m_c
-        slot_col = (cxyz[:, 0] + 1) * m_c + rank
-        flat = (((cxyz[:, 2] + 1) * (ny + 2) + (cxyz[:, 1] + 1)) * row_len
-                + slot_col)
+        slot_col = (ix + 1) * m_c + rank
+        flat = ((iz + 1) * (ny + 2) + (iy + 1)) * row_len + slot_col
         total = (nz + 2) * (ny + 2) * row_len
         keep = (rank < m_c) & (sorted_key < n_cells)
         flat = jnp.where(keep, flat, total)         # out of range -> dropped
@@ -147,21 +159,16 @@ def bin_particles(domain: Domain, positions: Array,
 
         def scatter(values: Array, fill: float) -> Array:
             plane = jnp.full((total,), fill, dtype=values.dtype)
-            plane = plane.at[flat].set(values[order], mode="drop")
+            plane = plane.at[flat].set(values, mode="drop")
             return plane.reshape(shape)
 
-        planes = {
-            "x": scatter(positions[:, 0], EMPTY_POS),
-            "y": scatter(positions[:, 1], EMPTY_POS),
-            "z": scatter(positions[:, 2], EMPTY_POS),
-        }
-        if fields:
-            for k, v in fields.items():
-                planes[k] = scatter(v, 0.0)
+        planes = {name: scatter(col, EMPTY_POS)
+                  for name, col in zip("xyz", sorted_cols)}
+        for name, col in zip(fields or {}, sorted_cols[3:]):
+            planes[name] = scatter(col, 0.0)
 
         slot_flat = jnp.full((total,), -1, dtype=jnp.int32)
-        slot_flat = slot_flat.at[flat].set(order.astype(jnp.int32),
-                                           mode="drop")
+        slot_flat = slot_flat.at[flat].set(order, mode="drop")
         slot_id = slot_flat.reshape(shape)
 
         particle_slot = jnp.zeros((n,), dtype=jnp.int32).at[order].set(

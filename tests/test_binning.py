@@ -7,6 +7,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import Domain, bin_particles, gather_to_particles, suggest_m_c
+from repro.core import binning
 from repro.core.binning import EMPTY_POS, interior
 
 
@@ -200,3 +201,131 @@ def test_thin_axis_single_particle_sees_no_self_force():
                 strategy="xpencil").execute(state)
     np.testing.assert_array_equal(np.asarray(f), np.zeros((1, 3)))
     np.testing.assert_array_equal(np.asarray(q), np.zeros((1,)))
+
+
+# ---------------------------------------------------------------------------
+# the payload sort against the argsort-plus-gather formulation: bin_particles
+# sorts (cell id, particle id) with x/y/z and every field carried along; the
+# oracle below sorts ids alone and gathers each column by the permutation.
+# Every CellBins field must come out bit-identical.
+# ---------------------------------------------------------------------------
+
+def _argsort_gather_bins(domain, positions, fields=None, *, m_c, valid=None):
+    n = positions.shape[0]
+    nx, ny, nz = domain.ncells
+    n_cells = domain.n_cells
+    coords = domain.cell_coords(positions)
+    cids = domain.linearize(coords)
+    if valid is None:
+        weights = jnp.ones((n,), jnp.int32)
+        sort_key = cids
+    else:
+        weights = valid.astype(jnp.int32)
+        cids = jnp.where(valid, cids, 0)
+        sort_key = jnp.where(valid, cids, n_cells)
+    counts = jax.ops.segment_sum(weights, cids, num_segments=n_cells)
+    offsets = jnp.concatenate(
+        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]])
+    order = jnp.argsort(sort_key, stable=True)
+    sorted_key = sort_key[order]
+    rank = jnp.arange(n, dtype=jnp.int32) - offsets[
+        jnp.clip(sorted_key, 0, n_cells - 1)]
+    cxyz = coords[order]
+    row_len = (nx + 2) * m_c
+    flat = (((cxyz[:, 2] + 1) * (ny + 2) + (cxyz[:, 1] + 1)) * row_len
+            + (cxyz[:, 0] + 1) * m_c + rank)
+    total = (nz + 2) * (ny + 2) * row_len
+    flat = jnp.where((rank < m_c) & (sorted_key < n_cells), flat, total)
+    shape = binning.padded_shape(domain, m_c)
+
+    def scatter(values, fill):
+        plane = jnp.full((total,), fill, values.dtype)
+        return plane.at[flat].set(values[order], mode="drop").reshape(shape)
+
+    planes = {k: scatter(positions[:, i], EMPTY_POS)
+              for i, k in enumerate("xyz")}
+    for k, v in (fields or {}).items():
+        planes[k] = scatter(v, 0.0)
+    slot_id = jnp.full((total,), -1, jnp.int32).at[flat].set(
+        order.astype(jnp.int32), mode="drop").reshape(shape)
+    particle_slot = jnp.zeros((n,), jnp.int32).at[order].set(
+        flat.astype(jnp.int32), mode="drop")
+    bins = binning.CellBins(planes=planes, slot_id=slot_id, counts=counts,
+                            offsets=offsets, particle_slot=particle_slot,
+                            m_c=m_c)
+    if domain.any_periodic:
+        bins = binning._fill_periodic_ghosts(domain, bins)
+    return bins
+
+
+def _grid_case(seed, periodic, n=5000):
+    dom = Domain(box=(12.0, 10.0, 8.0), ncells=(12, 10, 8), cutoff=1.0,
+                 periodic=periodic)
+    pos = dom.sample_uniform(jax.random.PRNGKey(seed), n)
+    return dom, pos
+
+
+def _assert_bins_equal(got, want):
+    assert got.m_c == want.m_c
+    assert sorted(got.planes) == sorted(want.planes)
+    for k in want.planes:
+        np.testing.assert_array_equal(np.asarray(got.planes[k]),
+                                      np.asarray(want.planes[k]), err_msg=k)
+    for k in ("slot_id", "counts", "offsets", "particle_slot"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, k)),
+                                      np.asarray(getattr(want, k)),
+                                      err_msg=k)
+
+
+@pytest.mark.parametrize("n_fields", [0, 2])
+@pytest.mark.parametrize("m_c", [16, 6])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("periodic", [False, True])
+def test_payload_sort_matches_argsort_gather(periodic, masked, m_c,
+                                             n_fields):
+    dom, pos = _grid_case(11, periodic)
+    n = pos.shape[0]
+    rng = np.random.RandomState(3)
+    valid = jnp.asarray(rng.rand(n) > 0.2) if masked else None
+    fields = {f"f{i}": jnp.asarray(rng.randn(n), jnp.float32)
+              for i in range(n_fields)}
+    if m_c == 6:    # small enough that some cells overflow
+        assert int(jnp.max(binning.cell_counts(dom, pos, valid))) > m_c
+    got = jax.jit(lambda p, f, v: bin_particles(dom, p, f, m_c=m_c,
+                                                valid=v))(pos, fields, valid)
+    want = _argsort_gather_bins(dom, pos, fields, m_c=m_c, valid=valid)
+    _assert_bins_equal(got, want)
+
+
+@pytest.mark.parametrize("periodic", [False, True])
+def test_payload_sort_matches_argsort_gather_under_vmap(periodic):
+    dom, _ = _grid_case(0, periodic)
+    states = jnp.stack([_grid_case(s, periodic)[1] for s in (21, 22)])
+    fields = {"mass": jnp.stack([jnp.full((5000,), 1.5, jnp.float32),
+                                 jnp.linspace(0.0, 1.0, 5000)])}
+    got = jax.vmap(lambda p, f: bin_particles(dom, p, f, m_c=8))(
+        states, fields)
+    for b in range(2):
+        want = _argsort_gather_bins(
+            dom, states[b], {"mass": fields["mass"][b]}, m_c=8)
+        _assert_bins_equal(jax.tree.map(lambda a: a[b], got), want)
+
+
+def _n_row_gathers(jaxpr, n):
+    count = 0
+    for eqn in jaxpr.eqns:
+        rows = eqn.outvars[0].aval.shape[:1]
+        if eqn.primitive.name == "gather" and rows == (n,):
+            count += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _n_row_gathers(sub, n)
+    return count
+
+
+def test_no_gather_by_the_sort_permutation():
+    """One gather with N rows of output is left in binning: the offsets
+    lookup behind each row's rank. The columns, the sorted key and the cell
+    coordinates come out of the sort, not gathered by its permutation."""
+    dom, pos = _grid_case(5, False)
+    jaxpr = jax.make_jaxpr(lambda p: bin_particles(dom, p, m_c=8))(pos)
+    assert _n_row_gathers(jaxpr.jaxpr, pos.shape[0]) == 1
