@@ -8,6 +8,7 @@ and ``compute_interactions`` are compatibility shims over it.
 """
 
 from .domain import Domain
+from .bonded import BondedTerm
 from .api import (ExecutionReport, InteractionPlan, ParticleState, PlanHealth,
                   active_unit_count, backend_matrix, choose_strategy,
                   clear_executor_cache, degradation_ladder, dispatch_count,
@@ -47,7 +48,8 @@ from . import autotune, scenarios, strategies, traffic
 from .autotune import TuneResult, tune
 
 __all__ = [
-    "Domain", "CellBins", "Occupancy", "PackedRows", "bin_particles",
+    "Domain", "BondedTerm", "CellBins", "Occupancy", "PackedRows",
+    "bin_particles",
     "gather_to_particles", "gather_pencil_rows", "dense_to_particles",
     "interior_to_padded", "pack_rows", "packed_to_particles",
     "padded_row_counts", "unpack_scatter", "full_pencil_occupancy",

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 
 from . import strategies as S
 from . import traffic
+from .bonded import BondedTerm, add_bonded, bond_faults as _bond_faults
 from .binning import (CellBins, PackedRows, SfcClusters, bin_particles,
                       build_sfc_clusters, cell_counts, dense_to_particles,
                       full_pencil_occupancy, pack_rows, packed_to_particles,
@@ -83,11 +84,20 @@ class ParticleState:
     class without perturbing a single real interaction — executing a
     padded, masked state is bit-identical (for the real rows) to executing
     the unpadded state.
+
+    ``bonds`` is an optional (B, 2) int32 bond list (each bonded pair of
+    particle indices once; B static, the indices traced). A plan with a
+    bonded term (``plan(..., bonded=BondedTerm(...))``) adds its FENE
+    bonds and excludes the pair term between bonded ends
+    (``core.bonded``); a state without bonds traces as it did before
+    bonds existed. ``plan.trajectory`` and multi-shard halo plans refuse
+    a state that carries bonds.
     """
 
     positions: Array                                   # (N, 3)
     fields: Dict[str, Array] = dataclasses.field(default_factory=dict)
     valid: Optional[Array] = None                      # (N,) bool, None=all
+    bonds: Optional[Array] = None                      # (B, 2) int32
 
     @property
     def n(self) -> int:
@@ -239,6 +249,8 @@ class InteractionPlan:
     shard_axis: str = "halo"                     # mesh axis name
     shard_cap: Optional[int] = None              # static per-shard capacity
     mesh: Optional[object] = None                # jax Mesh; None = default
+    # -- bonds (core.bonded): FENE term + 1-2 exclusion for states with bonds
+    bonded: Optional[BondedTerm] = None
 
     def __post_init__(self):
         if self.strategy not in ("naive_n2", *STRATEGY_NAMES):
@@ -321,8 +333,11 @@ class InteractionPlan:
         """-> (forces (N, 3), per-particle potential (N,)). Jitted; one
         trace per (plan, state structure). Total potential energy is
         ``0.5 * potential.sum()`` (each pair counted twice, the paper's
-        convention)."""
+        convention). A state with ``bonds`` adds this plan's bonded term
+        (``core.bonded``)."""
         _count_dispatch(self)
+        if state.bonds is not None:
+            _count_bonds(self, state.bonds)
         if not _obs_active():            # zero-overhead disabled path
             return _executor(self, tuple(sorted(state.fields)))(state)
         with _obs_trace("plan.execute", backend=self.backend,
@@ -341,6 +356,8 @@ class InteractionPlan:
         Returns ``(forces (B, N, 3), potential (B, N))``, bit-identical to
         executing each system separately."""
         _count_dispatch(self)
+        if states.bonds is not None:
+            _count_bonds(self, states.bonds)
         if not _obs_active():            # zero-overhead disabled path
             return _batch_executor(self, tuple(sorted(states.fields)))(states)
         with _obs_trace("plan.execute_batch", backend=self.backend,
@@ -364,6 +381,21 @@ class InteractionPlan:
         """:meth:`compile` for the :meth:`execute_batch` executor."""
         return compile_ahead(
             _batch_executor(self, tuple(sorted(states.fields))), states)
+
+    def bond_faults(self, state: ParticleState) -> int:
+        """Bonds of ``state`` stretched past FENE's log floor (from r ~
+        0.949 R0; ``core.bonded``: their outputs are clamped as LAMMPS
+        clamps them, finite but not the potential's).
+        Host sync; counted in ``repro_bond_faults_total``. 0 for a state
+        without bonds."""
+        if state.bonds is None:
+            return 0
+        if self.bonded is None:
+            raise ValueError(_NO_BONDED_TERM)
+        n = _bond_faults(self.domain, self.bonded, state.positions,
+                         state.bonds, state.valid)
+        _plan_counter(BOND_FAULT_TOTAL, self).inc(n)
+        return n
 
     # -- M_C safety net ----------------------------------------------------
 
@@ -662,7 +694,7 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
          m_c_slack: float = 1.5,
          halo_inner: str = "reference", n_shards: Optional[int] = None,
          shard_axis: str = "halo", shard_cap: Optional[int] = None,
-         mesh=None) -> InteractionPlan:
+         mesh=None, bonded: Optional[BondedTerm] = None) -> InteractionPlan:
     """Build an :class:`InteractionPlan` (static planning, done once).
 
     Every static bound taken or measured here (``m_c``, ``max_active``,
@@ -725,6 +757,9 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
         over the local devices.
       shard_cap: static per-shard particle capacity for ``backend="halo"``;
         measured from ``positions`` (with slack) when omitted.
+      bonded: the bonded term (``core.bonded.BondedTerm``: FENE + WCA
+        bonds, the pair term between bonded ends left out) that
+        ``execute`` adds for a state with ``bonds``.
     """
     kernel = kernel or make_lennard_jones()
     if strategy == "autotune":
@@ -741,10 +776,11 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
         # than pinning it — the stopwatch gets the final word
         batch_sizes = tuple(dict.fromkeys(
             (batch_size, *autotune.DEFAULT_BATCH_SIZES)))
-        return autotune.tune(domain, kernel, positions, m_c=m_c,
-                             backends=backends, batch_sizes=batch_sizes,
-                             box=box, m_c_slack=m_c_slack,
-                             interpret=interpret).plan
+        tuned = autotune.tune(domain, kernel, positions, m_c=m_c,
+                              backends=backends, batch_sizes=batch_sizes,
+                              box=box, m_c_slack=m_c_slack,
+                              interpret=interpret).plan
+        return dataclasses.replace(tuned, bonded=bonded)
     if m_c is None:
         if positions is None:
             raise ValueError("plan() needs either m_c or positions "
@@ -856,7 +892,7 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
                         layout=layout, row_cap=row_cap, pair_cap=pair_cap,
                         halo_inner=halo_inner, n_shards=n_shards,
                         shard_axis=shard_axis, shard_cap=shard_cap,
-                        mesh=mesh)
+                        mesh=mesh, bonded=bonded)
     if strategy != "naive_n2":
         # fail at plan time, not execute time (halo validates the
         # per-shard backend the slab schedule will actually dispatch to)
@@ -1000,6 +1036,8 @@ def suggest_pair_cap(domain: Domain, positions: Optional[Array] = None,
 DISPATCH_TOTAL = "repro_dispatch_total"
 RECOMPILE_TOTAL = "repro_recompile_total"
 REPLAN_TOTAL = "repro_replan_total"
+BONDS_TOTAL = "repro_bonds_total"
+BOND_FAULT_TOTAL = "repro_bond_faults_total"
 
 # live Counter instances keyed by (name, backend, strategy, layout) — a
 # registry ``reset()`` zeroes them in place, so the cache never goes stale
@@ -1057,6 +1095,30 @@ def _count_replan(p: Optional["InteractionPlan"] = None) -> None:
     _plan_counter(REPLAN_TOTAL, p).inc()
 
 
+def _count_bonds(p: "InteractionPlan", bonds: Array) -> None:
+    """Bonds handed to one dispatch (static shape: no device sync)."""
+    _plan_counter(BONDS_TOTAL, p).inc(bonds.size // 2)
+
+
+_NO_BONDED_TERM = ("the state carries bonds but the plan has no bonded "
+                   "term: build it with plan(..., bonded=BondedTerm(...))")
+
+
+def _with_bonds(p: InteractionPlan, state: ParticleState,
+                out: Tuple[Array, Array]) -> Tuple[Array, Array]:
+    """The pair path's output, plus the plan's bonded term where the state
+    carries bonds (``core.bonded``); unchanged, and untraced, without."""
+    if state.bonds is None:
+        return out
+    if p.bonded is None:
+        raise ValueError(_NO_BONDED_TERM)
+    # staging span, like recompile accounting: runs once per trace
+    with _obs_trace("bonded.pass", phase="trace",
+                    bonds=int(state.bonds.shape[0])):
+        return add_bonded(p.domain, p.kernel, p.bonded, state.positions,
+                          state.bonds, state.valid, *out)
+
+
 def _impl(p: InteractionPlan) -> Callable:
     """The traced executor body shared by the single and batched paths."""
 
@@ -1085,17 +1147,20 @@ def _impl(p: InteractionPlan) -> Callable:
             with jax.named_scope("pair"):
                 fx, fy, fz, pot = S.naive_n2(p.domain, state.positions,
                                              p.kernel)
-                return jnp.stack([fx, fy, fz], axis=-1), pot
+                out = jnp.stack([fx, fy, fz], axis=-1), pot
+            return _with_bonds(p, state, out)
         bins = bin_particles(p.domain, state.positions, state.fields,
                              m_c=p.m_c, valid=state.valid)
         if p.layout == "packed":
             packed = pack_rows(p.domain, bins, row_cap=p.row_cap)
-            return get_backend(backend, p.strategy, "packed")(p, packed,
-                                                              state)
-        if p.layout == "sfc":
+            out = get_backend(backend, p.strategy, "packed")(p, packed,
+                                                             state)
+        elif p.layout == "sfc":
             sfc = build_sfc_clusters(p.domain, bins, pair_cap=p.pair_cap)
-            return get_backend(backend, p.strategy, "sfc")(p, sfc, state)
-        return get_backend(backend, p.strategy)(p, bins, state)
+            out = get_backend(backend, p.strategy, "sfc")(p, sfc, state)
+        else:
+            out = get_backend(backend, p.strategy)(p, bins, state)
+        return _with_bonds(p, state, out)
 
     return impl
 
@@ -1303,6 +1368,7 @@ class ExecutionReport:
     replans: int = 0                   # bound-growth events this call
     retries: int = 0                   # extra execution attempts
     nonfinite: int = 0                 # non-finite output elements seen
+    bond_faults: int = 0               # bonds stretched past FENE's floor
     out_of_domain: int = 0             # valid particles outside the box
     faults: List[str] = dataclasses.field(default_factory=list)
     ladder_level: int = 0              # rung that produced the result
@@ -1405,7 +1471,8 @@ def _execute_checked(base: InteractionPlan, state: ParticleState, *,
                                             sleep=sleep)
         sp.set(status=report.status, overflow=report.overflow or "none",
                replans=report.replans, retries=report.retries,
-               ladder_level=report.ladder_level)
+               ladder_level=report.ladder_level,
+               bond_faults=report.bond_faults)
         return out, report
 
 
@@ -1432,6 +1499,9 @@ def _execute_checked_impl(base: InteractionPlan, state: ParticleState, *,
             break
         p = grown
     report.plan = p
+    # a stretched bond is the state's fault, not the plan's: reported,
+    # never replanned or degraded around
+    report.bond_faults = p.bond_faults(state)
 
     rungs = degradation_ladder(p)
     health = plan_health(p)

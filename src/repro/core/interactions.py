@@ -21,7 +21,7 @@ model (the paper's own counting convention: distance + kernel, sqrt = 1 FLOP).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,8 +64,15 @@ def _lj_terms(r2: Array, sigma2: float, eps: float):
 
 
 def make_lennard_jones(sigma: float = 0.2, eps: float = 1.0,
-                       softening: float = 1e-6) -> PairKernel:
-    """Lennard-Jones 12-6 with the paper's softening against random overlaps."""
+                       softening: float = 1e-6,
+                       shift_at: Optional[float] = None) -> PairKernel:
+    """Lennard-Jones 12-6 with the paper's softening against random overlaps.
+
+    ``shift_at``: subtract the potential's value at that distance (LAMMPS
+    ``pair_modify shift yes`` with ``shift_at`` = the cutoff), so the
+    potential reaches 0 at the cutoff; the force is unchanged. Cut and
+    shifted at 2^(1/6) sigma this is the WCA repulsion. Off by default.
+    """
     sigma2 = sigma * sigma
 
     def coeff(r2):
@@ -78,8 +85,16 @@ def make_lennard_jones(sigma: float = 0.2, eps: float = 1.0,
         a6, a12 = _lj_terms(r2, sigma2, eps)
         return 4.0 * eps * (a12 - a6)
 
-    return PairKernel("lennard_jones", coeff, potential, flops=21,
-                      static_params=(sigma, eps, softening))
+    if shift_at is None:
+        return PairKernel("lennard_jones", coeff, potential, flops=21,
+                          static_params=(sigma, eps, softening))
+    offset = float(potential(shift_at * shift_at))
+
+    def shifted(r2):
+        return potential(r2) - offset
+
+    return PairKernel("lennard_jones", coeff, shifted, flops=22,
+                      static_params=(sigma, eps, softening, shift_at))
 
 
 def make_low_flop() -> PairKernel:

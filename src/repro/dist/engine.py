@@ -135,7 +135,6 @@ def halo_impl(plan):
                                 backend=plan.halo_inner, n_shards=None,
                                 shard_cap=None, mesh=None)
     inner_fn = get_backend(inner.backend, inner.strategy, plan.layout)
-    mesh = resolve_mesh(plan)
 
     def body(pos_blk: Array, fields_blk: Dict[str, Array]):
         idx = jax.lax.axis_index(axis)
@@ -222,6 +221,12 @@ def halo_impl(plan):
                 jnp.where(valid, pot, 0.0))
 
     def impl(state) -> Tuple[Array, Array]:
+        if state.bonds is not None:
+            raise ValueError(
+                "a multi-shard halo plan does not carry bonds yet: the "
+                "state's bond list would be dropped at the Z-slab "
+                "partition; execute bonded states on one shard")
+        mesh = resolve_mesh(plan)
         # like the body, impl itself is traced once per compile: these
         # are staging spans (phase="trace"), not per-dispatch timings
         n = state.positions.shape[0]

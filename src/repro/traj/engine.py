@@ -465,6 +465,11 @@ def _normalize_state(state, velocities, plan) -> Tuple[
         return (state.positions, state.velocities, {}, None,
                 int(state.step), state.forces, state.potential)
     if isinstance(state, ParticleState):
+        if state.bonds is not None:
+            raise ValueError(
+                "plan.trajectory does not carry bonds yet: the state's "
+                "bond list would be dropped from the fused scan; call "
+                "plan.execute once per step of your own integrator")
         pos = state.positions
         vel = (velocities if velocities is not None
                else jnp.zeros_like(pos))
