@@ -18,6 +18,13 @@ kernel: for each bond, the pair term the kernel counted between its ends
 cutoff test) is subtracted. So the kernel and the binning run the
 programs they run without bonds.
 
+The pass gathers the positions by rows: one (B, 3) row gather per bond
+end, since on a TPU v5e one index costs about as much whether it moves
+one value or three (two row gathers of 990,000 bonds take 11.5 ms, the
+six 1-D gathers they replace 43.3 ms). The adds onto both ends stay four
+1-D scatter-adds: one (2B, 4) row scatter-add of (fx, fy, fz, energy)
+took 90.8 ms there against 69.6 ms for the four.
+
 Conventions are the pair term's: the force on ``i`` from ``j`` is
 ``coeff * (r_i - r_j)`` with minimum-image displacements, and each end's
 potential channel gets the bond's whole energy, so ``0.5 *
@@ -92,17 +99,17 @@ def fene_terms(term: BondedTerm, r2: Array) -> Tuple[Array, Array, Array]:
 
 
 def _bond_vectors(domain: Domain, positions: Array, bonds: Array):
-    """Minimum-image ``r_i - r_j`` of every bond, one array per axis."""
-    i, j = bonds[:, 0], bonds[:, 1]
+    """Minimum-image ``r_i - r_j`` of every bond, one array per axis, from
+    one (B, 3) row gather per bond end."""
+    d = positions[bonds[:, 0]] - positions[bonds[:, 1]]
     box, per = domain.box, domain.periodic_axes
-    d = []
+    out = []
     for a in range(3):
-        x = positions[:, a]
-        da = x[i] - x[j]
+        da = d[:, a]
         if per[a]:
             da = da - box[a] * jnp.round(da / box[a])
-        d.append(da)
-    return d
+        out.append(da)
+    return out
 
 
 def _live(bonds: Array, valid) -> Array:
