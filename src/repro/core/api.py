@@ -42,15 +42,16 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import bounds
 from . import strategies as S
 from . import traffic
 from .bonded import BondedTerm, add_bonded, bond_faults as _bond_faults
 from .binning import (CellBins, PackedRows, SfcClusters, bin_particles,
                       build_sfc_clusters, cell_counts, dense_to_particles,
                       full_pencil_occupancy, pack_rows, packed_to_particles,
-                      padded_row_counts, pencil_counts, pencil_occupancy,
-                      sfc_n_clusters, sfc_pair_count, sfc_to_particles,
-                      subbox_counts, subbox_occupancy)
+                      padded_row_counts, pencil_occupancy, sfc_n_clusters,
+                      sfc_pair_count, sfc_to_particles, subbox_occupancy)
+from .bounds import allin_box as _allin_box, n_units
 from .domain import Domain, slab_domain
 from .interactions import PairKernel, make_lennard_jones
 # obs imports only its own trace/metrics modules eagerly (no core imports),
@@ -402,14 +403,9 @@ class InteractionPlan:
     def check_overflow(self, state: ParticleState) -> bool:
         """True if some static bound of this plan no longer covers these
         positions — results computed anyway would silently drop
-        interactions. Which bounds exist, what each one covers and how an
-        overflowed one grows is the replan contract: see :meth:`replan`
-        (the canonical statement) and ARCHITECTURE.md. For halo plans the
-        per-shard flags are reduced (max) across shards, keeping the
-        safety contract global; everything derives from one binning
-        pass. Padding rows (``state.valid`` False) are excluded — a padded
-        request must never trigger a replan its real particles don't
-        need."""
+        interactions. The bounds, their measures (one binning pass,
+        padding rows left out, halo plans: the busiest shard) and their
+        growth are the replan contract (:meth:`replan`)."""
         return self.overflow_class(state) is not None
 
     def overflow_class(self, state: ParticleState) -> Optional[str]:
@@ -429,128 +425,56 @@ class InteractionPlan:
         if chaos.forced_overflow("core.binning"):
             return "injected"
         counts = _cell_counts(self.domain, state.positions, state.valid)
-        if int(jnp.max(counts)) > self.m_c:
-            return "m_c"
-        if self.layout == "packed":
-            if int(jnp.max(padded_row_counts(self.domain, counts))
-                   ) > self.row_cap:
-                return "row_cap"
-        if self.layout == "sfc" and not self._multi_shard:
-            # multi-shard sfc plans check pair_cap per shard (slab-local
-            # cluster orders) inside halo_overflow_class below
-            if sfc_pair_count(self.domain, counts=counts) > self.pair_cap:
-                return "pair_cap"
-        if self._multi_shard:
-            from ..dist.engine import halo_overflow_class
-            return halo_overflow_class(self, counts)
-        if self.compact:
-            n_act = active_unit_count(self.domain, state.positions,
-                                      self.strategy, box=self.box,
-                                      counts=counts)
-            if n_act > self.max_active:
-                return "max_active"
-        return None
+        return bounds.breached(self, bounds.measure(self, counts))
 
     @property
     def _multi_shard(self) -> bool:
         return self.backend == "halo" and (self.n_shards or 1) > 1
 
-    def replan(self, state: ParticleState, slack: float = 1.5,
-               align: int = 8) -> "InteractionPlan":
+    def replan(self, state: ParticleState,
+               slack: float = bounds.SLACK["m_c"],
+               align: int = bounds.ALIGN) -> "InteractionPlan":
         """A new plan whose static bounds cover ``state``.
 
         **The replan contract** (canonical statement — ``check_overflow``,
-        ``execute_or_replan``, the ``plan()`` bound arguments and the halo
-        engine all defer here; prose version in ARCHITECTURE.md):
+        ``execute_or_replan``, the ``plan()`` bound arguments, the
+        trajectory and the halo engine all defer here; prose version in
+        ARCHITECTURE.md; the code is ``core.bounds``, which every one of
+        them calls):
 
         Every static bound follows one pattern — *measure with slack,
         round up to ``align``, detect overflow, grow only what
-        overflowed*. The bounds, each paired with its measuring probe:
+        overflowed*. The bounds (``core.bounds.TABLE`` holds each one's
+        slack and ceiling), each with its measuring probe:
 
         * ``m_c`` — max particles per cell (``suggest_m_c``),
         * ``max_active`` — active work units of a compacted plan
-          (``suggest_max_active``),
+          (``suggest_max_active``; at most the schedule's unit count),
         * ``row_cap`` — particles per packed pencil row of a
           ``layout="packed"`` plan (``suggest_row_cap``),
         * ``pair_cap`` — compressed cluster-pair list length of a
-          ``layout="sfc"`` plan (``suggest_pair_cap``),
+          ``layout="sfc"`` plan (``suggest_pair_cap``; at most
+          ``n_clusters * 27``),
         * ``shard_cap`` — per-shard particle load of a multi-shard halo
-          plan (``dist.halo.suggest_shard_cap``; halo plans also apply
-          per-shard reductions to ``max_active``).
+          plan (``dist.halo.suggest_shard_cap``). Halo plans measure
+          ``max_active`` and ``pair_cap`` on their busiest shard.
 
         Exceeding a bound makes results *silently drop* interactions, so
         bounds are never trusted blindly: ``check_overflow`` detects an
         exceeded bound from one binning pass, and this method grows
         **only the bound that actually overflowed** — re-measured with
-        slack and forced strictly past the old value — so e.g. a pencil
-        count outgrowing ``max_active`` does not churn ``m_c`` (and with
-        it the whole slot layout) for nothing. Derived statics follow
-        their inputs: the allin sub-box is recomputed whenever ``m_c``
-        changes, and a compacted allin re-measures ``max_active`` against
-        the new tiling. ``row_cap`` depends only on the positions, so it
-        never moves when ``m_c`` does. Padding rows (``state.valid``
-        False) are excluded from every measure, exactly as in
-        ``check_overflow``."""
+        slack (``slack`` for ``m_c``) and forced strictly past the old
+        value — so e.g. a pencil count outgrowing ``max_active`` does not
+        churn ``m_c`` (and with it the whole slot layout) for nothing.
+        Derived statics follow their inputs: the allin sub-box is
+        recomputed whenever ``m_c`` changes, and a compacted allin
+        re-measures ``max_active`` against the new tiling. ``row_cap``
+        depends only on the positions, so it never moves when ``m_c``
+        does. Padding rows (``state.valid`` False) are excluded from
+        every measure, exactly as in ``check_overflow``."""
         counts = _cell_counts(self.domain, state.positions, state.valid)
-        m_c = self.m_c
-        mx_cell = int(jnp.max(counts))
-        if mx_cell > self.m_c:
-            # suggest_m_c's slack-and-align contract, applied to the
-            # mask-aware counts of this one binning pass
-            measured = -(-max(1, int(mx_cell * slack + 0.999)) // align
-                         ) * align
-            grow = -(-(self.m_c + 1) // align) * align  # aligned, > m_c
-            m_c = max(measured, grow)
-        box = self.box if m_c == self.m_c else None
-        row_cap = self.row_cap
-        if self.layout == "packed":
-            mx_row = int(jnp.max(padded_row_counts(self.domain, counts)))
-            if mx_row > row_cap:
-                grow = -(-(row_cap + 1) // align) * align
-                row_cap = max(suggest_row_cap(self.domain, state.positions,
-                                              align=align, counts=counts),
-                              grow)
-        pair_cap = self.pair_cap
-        if self.layout == "sfc":
-            if self._multi_shard:
-                # the bound is per shard: each slab has its own cluster
-                # order, so the busiest shard's pair list sets the cap
-                from ..dist.engine import shard_sfc_pairs
-                n_pairs = int(max(shard_sfc_pairs(self.domain, counts,
-                                                  self.n_shards)))
-                suggested = -(-max(1, int(n_pairs * 1.25 + 0.999))
-                              // align) * align
-            else:
-                n_pairs = sfc_pair_count(self.domain, counts=counts)
-                suggested = suggest_pair_cap(self.domain, align=align,
-                                             counts=counts)
-            if n_pairs > pair_cap:
-                grow = -(-(pair_cap + 1) // align) * align
-                pair_cap = max(suggested, grow, n_pairs)
-        max_active = self.max_active
-        shard_cap = self.shard_cap
-        if self._multi_shard:
-            # shard-level bounds: per-shard load vs shard_cap, per-shard
-            # active pencils vs max_active — grown only when exceeded
-            from ..dist.engine import halo_grown_bounds
-            shard_cap, max_active = halo_grown_bounds(self, state,
-                                                      align=align)
-        elif self.compact:
-            if self.strategy == "allin" and box is None:
-                # fix the new tiling first: the active-sub-box bound must
-                # be measured against the grid that will actually run
-                box = _allin_box(self.domain, m_c)
-            n_act = active_unit_count(self.domain, state.positions,
-                                      self.strategy, box=box, counts=counts)
-            if n_act > max_active or box != self.box:
-                suggested = suggest_max_active(self.domain, state.positions,
-                                               self.strategy, box=box,
-                                               align=align, counts=counts)
-                max_active = max(suggested, n_act)
-        grown = dataclasses.replace(self, m_c=m_c, box=box,
-                                    max_active=max_active,
-                                    shard_cap=shard_cap, row_cap=row_cap,
-                                    pair_cap=pair_cap)
+        grown = bounds.grow(self, bounds.measure(self, counts), counts,
+                            m_c_slack=slack, align=align)
         if grown != self:                # no-op replans are not replans
             _count_replan(self)
             _obs_event("plan.replan", strategy=self.strategy,
@@ -611,7 +535,8 @@ class InteractionPlan:
         for the langevin integrator, ...): see
         :func:`repro.traj.engine.run_trajectory` — the engine and the
         canonical contract live there. Requires a cell schedule
-        (``cell_dense`` / ``xpencil`` / ``allin``) on a single shard."""
+        (``cell_dense`` / ``xpencil`` / ``allin``) on a single shard, in
+        the dense or packed layout."""
         from ..traj.engine import run_trajectory
         return run_trajectory(self, state, n_steps, dt,
                               integrator=integrator, skin=skin, **opts)
@@ -691,16 +616,17 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
          compact: bool = False, max_active: Optional[int] = None,
          layout: str = "dense", row_cap: Optional[int] = None,
          pair_cap: Optional[int] = None,
-         m_c_slack: float = 1.5,
+         m_c_slack: float = bounds.SLACK["m_c"],
          halo_inner: str = "reference", n_shards: Optional[int] = None,
          shard_axis: str = "halo", shard_cap: Optional[int] = None,
          mesh=None, bonded: Optional[BondedTerm] = None) -> InteractionPlan:
     """Build an :class:`InteractionPlan` (static planning, done once).
 
     Every static bound taken or measured here (``m_c``, ``max_active``,
-    ``row_cap``, ``shard_cap``) obeys one safety contract — measured with
-    slack, overflow detectable, grown individually by
-    ``execute_or_replan`` — stated once on :meth:`InteractionPlan.replan`.
+    ``row_cap``, ``pair_cap``, ``shard_cap``) obeys one safety contract —
+    measured with slack, overflow detectable, grown individually by
+    ``execute_or_replan`` — stated once on :meth:`InteractionPlan.replan`;
+    an omitted one is measured from ``positions`` by ``core.bounds``.
 
     Args:
       domain: the cell grid.
@@ -781,12 +707,12 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
                               box=box, m_c_slack=m_c_slack,
                               interpret=interpret).plan
         return dataclasses.replace(tuned, bonded=bonded)
+    counts = None
     if m_c is None:
         if positions is None:
-            raise ValueError("plan() needs either m_c or positions "
-                             "(to measure the M_C bound)")
-        from .engine import suggest_m_c
-        m_c = suggest_m_c(domain, positions, slack=m_c_slack)
+            raise ValueError(_needs_positions("m_c"))
+        counts = _cell_counts(domain, positions)
+        m_c = bounds.size(int(jnp.max(counts)), m_c_slack)
     if strategy == "auto":
         if positions is None:
             raise ValueError('strategy="auto" needs positions (the cost '
@@ -811,7 +737,6 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
     inner_backend = halo_inner if backend == "halo" else backend
     if backend == "halo":
         from ..dist import engine as dist_engine
-        from ..dist.halo import suggest_shard_cap
         if mesh is not None and shard_axis not in mesh.axis_names:
             raise ValueError(
                 f"mesh has axes {mesh.axis_names}, no {shard_axis!r} "
@@ -823,76 +748,35 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
                 n_shards = int(mesh.shape[shard_axis])
             else:
                 n_shards = dist_engine.default_n_shards(domain)
-        if n_shards > 1 and shard_cap is None:
-            if positions is None:
-                raise ValueError("backend='halo' needs either shard_cap or "
-                                 "positions (to measure the per-shard "
-                                 "capacity)")
-            shard_cap = suggest_shard_cap(domain, positions, n_shards)
-    if layout == "packed":
-        if not supports_layout(inner_backend, strategy, "packed"):
+    for lay in ("packed", "sfc"):
+        if layout == lay and not supports_layout(inner_backend, strategy,
+                                                 lay):
             raise ValueError(
-                f"backend {inner_backend!r} has no packed path for "
-                f"strategy {strategy!r}; packed-capable pairs: "
-                f"{sorted(k[:2] for k in _BACKENDS if k[2] == 'packed')}")
-        if row_cap is None:
-            if positions is None:
-                raise ValueError('layout="packed" needs either row_cap or '
-                                 "positions (to measure the packed-row "
-                                 "bound)")
-            row_cap = suggest_row_cap(domain, positions)
-    if layout == "sfc":
-        if not supports_layout(inner_backend, strategy, "sfc"):
-            raise ValueError(
-                f"backend {inner_backend!r} has no sfc path for "
-                f"strategy {strategy!r}; sfc-capable pairs: "
-                f"{sorted(k[:2] for k in _BACKENDS if k[2] == 'sfc')}")
-        if pair_cap is None:
-            if positions is None:
-                raise ValueError('layout="sfc" needs either pair_cap or '
-                                 "positions (to measure the pair-list "
-                                 "bound)")
-            if backend == "halo" and n_shards > 1:
-                # per-shard bound: each slab has its own cluster order,
-                # so the busiest shard's measured pair list sets the cap
-                from ..dist.engine import shard_sfc_pairs
-                counts_ = _cell_counts(domain, positions)
-                n_pairs = int(max(shard_sfc_pairs(domain, counts_,
-                                                  n_shards)))
-                pair_cap = -(-max(1, int(n_pairs * 1.25 + 0.999)) // 8) * 8
-            else:
-                pair_cap = suggest_pair_cap(domain, positions)
-    if compact:
-        if not supports_compact(inner_backend, strategy, layout):
-            raise ValueError(
-                f"backend {inner_backend!r} has no compacted path for "
-                f"strategy {strategy!r} (layout {layout!r}); "
-                f"compact-capable triples: {sorted(_COMPACT_OK)}")
-        if max_active is None:
-            if positions is None:
-                raise ValueError("compact=True needs either max_active or "
-                                 "positions (to measure the active-unit "
-                                 "bound)")
-            if backend == "halo" and n_shards > 1:
-                # one static bound shared by all shards: the busiest
-                # shard's active pencils, not the global count
-                from ..dist.halo import suggest_shard_max_active
-                max_active = suggest_shard_max_active(domain, positions,
-                                                      n_shards)
-            else:
-                mbox = box
-                if strategy == "allin" and mbox is None:
-                    mbox = _allin_box(domain, m_c)
-                max_active = suggest_max_active(domain, positions, strategy,
-                                                box=mbox)
+                f"backend {inner_backend!r} has no {lay} path for "
+                f"strategy {strategy!r}; {lay}-capable pairs: "
+                f"{sorted(k[:2] for k in _BACKENDS if k[2] == lay)}")
+    if compact and not supports_compact(inner_backend, strategy, layout):
+        raise ValueError(
+            f"backend {inner_backend!r} has no compacted path for "
+            f"strategy {strategy!r} (layout {layout!r}); "
+            f"compact-capable triples: {sorted(_COMPACT_OK)}")
+    given = {"row_cap": row_cap, "pair_cap": pair_cap,
+             "max_active": max_active, "shard_cap": shard_cap}
+    omitted = [k for k, v in given.items() if v is None]
+    # omitted bounds hold a placeholder until they are measured below
     p = InteractionPlan(domain=domain, kernel=kernel, m_c=m_c,
                         strategy=strategy, backend=backend,
                         batch_size=batch_size, box=box, interpret=interpret,
-                        compact=compact, max_active=max_active,
-                        layout=layout, row_cap=row_cap, pair_cap=pair_cap,
-                        halo_inner=halo_inner, n_shards=n_shards,
-                        shard_axis=shard_axis, shard_cap=shard_cap,
-                        mesh=mesh, bonded=bonded)
+                        compact=compact, layout=layout, halo_inner=halo_inner,
+                        n_shards=n_shards, shard_axis=shard_axis, mesh=mesh,
+                        bonded=bonded,
+                        **{k: 1 if v is None else v for k, v in given.items()})
+    need = [b.name for b in bounds.TABLE if b.name in omitted and b.carried(p)]
+    if need and positions is None:
+        raise ValueError(_needs_positions(need[0]))
+    if need and counts is None:
+        counts = _cell_counts(domain, positions)
+    p = bounds.fit(p, counts, omitted)
     if strategy != "naive_n2":
         # fail at plan time, not execute time (halo validates the
         # per-shard backend the slab schedule will actually dispatch to)
@@ -901,6 +785,11 @@ def plan(domain: Domain, kernel: Optional[PairKernel] = None, *,
         # never left to fail (or be degraded around) at execute time
         kernel_budget(p)
     return p
+
+
+def _needs_positions(bound: str) -> str:
+    return (f"plan() needs either {bound} or positions (to measure the "
+            f"{bound} bound)")
 
 
 def choose_strategy(domain: Domain, m_c: int, avg_ppc: float,
@@ -920,11 +809,6 @@ def choose_strategy(domain: Domain, m_c: int, avg_ppc: float,
                               order[r.strategy])).strategy
 
 
-def _allin_box(domain: Domain, m_c: int) -> Tuple[int, int, int]:
-    """VMEM-budget sub-box, shrunk to divisors of the grid (static)."""
-    return S.shrink_to_divisors(domain, S.subbox_dims(domain, m_c))
-
-
 _cell_counts = cell_counts          # binning owns the single binning pass
 
 
@@ -942,75 +826,49 @@ def active_unit_count(domain: Domain, positions: Array,
     pass precomputed per-cell ``counts`` to skip the binning pass."""
     if counts is None:
         counts = _cell_counts(domain, positions)
-    if strategy == "allin":
-        if box is None:
-            box = _allin_box(domain, 1)
-        box = S.shrink_to_divisors(domain, box)
-        uc = subbox_counts(domain, counts, box)
-    else:
-        uc = pencil_counts(domain, counts)
-    return int(jnp.sum(uc > 0))
-
-
-def n_units(domain: Domain, strategy: str = "xpencil",
-            box: Optional[Tuple[int, int, int]] = None) -> int:
-    """Total work units of a schedule (denominator of the fill fraction)."""
-    if strategy == "allin":
-        if box is None:
-            box = _allin_box(domain, 1)
-        bx, by, bz = S.shrink_to_divisors(domain, box)
-        return (domain.nx // bx) * (domain.ny // by) * (domain.nz // bz)
-    return domain.nz * domain.ny
+    return bounds.active_units(domain, counts, strategy, box)
 
 
 def suggest_max_active(domain: Domain, positions: Array,
                        strategy: str = "xpencil",
                        box: Optional[Tuple[int, int, int]] = None,
-                       slack: float = 1.25, align: int = 8,
+                       slack: float = bounds.SLACK["max_active"],
+                       align: int = bounds.ALIGN,
                        counts: Optional[Array] = None) -> int:
     """One-off static ``max_active`` bound: measured active units with
     slack, rounded up to ``align``, clipped to the total unit count (a full
-    bound degrades gracefully to dense coverage). The compacted-path
-    counterpart of ``suggest_m_c``. Pass precomputed per-cell ``counts``
-    to skip the binning pass (or to exclude masked padding rows)."""
+    bound degrades gracefully to dense coverage). Precomputed per-cell
+    ``counts`` skip the binning pass."""
     n_act = active_unit_count(domain, positions, strategy, box=box,
                               counts=counts)
-    total = n_units(domain, strategy, box=box)
-    bound = max(1, int(n_act * slack + 0.999))
-    bound = -(-bound // align) * align
-    return min(bound, total)
+    return bounds.size(n_act, slack, align, n_units(domain, strategy, box))
 
 
-def suggest_row_cap(domain: Domain, positions: Array, slack: float = 1.25,
-                    align: int = 8, counts: Optional[Array] = None) -> int:
+def suggest_row_cap(domain: Domain, positions: Array,
+                    slack: float = bounds.SLACK["row_cap"],
+                    align: int = bounds.ALIGN,
+                    counts: Optional[Array] = None) -> int:
     """One-off static ``row_cap`` bound for ``layout="packed"``: the
     fullest *padded* pencil row (interior particles plus periodic X-ghost
     copies — ``binning.padded_row_counts``) with slack, rounded up to
-    ``align`` (sublane contract). The packed-layout counterpart of
-    ``suggest_m_c``; obeys the replan contract
-    (:meth:`InteractionPlan.replan`). Pass precomputed per-cell ``counts``
-    to skip the binning pass."""
+    ``align``. Precomputed per-cell ``counts`` skip the binning pass."""
     if counts is None:
         counts = _cell_counts(domain, positions)
-    mx = int(jnp.max(padded_row_counts(domain, counts)))
-    cap = max(1, int(mx * slack + 0.999))
-    return -(-cap // align) * align
+    return bounds.size(int(jnp.max(padded_row_counts(domain, counts))),
+                       slack, align)
 
 
 def suggest_pair_cap(domain: Domain, positions: Optional[Array] = None,
-                     slack: float = 1.25, align: int = 8,
+                     slack: float = bounds.SLACK["pair_cap"],
+                     align: int = bounds.ALIGN,
                      counts: Optional[Array] = None) -> int:
     """One-off static ``pair_cap`` bound for ``layout="sfc"``: the measured
     compressed cluster-pair list length (``binning.sfc_pair_count``) with
     slack, rounded up to ``align``, clipped to the all-pairs total
-    ``n_clusters * 27`` (the bound degrades gracefully to the dense
-    stencil). The SFC-layout counterpart of ``suggest_row_cap``; obeys the
-    replan contract (:meth:`InteractionPlan.replan`). Pass precomputed
-    per-cell ``counts`` to skip the binning pass."""
-    n_pairs = sfc_pair_count(domain, positions, counts=counts)
-    cap = max(1, int(n_pairs * slack + 0.999))
-    cap = -(-cap // align) * align
-    return max(min(cap, sfc_n_clusters(domain) * 27), n_pairs)
+    ``n_clusters * 27``. Precomputed per-cell ``counts`` skip the binning
+    pass."""
+    return bounds.size(sfc_pair_count(domain, positions, counts=counts),
+                       slack, align, sfc_n_clusters(domain) * 27)
 
 
 # --------------------------------------------------------------------------
@@ -1133,10 +991,6 @@ def _impl(p: InteractionPlan) -> Callable:
             return inner(state)
         return halo_counted
 
-    # a single-shard halo plan runs the inner backend directly — no mesh,
-    # no exchange: the bit-identical single-device fallback
-    backend = p.halo_inner if p.backend == "halo" else p.backend
-
     def impl(state: ParticleState) -> Tuple[Array, Array]:
         _count_recompile(p)              # runs at trace time only
         if p.strategy == "naive_n2":
@@ -1151,18 +1005,25 @@ def _impl(p: InteractionPlan) -> Callable:
             return _with_bonds(p, state, out)
         bins = bin_particles(p.domain, state.positions, state.fields,
                              m_c=p.m_c, valid=state.valid)
-        if p.layout == "packed":
-            packed = pack_rows(p.domain, bins, row_cap=p.row_cap)
-            out = get_backend(backend, p.strategy, "packed")(p, packed,
-                                                             state)
-        elif p.layout == "sfc":
-            sfc = build_sfc_clusters(p.domain, bins, pair_cap=p.pair_cap)
-            out = get_backend(backend, p.strategy, "sfc")(p, sfc, state)
-        else:
-            out = get_backend(backend, p.strategy)(p, bins, state)
-        return _with_bonds(p, state, out)
+        return _with_bonds(p, state, _pair_forces(p, bins, state))
 
     return impl
+
+
+def _pair_forces(p: InteractionPlan, bins: CellBins,
+                 state: ParticleState) -> Tuple[Array, Array]:
+    """The pair term of a single-shard plan on ``bins``: the layout's
+    schedule builder (``pack_rows`` / ``build_sfc_clusters``), then the
+    backend registered for it. Shared by :func:`_impl`, which bins the
+    state first, and the trajectory, which passes the bins it reuses. A
+    single-shard halo plan runs its inner backend: no mesh, no exchange,
+    the bit-identical single-device fallback."""
+    backend = p.halo_inner if p.backend == "halo" else p.backend
+    if p.layout == "packed":
+        bins = pack_rows(p.domain, bins, row_cap=p.row_cap)
+    elif p.layout == "sfc":
+        bins = build_sfc_clusters(p.domain, bins, pair_cap=p.pair_cap)
+    return get_backend(backend, p.strategy, p.layout)(p, bins, state)
 
 
 _CacheInfo = collections.namedtuple(

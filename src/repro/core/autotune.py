@@ -41,6 +41,7 @@ from . import strategies as S
 from . import traffic
 from .api import (InteractionPlan, ParticleState, STRATEGY_NAMES,
                   _allin_box, _max_cell_count, get_backend)
+from .bounds import SLACK
 from .domain import Domain
 from .interactions import PairKernel, make_lennard_jones
 from .timing import time_fn
@@ -258,8 +259,7 @@ def _audit_pruned(domain: Domain, positions: Array,
 
 
 def compact_twins(domain: Domain, positions: Array,
-                  candidates: Sequence[Candidate], *, slack: float = 1.25,
-                  align: int = 8) -> List[Candidate]:
+                  candidates: Sequence[Candidate]) -> List[Candidate]:
     """The dense-vs-compact candidate axis: for every candidate whose
     (backend, strategy) implements the occupancy-compacted path, a twin
     with ``compact=True`` and a ``max_active`` bound measured from
@@ -272,17 +272,15 @@ def compact_twins(domain: Domain, positions: Array,
             continue
         key = ("box", c.box) if c.strategy == "allin" else ("pencil",)
         if key not in bounds:
-            bounds[key] = suggest_max_active(
-                domain, positions, c.strategy, box=c.box,
-                slack=slack, align=align)
+            bounds[key] = suggest_max_active(domain, positions, c.strategy,
+                                             box=c.box)
         twins.append(dataclasses.replace(c, compact=True,
                                          max_active=bounds[key]))
     return list(dict.fromkeys(twins))
 
 
 def packed_twins(domain: Domain, positions: Array,
-                 candidates: Sequence[Candidate], *, slack: float = 1.25,
-                 align: int = 8) -> List[Candidate]:
+                 candidates: Sequence[Candidate]) -> List[Candidate]:
     """The dense-vs-packed layout axis: for every candidate whose
     (backend, strategy) implements the packed-row layout, a twin with
     ``layout="packed"`` and a ``row_cap`` bound measured from
@@ -299,8 +297,7 @@ def packed_twins(domain: Domain, positions: Array,
         if c.compact and not _supports_packed_compact(c):
             continue
         if bound is None:
-            bound = suggest_row_cap(domain, positions, slack=slack,
-                                    align=align)
+            bound = suggest_row_cap(domain, positions)
         twins.append(dataclasses.replace(c, layout="packed", row_cap=bound))
     return list(dict.fromkeys(twins))
 
@@ -311,8 +308,7 @@ def _supports_packed_compact(c: Candidate) -> bool:
 
 
 def sfc_twins(domain: Domain, positions: Array,
-              candidates: Sequence[Candidate], *, slack: float = 1.25,
-              align: int = 8) -> List[Candidate]:
+              candidates: Sequence[Candidate]) -> List[Candidate]:
     """The SFC cluster-layout axis: for every candidate whose
     (backend, strategy) implements the compressed cluster-pair list, a
     twin with ``layout="sfc"`` and a ``pair_cap`` bound measured from
@@ -328,8 +324,7 @@ def sfc_twins(domain: Domain, positions: Array,
                 or not supports_layout(c.backend, c.strategy, "sfc")):
             continue
         if bound is None:
-            bound = suggest_pair_cap(domain, positions, slack=slack,
-                                     align=align)
+            bound = suggest_pair_cap(domain, positions)
         twins.append(dataclasses.replace(c, layout="sfc", pair_cap=bound))
     return list(dict.fromkeys(twins))
 
@@ -337,8 +332,7 @@ def sfc_twins(domain: Domain, positions: Array,
 def halo_twins(domain: Domain, positions: Array,
                candidates: Sequence[Candidate],
                shard_counts: Sequence[int], *,
-               device_count: Optional[int] = None,
-               cap_slack: float = 1.3, align: int = 8) -> List[Candidate]:
+               device_count: Optional[int] = None) -> List[Candidate]:
     """The shard-count candidate axis: for every cell-schedule candidate, a
     distributed twin per viable shard count — ``backend="halo"`` with the
     candidate's backend as the per-shard inner, a ``shard_cap`` measured
@@ -354,8 +348,7 @@ def halo_twins(domain: Domain, positions: Array,
     for ns in dict.fromkeys(shard_counts):
         if ns < 2 or ns > device_count or domain.nz % ns:
             continue
-        caps[ns] = suggest_shard_cap(domain, positions, ns,
-                                     slack=cap_slack, align=align)
+        caps[ns] = suggest_shard_cap(domain, positions, ns)
         for c in candidates:
             if c.distributed:
                 continue
@@ -366,8 +359,8 @@ def halo_twins(domain: Domain, positions: Array,
             max_active = c.max_active
             if c.compact:
                 if ns not in bounds:
-                    bounds[ns] = suggest_shard_max_active(
-                        domain, positions, ns, align=align)
+                    bounds[ns] = suggest_shard_max_active(domain, positions,
+                                                          ns)
                 max_active = bounds[ns]
             twins.append(dataclasses.replace(
                 c, n_shards=ns, shard_cap=caps[ns], box=None,
@@ -544,7 +537,7 @@ def tune(domain: Domain, kernel: Optional[PairKernel] = None,
          strategies: Sequence[str] = STRATEGY_NAMES,
          box: Optional[Tuple[int, int, int]] = None,
          candidates: Optional[Sequence[Candidate]] = None,
-         m_c_slack: float = 1.5,
+         m_c_slack: float = SLACK["m_c"],
          include_compact: bool = True,
          include_packed: bool = True,
          include_sfc: bool = True,
@@ -565,7 +558,7 @@ def tune(domain: Domain, kernel: Optional[PairKernel] = None,
       positions: representative positions — required; the tuner times real
         executions and measures the M_C bound from them.
       m_c: pin the slot bound; by default both a tight (slack=1.0) and a
-        slacked (``m_c_slack``, default 1.5) sublane-aligned bound are
+        slacked (``m_c_slack``, the table's 1.5) sublane-aligned bound are
         candidates.
       backends: backends to tune over; default is ``("reference",)`` off-TPU
         (interpret-mode Pallas would time the interpreter, not the kernel)

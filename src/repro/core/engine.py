@@ -20,30 +20,24 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import bounds
 from .api import InteractionPlan, ParticleState, plan as make_plan
-from .binning import CellBins, bin_particles
+from .binning import CellBins, bin_particles, cell_counts
 from .domain import Domain
 from .interactions import PairKernel, make_lennard_jones
 
 Array = jnp.ndarray
 
 
-def suggest_m_c(domain: Domain, positions, slack: float = 1.5,
-                align: int = 8) -> int:
-    """One-off (outside jit) M_C choice: max cell count rounded up with slack.
-
-    The paper keeps the running max while building the prefix sum; we do the
-    same but add slack so the bound survives a few integration steps before a
-    re-bin with a larger M_C is needed, and round *up* to a sublane multiple
-    — unconditionally, since ``kernels/xpencil.py`` documents sublane-aligned
-    slices as an invariant (small maxima used to leak through unrounded).
-    """
-    counts = jax.ops.segment_sum(
-        jnp.ones((positions.shape[0],), jnp.int32),
-        domain.cell_ids(positions), num_segments=domain.n_cells)
-    mx = int(jnp.max(counts))
-    m_c = max(1, int(mx * slack + 0.999))
-    return -(-m_c // align) * align
+def suggest_m_c(domain: Domain, positions,
+                slack: float = bounds.SLACK["m_c"],
+                align: int = bounds.ALIGN) -> int:
+    """One-off (outside jit) M_C choice: the fullest cell with slack (so the
+    bound survives a few integration steps before a re-bin needs a larger
+    one), rounded *up* to a sublane multiple — always, since
+    ``kernels/xpencil.py`` takes sublane-aligned slices as an invariant."""
+    return bounds.size(int(jnp.max(cell_counts(domain, positions))), slack,
+                       align)
 
 
 class CellListEngine:

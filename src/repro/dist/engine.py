@@ -26,10 +26,13 @@ executor per plan does, end to end:
   5. **scatter-back** — per-shard results return to global particle order.
 
 Overflow stays a *global* contract: ``InteractionPlan.check_overflow``
-reduces the per-shard load and per-shard active-pencil counts across shards
-(max) against the plan's static bounds, so ``execute_or_replan`` grows
-exactly the bound that overflowed — ``m_c``, ``shard_cap``, or the
-compacted ``max_active`` — never silently dropping work.
+reduces the per-shard load, active-pencil and sfc pair-list counts across
+shards (max) against the plan's static bounds (``core.bounds``, with the
+per-shard probes ``binning.shard_slab_counts`` /
+``binning.shard_pencil_active`` / :func:`shard_sfc_pairs`), so
+``execute_or_replan`` grows exactly the bound that overflowed — ``m_c``,
+``shard_cap``, ``pair_cap`` or the compacted ``max_active`` — never
+silently dropping work.
 
 A single-shard halo plan degrades to the inner backend bit-identically (no
 mesh, no exchange) — the single-device fallback the README documents.
@@ -45,12 +48,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core import bounds
 from ..core.binning import (EMPTY_POS, bin_particles, build_sfc_clusters,
-                            cell_counts, pack_rows, sfc_pair_count,
-                            shard_pencil_active, shard_slab_counts)
+                            pack_rows, sfc_pair_count)
 from ..core.domain import Domain, slab_domain
 from ..obs import metrics as _obs_metrics
-from ..obs.trace import event as _obs_event, trace as _obs_trace
+from ..obs.trace import trace as _obs_trace
 from . import halo as H
 
 # ppermute ghost-plane exchanges *staged* per executor trace (the halo
@@ -204,8 +207,8 @@ def halo_impl(plan):
             # planes arriving as dense slots feed the compressed pair
             # list with no extra bookkeeping; each shard then builds its
             # own slab-local cluster order under the plan's static
-            # pair_cap (a per-shard bound, checked per shard by
-            # halo_overflow_class)
+            # pair_cap (a per-shard bound: core.bounds measures the
+            # busiest shard's list)
             bins = dataclasses.replace(bins,
                                        planes=exchange_planes(bins.planes),
                                        slot_id=exchange(sid, -1))
@@ -255,36 +258,8 @@ def halo_impl(plan):
 
 
 # --------------------------------------------------------------------------
-# the overflow contract, reduced across shards
+# the per-shard sfc probe behind the pair_cap bound
 # --------------------------------------------------------------------------
-
-def halo_overflow(plan, counts: Array) -> bool:
-    """Shard-level overflow: True when any shard's particle load exceeds
-    ``shard_cap``, or (compacted plans) any shard's active-pencil count
-    exceeds ``max_active``. ``counts`` are the global per-cell counts the
-    caller already computed for the ``m_c`` check — the shard reductions
-    (max across shards) derive from them, so the whole safety check stays
-    one binning pass."""
-    return halo_overflow_class(plan, counts) is not None
-
-
-def halo_overflow_class(plan, counts: Array) -> Optional[str]:
-    """Which shard-level bound overflowed — ``"shard_cap"`` /
-    ``"max_active"`` / ``"pair_cap"`` — or None (:func:`halo_overflow`
-    with the bound named, feeding ``InteractionPlan.overflow_class``)."""
-    loads = shard_slab_counts(plan.domain, counts, plan.n_shards)
-    if int(jnp.max(loads)) > plan.shard_cap:
-        return "shard_cap"
-    if plan.layout == "sfc":
-        if max(shard_sfc_pairs(plan.domain, counts,
-                               plan.n_shards)) > plan.pair_cap:
-            return "pair_cap"
-    if plan.compact:
-        act = shard_pencil_active(plan.domain, counts, plan.n_shards)
-        if int(jnp.max(act)) > plan.max_active:
-            return "max_active"
-    return None
-
 
 def shard_sfc_pairs(domain: Domain, counts: Array, n_shards: int) -> list:
     """Per-shard compressed pair-list lengths of an SFC halo plan.
@@ -360,38 +335,14 @@ def elastic_shrink(plan, state=None, lost: int = 1):
     else:
         # fewer shards -> each slab holds at least old_load * old/new
         ratio = plan.n_shards / ns
-        shard_cap = -(-int(plan.shard_cap * ratio + 0.999) // 8) * 8
+        shard_cap = bounds.size(plan.shard_cap, ratio)
     max_active = plan.max_active
     if plan.compact:
         if pos is not None:
             max_active = H.suggest_shard_max_active(plan.domain, pos, ns)
         else:
-            max_active = min(-(-int(max_active * ratio + 0.999) // 8) * 8,
-                             plan.domain.nz * plan.domain.ny)
+            max_active = bounds.size(max_active, ratio, ceiling=(
+                plan.domain.nz * plan.domain.ny))
     return dataclasses.replace(plan, n_shards=ns, shard_cap=shard_cap,
                                max_active=max_active, mesh=None, box=None)
 
-
-def halo_grown_bounds(plan, state, align: int = 8
-                      ) -> Tuple[int, Optional[int]]:
-    """-> ``(shard_cap, max_active)`` covering ``state``, growing only the
-    bound(s) that actually overflowed (the replan contract)."""
-    pos = state.positions
-    counts = cell_counts(plan.domain, pos)           # one binning pass
-    shard_cap = plan.shard_cap
-    loads = H.shard_loads(plan.domain, pos, plan.n_shards, counts=counts)
-    if int(jnp.max(loads)) > shard_cap:
-        grow = -(-(shard_cap + 1) // align) * align      # aligned, > cap
-        shard_cap = max(
-            H.suggest_shard_cap(plan.domain, pos, plan.n_shards,
-                                align=align), grow)
-    max_active = plan.max_active
-    if plan.compact:
-        n_act = int(jnp.max(shard_pencil_active(plan.domain, counts,
-                                                plan.n_shards)))
-        if n_act > max_active:
-            max_active = max(
-                H.suggest_shard_max_active(plan.domain, pos, plan.n_shards,
-                                           align=align, counts=counts),
-                n_act)
-    return shard_cap, max_active

@@ -33,6 +33,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core import bounds
 from ..core.binning import (EMPTY_POS, cell_counts, shard_pencil_active,
                             shard_slab_counts)
 from ..core.domain import Domain
@@ -67,20 +68,21 @@ def shard_loads(domain: Domain, positions: Array, n_shards: int,
 
 
 def suggest_shard_cap(domain: Domain, positions: Array, n_shards: int,
-                      slack: float = 1.3, align: int = 8) -> int:
+                      slack: float = bounds.SLACK["shard_cap"],
+                      align: int = bounds.ALIGN) -> int:
     """One-off static per-shard particle capacity: the busiest shard's load
     with slack, rounded up to ``align`` — the same measure-plus-slack
     contract as ``suggest_m_c``. Particles drift between slabs as they
     move; an exceeded cap is caught by ``InteractionPlan.check_overflow``.
     """
     mx = int(jnp.max(shard_loads(domain, positions, n_shards)))
-    cap = max(1, int(mx * slack + 0.999))
-    return -(-cap // align) * align
+    return bounds.size(mx, slack, align)
 
 
 def suggest_shard_max_active(domain: Domain, positions: Array,
-                             n_shards: int, slack: float = 1.25,
-                             align: int = 8,
+                             n_shards: int,
+                             slack: float = bounds.SLACK["max_active"],
+                             align: int = bounds.ALIGN,
                              counts: Array | None = None) -> int:
     """Static per-shard active-pencil bound for the compacted halo path:
     the busiest shard's active (z, y) pencil count with slack, aligned,
@@ -88,9 +90,7 @@ def suggest_shard_max_active(domain: Domain, positions: Array,
     if counts is None:
         counts = cell_counts(domain, positions)
     mx = int(jnp.max(shard_pencil_active(domain, counts, n_shards)))
-    bound = max(1, int(mx * slack + 0.999))
-    bound = -(-bound // align) * align
-    return min(bound, (domain.nz // n_shards) * domain.ny)
+    return bounds.size(mx, slack, align, (domain.nz // n_shards) * domain.ny)
 
 
 # --------------------------------------------------------------------------

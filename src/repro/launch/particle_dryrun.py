@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import Domain, ParticleState, make_lennard_jones
-from ..core import api as A
+from ..core import api as A, bounds
 from . import roofline as RL
 from .mesh import make_production_mesh
 
@@ -46,7 +46,7 @@ def run(multi_pod: bool, division: int = 128, ppc: int = 16,
     n_shards = int(mesh.shape["data"])
     # uniform benchmark load: the analytic per-shard capacity with the
     # usual slack + alignment (no positions exist at dry-run time)
-    cap = -(-int(n / n_shards * 1.3) // 8) * 8
+    cap = bounds.size(n // n_shards, bounds.SLACK["shard_cap"])
     p = A.plan(domain, kernel, m_c=m_c, strategy="xpencil", backend="halo",
                mesh=mesh, shard_axis="data", n_shards=n_shards,
                shard_cap=cap)

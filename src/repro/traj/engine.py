@@ -48,10 +48,11 @@ delay before a segment, nonfinite on its committed positions),
 inside the checkpoint writer itself.
 
 Restrictions: trajectories need a cell schedule whose force inputs are
-bins (``cell_dense`` / ``xpencil`` / ``allin``) on a single shard —
-``par_part`` reads raw positions (stale bins would silently drop its
-interactions), ``naive_n2`` bypasses binning, and multi-shard halo plans
-re-partition per call; all three raise up front.
+bins (``cell_dense`` / ``xpencil`` / ``allin``) on a single shard, in the
+dense or packed layout — ``par_part`` reads raw positions (stale bins
+would silently drop its interactions), ``naive_n2`` bypasses binning,
+multi-shard halo plans re-partition per call, and the monitors keep no
+sfc pair-list length to grow ``pair_cap`` by; all four raise up front.
 """
 
 from __future__ import annotations
@@ -65,13 +66,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import api
+from ..core import api, bounds
 from ..core.api import InteractionPlan, ParticleState
 from ..obs import metrics as _obs_metrics
 from ..obs.trace import event as _obs_event, trace as _obs_trace
-from ..core.binning import (bin_particles, image_positions, max_displacement,
-                            pack_rows, padded_row_counts, pencil_counts,
-                            refresh_bins, subbox_counts)
+from ..core.binning import (bin_particles, cell_counts, image_positions,
+                            max_displacement, padded_row_counts,
+                            pencil_counts, refresh_bins, subbox_counts)
 from ..core.domain import Domain, effective_skin, skin_domain
 from ..physics.integrators import MDState
 from ..testing import chaos
@@ -94,12 +95,6 @@ INTEGRATORS = ("velocity_verlet", "leapfrog", "langevin")
 # grid stays modest in the paper's few-particles-per-cell regime, large
 # enough that a cold LJ/SPH system drifts for tens of steps before a rebin.
 DEFAULT_SKIN_FRACTION = 0.25
-
-_ALIGN = 8
-
-
-def _round_up(n: int, align: int = _ALIGN) -> int:
-    return -(-int(n) // align) * align
 
 
 @jax.tree_util.register_dataclass
@@ -158,6 +153,12 @@ def _check_supported(p: InteractionPlan) -> None:
             "the per-call Z-slab re-partition is exactly the cost the "
             "skin contract amortizes away (single-shard halo plans fall "
             "back to their inner backend and work fine)")
+    if p.layout == "sfc":
+        raise ValueError(
+            'plan.trajectory does not run layout="sfc" plans yet: the '
+            "segment monitors keep no pair-list length, so a pair_cap "
+            "outgrown mid-run would go unseen; use the dense or packed "
+            "layout")
 
 
 def trajectory_plan(base: InteractionPlan, skin: float,
@@ -182,59 +183,21 @@ def trajectory_plan(base: InteractionPlan, skin: float,
                     else min(base.max_active,
                              api.n_units(dom, base.strategy))))
     if positions is not None:
-        state = ParticleState(positions, valid=valid)
-        while grown.check_overflow(state):
-            grown = grown.replan(state)
+        counts = cell_counts(dom, positions, valid)
+        grown = bounds.grow(grown, bounds.measure(grown, counts), counts)
     return grown
 
 
 def _volume_scaled(bound: int, old: Domain, new: Domain) -> int:
     ratio = (float(np.prod(np.asarray(new.cell_width)))
              / max(float(np.prod(np.asarray(old.cell_width))), 1e-30))
-    return _round_up(max(1, int(np.ceil(bound * max(ratio, 1.0)))))
-
-
-def _grow_bounds(p: InteractionPlan, cell_max: int, row_max: int,
-                 units: int) -> InteractionPlan:
-    """Observed-maxima flavor of the replan contract (see
-    ``InteractionPlan.replan`` for the canonical statement): grow only the
-    bound the monitors saw exceeded, with slack, aligned, strictly past
-    the old value. Used between segments — the scan itself cannot change
-    static shapes."""
-    q = p
-    if cell_max > p.m_c:
-        measured = _round_up(max(1, int(cell_max * 1.5 + 0.999)))
-        q = dataclasses.replace(q, m_c=max(measured, _round_up(p.m_c + 1)),
-                                box=None)
-    if p.layout == "packed" and row_max > (p.row_cap or 0):
-        measured = _round_up(max(1, int(row_max * 1.25 + 0.999)))
-        q = dataclasses.replace(
-            q, row_cap=max(measured, _round_up((p.row_cap or 0) + 1)))
-    if p.compact and units > (p.max_active or 0):
-        total = api.n_units(p.domain, p.strategy, box=q.box)
-        measured = _round_up(max(1, int(units * 1.25 + 0.999)))
-        grown = max(measured, _round_up((p.max_active or 0) + 1))
-        q = dataclasses.replace(q, max_active=min(grown, total))
-    return q
+    return bounds.align_up(max(1, int(np.ceil(bound * max(ratio, 1.0)))))
 
 
 # --------------------------------------------------------------------------
-# traced pieces: forces against given bins, integrators, fused segment
+# traced pieces: integrators, fused segment (forces: api._pair_forces on
+# the bins the scan carries, where plan.execute re-bins every call)
 # --------------------------------------------------------------------------
-
-
-def _forces(p: InteractionPlan, bins, positions: Array,
-            fields: Dict[str, Array], valid: Optional[Array]
-            ) -> Tuple[Array, Array]:
-    """Backend dispatch against *given* bins — the one divergence from
-    ``api._impl``, which always re-bins from the positions."""
-    backend = p.halo_inner if p.backend == "halo" else p.backend
-    state = ParticleState(positions, fields, valid)
-    if p.layout == "packed":
-        packed = pack_rows(p.domain, bins, row_cap=p.row_cap)
-        return api.get_backend(backend, p.strategy, "packed")(p, packed,
-                                                              state)
-    return api.get_backend(backend, p.strategy)(p, bins, state)
 
 
 def _wrap(domain: Domain, positions: Array) -> Array:
@@ -349,7 +312,8 @@ def _segment_exec(p: InteractionPlan, integrator: str, seg_len: int,
             # positions as the (possibly stale) bins see them: the image
             # nearest the binned reference — exactly ``pos`` after a rebin
             img = image_positions(dom, pos, ref)
-            forces, pot = _forces(p, bins, img, fields, valid)
+            forces, pot = api._pair_forces(
+                p, bins, ParticleState(img, fields, valid))
             with jax.named_scope("integrate"):
                 vel = _integ_kick(integrator, mass, v_staged, forces, dt)
 
@@ -399,7 +363,8 @@ def _init_exec(p: InteractionPlan, mass: float,
         if has_forces:
             forces, pot = forces0, pot0
         else:
-            forces, pot = _forces(p, bins, positions, fields, valid)
+            forces, pot = api._pair_forces(
+                p, bins, ParticleState(positions, fields, valid))
         md = MDState(positions, velocities, forces, pot, step0)
         ke, pe = _masked_energies(velocities, pot, valid, mass)
         return TrajCarry(md=md, bins=bins, ref=positions, rng=rng,
@@ -540,7 +505,7 @@ def run_trajectory(base: InteractionPlan, state, n_steps: int, dt: float, *,
     seg = max(1, int(segment_len))
     ck_every = None
     if checkpoint_dir is not None:
-        ck_every = _round_up(checkpoint_every or 4 * seg, seg)
+        ck_every = bounds.align_up(checkpoint_every or 4 * seg, seg)
         checkpoint_dir = pathlib.Path(checkpoint_dir)
 
     result = TrajectoryResult(state=None, traces={}, plan=p,
@@ -668,9 +633,9 @@ def run_trajectory(base: InteractionPlan, state, n_steps: int, dt: float, *,
 
         # ---- overflow? grow bounds, roll back, replay --------------------
         forced = chaos.forced_overflow("traj.rebin")
-        grown = _grow_bounds(p, int(mon_cur.max_cell_count),
-                             int(mon_cur.max_row_count),
-                             int(mon_cur.max_active_units))
+        grown = bounds.grow(p, {"m_c": int(mon_cur.max_cell_count),
+                                "row_cap": int(mon_cur.max_row_count),
+                                "max_active": int(mon_cur.max_active_units)})
         if grown != p or forced:
             if grown == p:
                 # injected verdict with nothing to grow: record, move on

@@ -191,6 +191,23 @@ def test_distribute_builds_halo_twin():
     assert grown.max_active == d.max_active
 
 
+def test_halo_replan_measures_what_the_check_measures():
+    """The overflow check and replan read one per-cell count, with padding
+    rows (``valid`` False) left out of both: a breached shard_cap grows to
+    cover the real rows, not the padding piled into shard 0."""
+    dom = Domain.cubic(4, cutoff=1.0, periodic=True)
+    pos = dom.sample_uniform(jax.random.PRNGKey(0), 300)
+    state = ParticleState(jnp.concatenate([pos, jnp.full((200, 3), 0.5)]),
+                          valid=jnp.arange(500) < 300)
+    p = plan(dom, make_lennard_jones(), positions=pos, strategy="xpencil",
+             backend="halo", n_shards=2)
+    tight = dataclasses.replace(p, shard_cap=8)
+    assert tight.overflow_class(state) == "shard_cap"
+    grown = tight.replan(state)
+    assert grown.shard_cap == H.suggest_shard_cap(dom, pos, 2) == p.shard_cap
+    assert not grown.check_overflow(state)
+
+
 def test_autotune_halo_twins_enumeration():
     from repro.core.autotune import Candidate, halo_twins, prune_candidates
     dom = Domain.cubic(8, cutoff=1.0)

@@ -208,6 +208,23 @@ def test_pair_cap_overflow_detected_and_replanned():
     assert not np.array_equal(np.asarray(f_bad), np.asarray(f_d))
 
 
+def test_pair_cap_growth_stops_at_the_all_pairs_total():
+    """A full pair list on a 3^3 periodic grid: 7 clusters, all 189 pairs
+    occupied. Growing a pair_cap just short of it lands on the all-pairs
+    total ``n_clusters * 27``, the ceiling of the bound, and not on the
+    next multiple of 8 past the old value (192)."""
+    dom = Domain.cubic(3, cutoff=1.0, periodic=True)
+    pos = dom.sample_uniform(jax.random.PRNGKey(0), 200)
+    state = ParticleState(pos)
+    total = sfc_n_clusters(dom) * 27
+    assert sfc_pair_count(dom, pos) == total == 189       # premise
+    p = plan(dom, KERN, positions=pos, strategy="cell_dense", layout="sfc")
+    assert p.pair_cap == total
+    grown = dataclasses.replace(p, pair_cap=184).replan(state)
+    assert grown.pair_cap == total
+    assert not grown.check_overflow(state)
+
+
 def test_empty_clusters_cost_no_pairs():
     """Everything in one cell: exactly one cluster holds particles, the
     pair list stays tiny, and the schedule is still bit-identical."""

@@ -124,6 +124,57 @@ def test_langevin_gamma0_matches_verlet(setup):
 
 
 # ---------------------------------------------------------------------------
+# the plans a trajectory takes, and how their bounds grow
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("entry", ["trajectory", "trajectory_plan",
+                                   "traj_plan"])
+def test_trajectory_refuses_an_sfc_plan(setup, entry):
+    """The segment monitors keep no pair-list length, so a pair_cap
+    outgrown mid-run would go unseen: every way into the trajectory
+    refuses ``layout="sfc"`` by name instead of running it."""
+    dom, pos, vel, kern, p = setup
+    sfc = api.plan(dom, kern, positions=pos, strategy="cell_dense",
+                   layout="sfc")
+    with pytest.raises(ValueError, match='layout="sfc"'):
+        if entry == "trajectory":
+            sfc.trajectory(ParticleState(pos), 8, DT)
+        elif entry == "trajectory_plan":
+            trajectory_plan(sfc, 0.25, pos)
+        else:
+            run_trajectory(p, pos, 8, DT, traj_plan=sfc)
+
+
+def test_trajectory_plan_sizes_without_counting_a_replan(setup):
+    """Measuring the skin plan's bounds from positions is planning, not a
+    replan of the base plan: the bounds cover the positions and the
+    replan counter does not move."""
+    dom, pos, vel, kern, p = setup
+    blob = (0.45 * jax.random.normal(jax.random.PRNGKey(2), (200, 3),
+                                     jnp.float32) + 2.25) % 6.0
+    before = api.replan_count()
+    sparse = trajectory_plan(p, 0.25, pos)
+    tp = trajectory_plan(p, 0.25, blob)
+    assert tp.m_c > sparse.m_c
+    assert not tp.check_overflow(ParticleState(blob))
+    assert api.replan_count() == before
+
+
+def test_segment_growth_sizes_max_active_on_the_plans_tiling():
+    """Between segments the monitors report maxima only. When m_c and
+    max_active of a compacted allin plan grow together, max_active is
+    sized on the plan's own tiling: 12 sub-boxes of 6x6x4 on a 12^3 grid
+    cover the 11 seen active (the m_c=1 tiling has 8)."""
+    from repro.core import bounds
+    dom = Domain.cubic(12, cutoff=1.0)
+    p = api.plan(dom, make_lennard_jones(), m_c=1056, strategy="allin",
+                 compact=True, max_active=8)
+    assert p.box == (6, 6, 4) and api.n_units(dom, "allin") == 8
+    grown = bounds.grow(p, {"m_c": 1100, "max_active": 11})
+    assert grown.m_c > 1100 and grown.max_active == 12
+
+
+# ---------------------------------------------------------------------------
 # checkpoint / resume
 # ---------------------------------------------------------------------------
 
