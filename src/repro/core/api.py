@@ -398,6 +398,20 @@ class InteractionPlan:
         _plan_counter(BOND_FAULT_TOTAL, self).inc(n)
         return n
 
+    def slot_pair_share(self, state: ParticleState) -> float:
+        """The share of the dense ``3*m_c x m_c`` slot pairs of a grid
+        step that the X-pencil kernel, bounded by each pencil's highest
+        occupied slot, evaluates on ``state``'s dense bins, averaged over
+        the grid steps (``kernels.xpencil.slot_pair_share``); 1 at full
+        occupancy. Host sync; set in the gauge
+        ``repro_xpencil_slot_pair_share``."""
+        from ..kernels.xpencil import slot_pair_share
+        bins = bin_particles(self.domain, state.positions, m_c=self.m_c,
+                             valid=state.valid)
+        share = float(slot_pair_share(bins.slot_id, self.m_c))
+        _plan_counter(SLOT_PAIR_SHARE, self, _obs_metrics.Gauge).set(share)
+        return share
+
     # -- M_C safety net ----------------------------------------------------
 
     def check_overflow(self, state: ParticleState) -> bool:
@@ -896,21 +910,24 @@ RECOMPILE_TOTAL = "repro_recompile_total"
 REPLAN_TOTAL = "repro_replan_total"
 BONDS_TOTAL = "repro_bonds_total"
 BOND_FAULT_TOTAL = "repro_bond_faults_total"
+SLOT_PAIR_SHARE = "repro_xpencil_slot_pair_share"       # a gauge
 
-# live Counter instances keyed by (name, backend, strategy, layout) — a
+# live counters and gauges keyed by (name, backend, strategy, layout) — a
 # registry ``reset()`` zeroes them in place, so the cache never goes stale
-_metric_cache: Dict[tuple, _obs_metrics.Counter] = {}
+_metric_cache: Dict[tuple, object] = {}
 
 
-def _plan_counter(name: str,
-                  p: Optional["InteractionPlan"]) -> _obs_metrics.Counter:
+def _plan_counter(name: str, p: Optional["InteractionPlan"],
+                  kind: type = _obs_metrics.Counter):
     key = (name,) if p is None else (name, p.backend, p.strategy, p.layout)
     c = _metric_cache.get(key)
     if c is None:
         labels = ({} if p is None else
                   {"backend": p.backend, "strategy": p.strategy,
                    "layout": p.layout})
-        c = _metric_cache[key] = _obs_metrics.registry.counter(name, **labels)
+        make = (_obs_metrics.registry.gauge if kind is _obs_metrics.Gauge
+                else _obs_metrics.registry.counter)
+        c = _metric_cache[key] = make(name, **labels)
     return c
 
 

@@ -39,7 +39,8 @@ from .ops import (allin_interactions, cell_sfc_interactions, prefix_sum,
                   window_attention, xpencil_interactions,
                   xpencil_packed_interactions, xpencil_sparse_interactions)
 from .sfc import sfc_budget
-from .xpencil import packed_vmem_bytes, xpencil_vmem_bytes
+from .xpencil import (packed_vmem_bytes, xpencil_smem_bytes,
+                      xpencil_vmem_bytes)
 
 __all__ = ["allin_interactions", "cell_sfc_interactions", "prefix_sum",
            "window_attention", "xpencil_interactions",
@@ -62,9 +63,10 @@ def _fits(estimate: Callable[[InteractionPlan, Domain], Budget]):
 
 def _xpencil_budget(plan: InteractionPlan, dom: Domain) -> Budget:
     vmem = xpencil_vmem_bytes(dom.nx, plan.m_c)
+    smem = xpencil_smem_bytes(dom.ny, dom.nz)
     if plan.compact:
-        return "xpencil_compact", vmem, 4 * plan.max_active
-    return "xpencil", vmem, 0
+        return "xpencil_compact", vmem, smem + 4 * plan.max_active
+    return "xpencil", vmem, smem
 
 
 def _allin_budget(plan: InteractionPlan, dom: Domain) -> Budget:

@@ -78,7 +78,7 @@ def _kernel(xp, yp, zp, ip,             # HBM-resident X-blocked planes
         acc = tuple(jnp.zeros_like(t) for t in tgt)
         for dz in (-1, 0, 1):
             for dy in (-1, 0, 1):
-                src = tuple(s[z + dz, y + dy] for s in staged)
+                src = tuple(s.at[z + dz, y + dy] for s in staged)
                 terms = window_terms(tgt, src, m_c=m_c, kernel=kernel,
                                      cutoff2=cutoff2)
                 acc = tuple(a + t for a, t in zip(acc, terms))

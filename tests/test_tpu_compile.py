@@ -71,20 +71,35 @@ def _dense_planes(spec):
     return {f: spec(shape) for f in "xyz"}, spec(shape, jnp.int32)
 
 
+def _kernel_operands(compiled) -> str:
+    """The operand list of the program's Pallas kernel call."""
+    [call] = [line for line in compiled.as_text().splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line]
+    return call
+
+
+# the X-pencil kernels' scalar-prefetched slot-bound table, one int32 per
+# padded pencil
+BOUND_TABLE = f"s32[{(DIVISION + 2) ** 2}]"
+
+
 def test_xpencil_dense_compiles(spec):
     from repro.kernels.xpencil import xpencil_forces
     planes, ids = _dense_planes(spec)
-    _compile(lambda p, s: xpencil_forces(
+    compiled = _compile(lambda p, s: xpencil_forces(
         p, s, nx=DOM.nx, m_c=M_C, kernel=KERN, cutoff2=1.0,
         interpret=False), planes, ids)
+    assert BOUND_TABLE in _kernel_operands(compiled)
 
 
 def test_xpencil_compact_compiles(spec):
     from repro.kernels.xpencil import xpencil_sparse_forces
     planes, ids = _dense_planes(spec)
-    _compile(lambda p, s, a: xpencil_sparse_forces(
+    compiled = _compile(lambda p, s, a: xpencil_sparse_forces(
         p, s, a, nx=DOM.nx, ny=DOM.ny, m_c=M_C, kernel=KERN, cutoff2=1.0,
         interpret=False), planes, ids, spec((DOM.ny * DOM.nz,), jnp.int32))
+    call = _kernel_operands(compiled)
+    assert BOUND_TABLE in call and f"s32[{DOM.ny * DOM.nz}]" in call
 
 
 def test_xpencil_packed_compiles(spec):
@@ -156,6 +171,18 @@ def test_smoke_plans_fit_their_budgets():
         p = plan(DOM, KERN, m_c=M_C, backend="pallas", **kw)
         name, vmem, smem = kernel_budget(p)
         assert 0 < vmem < 100 * 2 ** 20 and smem < 512 * 2 ** 10, name
+
+
+def test_xpencil_budget_counts_the_bound_table():
+    """The slot-bound table sits in SMEM beside the compacted kernel's
+    active list: the budget ``plan()`` checks counts both."""
+    from repro.core import kernel_budget
+    table = 4 * (DIVISION + 2) ** 2
+    dense = plan(DOM, KERN, m_c=M_C, strategy="xpencil", backend="pallas")
+    assert kernel_budget(dense)[2] == table
+    compact = plan(DOM, KERN, m_c=M_C, strategy="xpencil", backend="pallas",
+                   compact=True, max_active=DIVISION ** 2)
+    assert kernel_budget(compact)[2] == table + 4 * DIVISION ** 2
 
 
 @pytest.mark.parametrize("n", [64, 1000, 4096])
