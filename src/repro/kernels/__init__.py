@@ -39,8 +39,7 @@ from .ops import (allin_interactions, cell_sfc_interactions, prefix_sum,
                   window_attention, xpencil_interactions,
                   xpencil_packed_interactions, xpencil_sparse_interactions)
 from .sfc import sfc_budget
-from .xpencil import (packed_vmem_bytes, xpencil_smem_bytes,
-                      xpencil_vmem_bytes)
+from .xpencil import packed_vmem_bytes, xpencil_budget
 
 __all__ = ["allin_interactions", "cell_sfc_interactions", "prefix_sum",
            "window_attention", "xpencil_interactions",
@@ -62,8 +61,7 @@ def _fits(estimate: Callable[[InteractionPlan, Domain], Budget]):
 
 
 def _xpencil_budget(plan: InteractionPlan, dom: Domain) -> Budget:
-    vmem = xpencil_vmem_bytes(dom.nx, plan.m_c)
-    smem = xpencil_smem_bytes(dom.ny, dom.nz)
+    vmem, smem = xpencil_budget(dom.nx, dom.ny, dom.nz, plan.m_c)
     if plan.compact:
         return "xpencil_compact", vmem, smem + 4 * plan.max_active
     return "xpencil", vmem, smem

@@ -18,14 +18,14 @@ untiled axis: every pair term is an elementwise op on whole vregs and the
 window reduction is a sum over that leading axis. On the CPU (interpret
 mode) XLA reduces a length-``3*m_c`` axis in the same order whichever
 axis it is, so these kernels stay bit-identical to the reference
-schedules. The X-pencil kernel bounds its window by occupancy and sums it
-in a loop over the occupied source rows; in interpret mode that loop
-keeps the order in which XLA's CPU backend reduces the whole window
-(``window_segments``), so it stays bit-identical too.
+schedules. Natively, the X-pencil kernel bounds its window by occupancy
+and sums it in a loop over the occupied 8-row source tiles
+(``window_terms`` given ``n_src`` / ``n_tgt``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -43,12 +43,9 @@ SUBLANES = 8
 # Scoped VMEM the kernels may ask Mosaic for. A v5e TensorCore has
 # 128 MiB of VMEM; the rest is left to Mosaic's own spills and buffers.
 VMEM_LIMIT_BYTES = 100 * 2 ** 20
-# Scalar memory holding the scalar-prefetched tables (1 MiB on v5e;
-# the compiler keeps some of it for its own use).
+# Scalar memory holding the scalar-prefetched tables and SMEM inputs
+# (1 MiB on v5e; the compiler keeps some of it for its own use).
 SMEM_LIMIT_BYTES = 512 * 2 ** 10
-# XLA's CPU backend sums a reduction longer than this in chunks of this
-# width (``window_segments``).
-WINDOW_CHUNK = 32
 
 
 def round_up(n: int, m: int) -> int:
@@ -112,8 +109,7 @@ def pair_terms(tx, ty, tz, tid, sx, sy, sz, sid, *, kernel: PairKernel,
 
 
 def window_terms(tgt: Tuple[Array, ...], src: Tuple, *, m_c: int,
-                 kernel: PairKernel, cutoff2: float, n_src=None, n_tgt=None,
-                 cpu_order: bool = False):
+                 kernel: PairKernel, cutoff2: float, n_src=None, n_tgt=None):
     """One staged source row against a row of target cells.
 
     ``tgt`` = (x, y, z, id) ``(m_c, L)`` tiles whose lane ``x`` holds
@@ -125,21 +121,13 @@ def window_terms(tgt: Tuple[Array, ...], src: Tuple, *, m_c: int,
 
     ``n_src`` / ``n_tgt`` bound the window by occupancy: no slot at or
     above ``n_src`` holds a source particle, none at or above ``n_tgt`` a
-    target. Left at their default (the full ``m_c``), the whole
-    ``(3*m_c, m_c, L)`` window is one static block. Given, each 8-row
-    target tile below ``n_tgt`` loops over the ``n_src`` occupied source
-    rows of each window cell, one ``(8, L)`` pair tile per row; natively
-    (``m_c`` a multiple of 8) it takes the rows as whole 8-row tiles,
-    rounding ``n_src`` up to 8, which the chip runs faster; a tile at
-    or above ``n_tgt`` reads 0. The skipped pairs are exact zeros, so the
-    sums are those of the full window.
-
-    ``cpu_order`` (interpret mode) sums the bounded window as XLA's CPU
-    backend sums the full one, so that the result is bit-identical: in
-    its chunks (``window_segments``), and, where the window is chunked,
-    with each term rounded before it is added (XLA materialises the terms
-    of a chunked reduction; it fuses an unchunked one into a
-    multiply-add).
+    target. Left out, the whole ``(3*m_c, m_c, L)`` window is one static
+    block. Given (``m_c`` a multiple of 8), each 8-row target tile below
+    ``n_tgt`` loops over the source rows of each window cell in whole
+    8-row tiles, ``ceil(n_src / 8)`` of them, one ``(8, L)`` pair tile
+    per row, summed in window order; a target tile at or above ``n_tgt``
+    reads 0. The skipped pairs are exact zeros, so the sums are those of
+    the full window up to the order of the float32 additions.
     """
     if n_src is None and n_tgt is None:
         lanes = tgt[0].shape[-1]
@@ -155,76 +143,30 @@ def window_terms(tgt: Tuple[Array, ...], src: Tuple, *, m_c: int,
                          kernel=kernel, cutoff2=cutoff2, axis=0)
         return tuple(o[0] for o in out)
 
-    n_src = m_c if n_src is None else n_src
-    n_tgt = m_c if n_tgt is None else n_tgt
-    segments = window_segments(m_c, cpu_order)
-    round_terms = cpu_order and 3 * m_c > WINDOW_CHUNK
+    assert m_c % SUBLANES == 0, m_c
+    src_tiles = (n_src + SUBLANES - 1) // SUBLANES
+
+    def one_tile(i, acc, t, dc):      # 8 source rows of window cell dc
+        base = pl.multiple_of(i * SUBLANES, SUBLANES)
+        blk = tuple(shift_lanes(r[pl.ds(base, SUBLANES), :], dc)
+                    for r in src)
+        for rr in range(SUBLANES):
+            terms = pair_values(*t, *(b[rr:rr + 1] for b in blk),
+                                kernel=kernel, cutoff2=cutoff2)
+            acc = tuple(a + v for a, v in zip(acc, terms))
+        return acc
+
     tiles = []
     for t0 in range(0, m_c, SUBLANES):
         t = tuple(v[t0:t0 + SUBLANES] for v in tgt)
-        rows = jnp.where(t0 < n_tgt, n_src, 0)
-        parts = []
-        for dc, lo, hi, new_part in segments:
-            if new_part:
-                parts.append(tuple(jnp.zeros(t[0].shape, tgt[0].dtype)
-                                   for _ in range(4)))
-
-            def one_row(j, acc, t=t, dc=dc):
-                s = tuple(shift_lanes(r[pl.ds(j, 1), :], dc) for r in src)
-                terms = pair_values(*t, *s, kernel=kernel, cutoff2=cutoff2)
-                if round_terms:          # a select keeps the add unfused
-                    keep = s[3] >= 0         # masked terms are 0 anyway
-                    terms = tuple(jnp.where(keep, v, 0.0) for v in terms)
-                return tuple(a + v for a, v in zip(acc, terms))
-            def one_tile(i, acc, t=t, dc=dc):
-                base = pl.multiple_of(i * SUBLANES, SUBLANES)
-                blk = tuple(shift_lanes(r[pl.ds(base, SUBLANES), :], dc)
-                            for r in src)
-                for rr in range(SUBLANES):
-                    s = tuple(b[rr:rr + 1] for b in blk)
-                    terms = pair_values(*t, *s, kernel=kernel,
-                                        cutoff2=cutoff2)
-                    acc = tuple(a + v for a, v in zip(acc, terms))
-                return acc
-            if cpu_order or m_c % SUBLANES:
-                parts[-1] = jax.lax.fori_loop(jnp.minimum(lo, rows),
-                                              jnp.minimum(hi, rows), one_row,
-                                              parts[-1])
-            else:                    # whole 8-row source tiles (faster)
-                parts[-1] = jax.lax.fori_loop(
-                    0, (rows + SUBLANES - 1) // SUBLANES, one_tile, parts[-1])
-        acc = parts[0]
-        for part in parts[1:]:
-            acc = tuple(a + b for a, b in zip(acc, part))
+        acc = tuple(jnp.zeros(t[0].shape, tgt[0].dtype) for _ in range(4))
+        n = jnp.where(t0 < n_tgt, src_tiles, 0)
+        for dc in range(3):
+            acc = jax.lax.fori_loop(
+                0, n, functools.partial(one_tile, t=t, dc=dc), acc)
         tiles.append(acc)
     return tuple(jnp.concatenate([a[i] for a in tiles], axis=0)
                  for i in range(4))
-
-
-def window_segments(m_c: int, cpu_order: bool):
-    """The bounded window's summation order: ``(dc, lo, hi, new_part)``
-    per run of source slots ``lo..hi-1`` of window cell ``dc``, where
-    ``new_part`` opens a new partial sum; the partials are then added in
-    order. Natively there is one partial, in window order. With
-    ``cpu_order`` the partials are the ``WINDOW_CHUNK``-wide chunks,
-    centred on the ``3*m_c`` window, that XLA's CPU backend cuts a longer
-    reduction into: summed so, the interpret-mode kernels stay
-    bit-identical to the reference schedules, which reduce the whole
-    window in one op."""
-    n = 3 * m_c
-    cuts = set()
-    if cpu_order:
-        assert n <= WINDOW_CHUNK ** 2, m_c   # one level of chunks
-        first = WINDOW_CHUNK - (round_up(n, WINDOW_CHUNK) - n) // 2
-        cuts = set(range(first, n, WINDOW_CHUNK))
-    segments = []
-    for dc in range(3):
-        edges = sorted({0, m_c} | {w - dc * m_c for w in cuts
-                                   if 0 < w - dc * m_c < m_c})
-        for lo, hi in zip(edges[:-1], edges[1:]):
-            new_part = not segments or dc * m_c + lo in cuts
-            segments.append((dc, lo, hi, new_part))
-    return segments
 
 
 def lane_gather(table_ref, idx: Array) -> Array:
@@ -269,5 +211,5 @@ def check_budget(name: str, vmem_bytes: int, smem_bytes: int = 0) -> None:
     if smem_bytes > SMEM_LIMIT_BYTES:
         raise ValueError(
             f"pallas {name} kernel needs {smem_bytes / 2 ** 10:.1f} KiB of "
-            f"scalar-prefetched tables; the limit is "
+            f"SMEM tables; the limit is "
             f"{SMEM_LIMIT_BYTES / 2 ** 10:.0f} KiB (TPU SMEM limit)")

@@ -20,25 +20,30 @@ Schedule (mirrors Algorithm 5, adapted per DESIGN.md §2):
   staged source tile (the dense slot layout makes the window contiguous —
   the paper needs its local-offset prefix sum for this).
 
-  Each step's window is bounded by occupancy. ``slot_bounds`` gives every
-  padded pencil its highest occupied slot plus one, a ``(nz+2, ny+2)``
-  table that is scalar-prefetched into SMEM. A step evaluates only the
-  ``n_src`` occupied source rows of each lane rotation, 8-row tile by
-  8-row tile in a ``fori_loop``, against only the target's 8-row tiles below
-  ``n_tgt`` (``_lanes.window_terms``): ``3 * n_src`` x ``8*ceil(n_tgt/8)``
-  slot pairs in place of ``3*m_c x m_c``. The skipped pairs are exact
-  zeros, so no computed value changes.
+  Natively (``m_c`` a multiple of 8) each step's window is bounded by
+  occupancy. ``slot_bounds`` gives every padded pencil its highest
+  occupied slot plus one, a ``(nz+2, ny+2)`` table held whole in SMEM
+  (an ordinary SMEM input, not a scalar prefetch, so that ``vmap`` adds
+  a grid axis and does not fall back to a loop of kernel launches). A
+  step evaluates only the source rows below ``n_src`` of each lane
+  rotation, in whole 8-row tiles in a ``fori_loop``, against only the
+  target's 8-row tiles below ``n_tgt`` (``_lanes.window_terms``):
+  ``3 * 8*ceil(n_src/8)`` x ``8*ceil(n_tgt/8)`` slot pairs in place of
+  ``3*m_c x m_c``. The skipped pairs are exact zeros; only the order of
+  the float32 additions differs from the full window. Interpret mode
+  sums the static full window, bit-identical to the reference schedules.
 
 VMEM per step: 12 double-buffered (m_c, L) tiles, the shifted target tiles
-and the row loop's (8, L) pair temporaries (``xpencil_vmem_bytes``) — well
+and the tile loop's (8, L) pair temporaries (``xpencil_vmem_bytes``) — well
 under a MiB at m_c = 16, far under budget: exactly the paper's point that
 pencils, unlike sub-boxes, leave head-room. SMEM holds the bound table
-(``xpencil_smem_bytes``).
+(``xpencil_budget``); a grid of more than about 360 x 360 pencils in
+(y, z), whose table would not fit, sums the full window instead.
 
 ``xpencil_sparse_forces`` below is the occupancy-compacted variant: its grid
 runs over the *active* pencils only, with the active-index list
-scalar-prefetched beside the bound table so the BlockSpec index maps
-become data-dependent.
+scalar-prefetched so the BlockSpec index maps become data-dependent; the
+bound table is an SMEM input as in the dense kernel.
 ``xpencil_packed_forces`` is the packed-row (CSR) variant on top of that:
 each DMA moves ``row_cap`` packed slots plus a prefix-sum offset row
 instead of a dense ``(nx+2)*m_c`` row — bytes proportional to the
@@ -57,9 +62,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.binning import EMPTY_POS
 from ..core.interactions import PairKernel
-from ._lanes import (LANES, SUBLANES, compiler_params, from_lanes,
-                     lane_gather, lane_width, pair_terms, shift_lanes,
-                     tile_bytes, to_lanes, window_terms)
+from ._lanes import (LANES, SMEM_LIMIT_BYTES, SUBLANES, compiler_params,
+                     from_lanes, lane_gather, lane_width, pair_terms,
+                     shift_lanes, tile_bytes, to_lanes, window_terms)
 from ._platform import resolve_interpret
 
 Array = jnp.ndarray
@@ -82,72 +87,109 @@ def slot_bounds(slot_id: Array, m_c: int) -> Array:
     return jnp.max(jnp.where(slot_id >= 0, slot, 0), axis=-1)
 
 
+def _table_bytes(ny: int, nz: int) -> int:
+    """SMEM of the slot-bound table: one int32 row, one entry per padded
+    pencil, laid out in whole 128-lane tiles."""
+    return 4 * lane_width((ny + 2) * (nz + 2))
+
+
+def _bounded(ny: int, nz: int, m_c: int, interpret: bool) -> bool:
+    """Whether the grid steps bound their window by the slot-bound table:
+    natively, where a cell's slots are whole 8-row tiles and the table
+    fits SMEM; otherwise each step sums the static full window (in
+    interpret mode as the reference schedules do, to the bit)."""
+    return (not interpret and m_c % SUBLANES == 0
+            and _table_bytes(ny, nz) <= SMEM_LIMIT_BYTES)
+
+
 def _pencil_pairs(bound: Array, m_c: int) -> Array:
-    """Slot pairs each (target pencil, neighbour) grid step evaluates:
-    ``3 * n_src`` source rows (in 8-row tiles where ``m_c`` allows)
-    against the target's 8-row tiles below ``n_tgt``
-    (``_lanes.window_terms``, natively). ``(nz, ny, 9)``."""
+    """Slot pairs each (target pencil, neighbour) grid step evaluates
+    natively: ``3 * 8*ceil(n_src/8)`` source rows against the target's
+    8-row tiles below ``n_tgt`` (``_lanes.window_terms``), or the full
+    ``3*m_c x m_c`` where ``m_c`` is not whole tiles. ``(nz, ny, 9)``."""
     nzp, nyp = bound.shape
     nz, ny = nzp - 2, nyp - 2
-    n_tgt = bound[1:nz + 1, 1:ny + 1]
-    tgt_rows = jnp.minimum(-(-n_tgt // SUBLANES) * SUBLANES, m_c)
-    n_src = jnp.stack([bound[k // 3:k // 3 + nz, k % 3:k % 3 + ny]
+    if not _bounded(ny, nz, m_c, interpret=False):
+        return jnp.full((nz, ny, 9), 3 * m_c * m_c, jnp.int32)
+    tiles = -(-bound // SUBLANES) * SUBLANES
+    n_src = jnp.stack([tiles[k // 3:k // 3 + nz, k % 3:k % 3 + ny]
                        for k in range(9)], axis=-1)
-    if m_c % SUBLANES == 0:            # whole 8-row source tiles
-        n_src = -(-n_src // SUBLANES) * SUBLANES
-    return 3 * n_src * tgt_rows[..., None]
+    return 3 * n_src * tiles[1:nz + 1, 1:ny + 1, None]
 
 
 def slot_pair_share(slot_id: Array, m_c: int) -> Array:
     """The share of the dense ``3*m_c x m_c`` slot pairs per grid step
-    that the bounded kernel evaluates on this ``slot_id`` plane, averaged
-    over the dense grid's steps."""
+    that the kernel evaluates natively on this ``slot_id`` plane,
+    averaged over the dense grid's steps."""
     pairs = _pencil_pairs(slot_bounds(slot_id, m_c), m_c)
     return jnp.mean(pairs / (3 * m_c * m_c))
 
 
-def xpencil_vmem_bytes(nx: int, m_c: int) -> int:
+def xpencil_vmem_bytes(nx: int, m_c: int, bounded: bool) -> int:
     """VMEM one dense/compacted X-pencil grid step holds: 12 pipelined
-    (m_c, L) tiles, double-buffered, the shifted target tiles and ~24
-    live (8, L) pair temporaries of the row loop."""
+    (m_c, L) tiles, double-buffered, plus, bounded, the shifted target
+    tiles and ~24 live (8, L) pair temporaries of the tile loop, else ~16
+    (3*m_c, m_c, L) temporaries of the full window."""
     tile = tile_bytes(m_c, nx + 2)
-    return 2 * 12 * tile + 4 * tile + 24 * tile_bytes(SUBLANES, nx + 2)
+    if bounded:
+        return 2 * 12 * tile + 4 * tile + 24 * tile_bytes(SUBLANES, nx + 2)
+    return 2 * 12 * tile + 16 * 3 * m_c * tile
 
 
-def xpencil_smem_bytes(ny: int, nz: int) -> int:
-    """SMEM of the prefetched slot-bound table (``slot_bounds``)."""
-    return 4 * (ny + 2) * (nz + 2)
+def xpencil_budget(nx: int, ny: int, nz: int, m_c: int) -> Tuple[int, int]:
+    """(VMEM bytes per grid step, SMEM bytes of the slot-bound table) of
+    the dense/compacted X-pencil kernel natively."""
+    bounded = _bounded(ny, nz, m_c, interpret=False)
+    return (xpencil_vmem_bytes(nx, m_c, bounded),
+            _table_bytes(ny, nz) if bounded else 0)
 
 
 def _pencil_step(z, y, k, bound_ref, t_refs, s_refs, o_refs, *, nyp: int,
-                 m_c: int, kernel: PairKernel, cutoff2: float,
-                 cpu_order: bool):
+                 m_c: int, kernel: PairKernel, cutoff2: float):
     """Step ``k`` = (dz, dy) of interior target pencil (z, y): the staged
     target pencil x one source pencil, accumulated into the resident
-    output tiles. The two pencils' slot bounds (the flattened
-    ``bound_ref``, ``nyp`` pencils per z) bound the window. Shared by the
-    dense and compacted kernels so compaction cannot change a computed
-    value."""
+    output tiles. Given a ``bound_ref`` (the slot-bound table as one
+    row, ``nyp`` pencils per z), the two pencils' slot bounds bound the
+    window. Shared by the dense and compacted kernels so compaction
+    cannot change a computed value."""
 
     @pl.when(k == 0)
     def _init():
         for o in o_refs:
             o[...] = jnp.zeros_like(o)
 
-    n_src = bound_ref[(z + k // 3) * nyp + y + k % 3]
-    n_tgt = bound_ref[(z + 1) * nyp + y + 1]
+    bounds = {} if bound_ref is None else dict(
+        n_src=bound_ref[0, (z + k // 3) * nyp + y + k % 3],
+        n_tgt=bound_ref[0, (z + 1) * nyp + y + 1])
     tgt = tuple(shift_lanes(r[...], 1) for r in t_refs)   # lane x = cell x
     terms = window_terms(tgt, s_refs, m_c=m_c, kernel=kernel,
-                         cutoff2=cutoff2, n_src=n_src, n_tgt=n_tgt,
-                         cpu_order=cpu_order)
+                         cutoff2=cutoff2, **bounds)
     for o, v in zip(o_refs, terms):
         o[...] += v
 
 
-def _kernel(bound_ref, *refs, nyp: int, **params):
+def _split_bound(refs, bounded: bool):
+    """(the slot-bound table's ref or None, the plane refs)."""
+    return (refs[0], refs[1:]) if bounded else (None, refs)
+
+
+def _kernel(*refs, bounded: bool, nyp: int, **params):
+    bound_ref, refs = _split_bound(refs, bounded)
     _pencil_step(pl.program_id(0), pl.program_id(1), pl.program_id(2),
                  bound_ref, refs[0:4], refs[4:8], refs[8:12], nyp=nyp,
                  **params)
+
+
+def _bound_operand(slot_id: Array, m_c: int, bounded: bool):
+    """The slot-bound table, flattened to one ``(1, nzp*nyp)`` row, and
+    its whole-array SMEM spec, as leading operand lists (empty where the
+    window is not bounded). Under ``vmap`` the row is a block of a
+    ``(B, 1, nzp*nyp)`` array, whose last two dimensions Mosaic takes
+    whole."""
+    if not bounded:
+        return [], []
+    return ([slot_bounds(slot_id, m_c).reshape(1, -1)],
+            [pl.BlockSpec(memory_space=pltpu.SMEM)])
 
 
 @functools.partial(jax.jit, static_argnames=("nx", "m_c", "kernel", "cutoff2", "interpret"))
@@ -166,34 +208,39 @@ def xpencil_forces(planes: dict, slot_id: Array, *, nx: int, m_c: int,
       (fx, fy, fz, pot), each (nz, ny, nx*m_c) over interior slots.
     """
     interpret = resolve_interpret(interpret)
-    bound = slot_bounds(slot_id, m_c)
+    nzp, nyp = slot_id.shape[:2]
+    return _dense_call(planes, slot_id, nx=nx, m_c=m_c, kernel=kernel,
+                       cutoff2=cutoff2, interpret=interpret,
+                       bounded=_bounded(nyp - 2, nzp - 2, m_c, interpret))
+
+
+def _dense_call(planes, slot_id, *, nx, m_c, kernel, cutoff2, interpret,
+                bounded):
+    """:func:`xpencil_forces` with the window's bounding given."""
+    bound, bound_spec = _bound_operand(slot_id, m_c, bounded)
     lanes = _lane_planes(planes, slot_id, m_c)
     nzp, nyp, _, width = lanes[0].shape
     nz, ny = nzp - 2, nyp - 2
     tile = (None, None, m_c, width)
-    row_block = pl.BlockSpec(tile, lambda z, y, k, b: (z + 1, y + 1, 0, 0))
-    nbr_block = pl.BlockSpec(tile, lambda z, y, k, b: (z + k // 3,
-                                                       y + k % 3, 0, 0))
-    out_block = pl.BlockSpec(tile, lambda z, y, k, b: (z, y, 0, 0))
+    row_block = pl.BlockSpec(tile, lambda z, y, k: (z + 1, y + 1, 0, 0))
+    nbr_block = pl.BlockSpec(tile, lambda z, y, k: (z + k // 3, y + k % 3,
+                                                    0, 0))
+    out_block = pl.BlockSpec(tile, lambda z, y, k: (z, y, 0, 0))
     out_shape = jax.ShapeDtypeStruct((nz, ny, m_c, width), lanes[0].dtype)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nz, ny, 9),
-        in_specs=[row_block] * 4 + [nbr_block] * 4,
-        out_specs=[out_block] * 4,
-    )
-    body = functools.partial(_kernel, nyp=nyp, m_c=m_c, kernel=kernel,
-                             cutoff2=float(cutoff2), cpu_order=interpret)
+    body = functools.partial(_kernel, bounded=bounded, nyp=nyp, m_c=m_c,
+                             kernel=kernel, cutoff2=float(cutoff2))
     outs = pl.pallas_call(
         body,
-        grid_spec=grid_spec,
+        grid=(nz, ny, 9),
+        in_specs=bound_spec + [row_block] * 4 + [nbr_block] * 4,
+        out_specs=[out_block] * 4,
         out_shape=[out_shape] * 4,
         compiler_params=compiler_params(
             ("parallel", "parallel", "arbitrary"),
-            xpencil_vmem_bytes(nx, m_c)),
+            xpencil_vmem_bytes(nx, m_c, bounded)),
         interpret=interpret,
-    )(bound.reshape(-1), *lanes, *lanes)
+    )(*bound, *lanes, *lanes)
     return tuple(from_lanes(o, nx) for o in outs)
 
 
@@ -204,15 +251,15 @@ def xpencil_forces(planes: dict, slot_id: Array, *, nx: int, m_c: int,
 # The dense kernel's grid is (nz, ny, 9) — every pencil pays 10 row DMAs
 # whether or not it holds particles. Here the grid is (max_active, 9): the
 # active-pencil index list is *scalar-prefetched*
-# (``pltpu.PrefetchScalarGridSpec``) beside the slot-bound table, so the
-# BlockSpec index maps can read it before each step and DMA exactly the
+# (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index maps can read it before each step and DMA exactly the
 # rows of the a-th active pencil — data-dependent staging, the TPU
 # analogue of a compacted thread-block launch. Outputs are compact
 # (max_active, nx*m_c) rows that the caller scatters back into the dense
 # planes (padding rows recompute pencil 0 and are dropped by the scatter).
 
 
-def _sparse_kernel(act_ref, bound_ref, *refs, ny: int, **params):
+def _sparse_kernel(act_ref, *refs, bounded: bool, ny: int, **params):
+    bound_ref, refs = _split_bound(refs, bounded)
     zy = act_ref[pl.program_id(0)]
     _pencil_step(zy // ny, zy % ny, pl.program_id(1), bound_ref, refs[0:4],
                  refs[4:8], refs[8:12], nyp=ny + 2, **params)
@@ -237,40 +284,48 @@ def xpencil_sparse_forces(planes: dict, slot_id: Array, active_zy: Array, *,
       holds the interior forces of pencil ``active_zy[a]``.
     """
     interpret = resolve_interpret(interpret)
-    bound = slot_bounds(slot_id, m_c)
+    return _sparse_call(planes, slot_id, active_zy, nx=nx, ny=ny, m_c=m_c,
+                        kernel=kernel, cutoff2=cutoff2, interpret=interpret,
+                        bounded=_bounded(ny, slot_id.shape[0] - 2, m_c,
+                                         interpret))
+
+
+def _sparse_call(planes, slot_id, active_zy, *, nx, ny, m_c, kernel,
+                 cutoff2, interpret, bounded):
+    """:func:`xpencil_sparse_forces` with the window's bounding given."""
+    bound, bound_spec = _bound_operand(slot_id, m_c, bounded)
     lanes = _lane_planes(planes, slot_id, m_c)
     width = lanes[0].shape[-1]
     max_active = active_zy.shape[0]
 
-    def tgt_map(a, k, act, b):
+    def tgt_map(a, k, act):
         return (act[a] // ny + 1, act[a] % ny + 1, 0, 0)
 
-    def nbr_map(a, k, act, b):
+    def nbr_map(a, k, act):
         return (act[a] // ny + k // 3, act[a] % ny + k % 3, 0, 0)
 
     tile = (None, None, m_c, width)
-    out_block = pl.BlockSpec((None, m_c, width),
-                             lambda a, k, act, b: (a, 0, 0))
+    out_block = pl.BlockSpec((None, m_c, width), lambda a, k, act: (a, 0, 0))
     out_shape = jax.ShapeDtypeStruct((max_active, m_c, width),
                                      lanes[0].dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(max_active, 9),
-        in_specs=([pl.BlockSpec(tile, tgt_map)] * 4
+        in_specs=(bound_spec + [pl.BlockSpec(tile, tgt_map)] * 4
                   + [pl.BlockSpec(tile, nbr_map)] * 4),
         out_specs=[out_block] * 4,
     )
-    body = functools.partial(_sparse_kernel, ny=ny, m_c=m_c, kernel=kernel,
-                             cutoff2=float(cutoff2), cpu_order=interpret)
+    body = functools.partial(_sparse_kernel, bounded=bounded, ny=ny,
+                             m_c=m_c, kernel=kernel, cutoff2=float(cutoff2))
     outs = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[out_shape] * 4,
-        compiler_params=compiler_params(("parallel", "arbitrary"),
-                                        xpencil_vmem_bytes(nx, m_c)),
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary"), xpencil_vmem_bytes(nx, m_c, bounded)),
         interpret=interpret,
-    )(active_zy.astype(jnp.int32), bound.reshape(-1), *lanes, *lanes)
+    )(active_zy.astype(jnp.int32), *bound, *lanes, *lanes)
     return tuple(from_lanes(o, nx) for o in outs)
 
 

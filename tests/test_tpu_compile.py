@@ -78,9 +78,9 @@ def _kernel_operands(compiled) -> str:
     return call
 
 
-# the X-pencil kernels' scalar-prefetched slot-bound table, one int32 per
+# the X-pencil kernels' slot-bound table in SMEM: one row of int32, one per
 # padded pencil
-BOUND_TABLE = f"s32[{(DIVISION + 2) ** 2}]"
+BOUND_TABLE = f"s32[1,{(DIVISION + 2) ** 2}]"
 
 
 def test_xpencil_dense_compiles(spec):
@@ -100,6 +100,33 @@ def test_xpencil_compact_compiles(spec):
         interpret=False), planes, ids, spec((DOM.ny * DOM.nz,), jnp.int32))
     call = _kernel_operands(compiled)
     assert BOUND_TABLE in call and f"s32[{DOM.ny * DOM.nz}]" in call
+
+
+def test_xpencil_dense_full_window_compiles(spec):
+    """Where a cell's slots are not whole 8-row tiles the kernel sums the
+    static full window, with no bound table."""
+    from repro.kernels.xpencil import xpencil_forces
+    nx, ny, nz = DOM.ncells
+    shape = (nz + 2, ny + 2, (nx + 2) * 12)
+    compiled = _compile(lambda p, s: xpencil_forces(
+        p, s, nx=nx, m_c=12, kernel=KERN, cutoff2=1.0, interpret=False),
+        {f: spec(shape) for f in "xyz"}, spec(shape, jnp.int32))
+    assert BOUND_TABLE not in _kernel_operands(compiled)
+
+
+def test_xpencil_dense_batches_under_vmap(spec):
+    """``execute_batch`` vmaps the dense kernel: the bound table, an SMEM
+    input and not a scalar prefetch, lets ``vmap`` add a grid axis, so
+    the batch stays one kernel launch with no loop around it."""
+    from repro.kernels.xpencil import xpencil_forces
+    b, d = 4, 12
+    shape = (b, d + 2, d + 2, (d + 2) * M_C)
+    planes, ids = {f: spec(shape) for f in "xyz"}, spec(shape, jnp.int32)
+    compiled = _compile(jax.vmap(lambda p, s: xpencil_forces(
+        p, s, nx=d, m_c=M_C, kernel=KERN, cutoff2=1.0, interpret=False)),
+        planes, ids)
+    assert f"s32[{b},1,{(d + 2) ** 2}]" in _kernel_operands(compiled)
+    assert " while(" not in compiled.as_text()
 
 
 def test_xpencil_packed_compiles(spec):
@@ -177,7 +204,7 @@ def test_xpencil_budget_counts_the_bound_table():
     """The slot-bound table sits in SMEM beside the compacted kernel's
     active list: the budget ``plan()`` checks counts both."""
     from repro.core import kernel_budget
-    table = 4 * (DIVISION + 2) ** 2
+    table = 4 * 128 * -(-(DIVISION + 2) ** 2 // 128)     # whole lane tiles
     dense = plan(DOM, KERN, m_c=M_C, strategy="xpencil", backend="pallas")
     assert kernel_budget(dense)[2] == table
     compact = plan(DOM, KERN, m_c=M_C, strategy="xpencil", backend="pallas",
@@ -189,3 +216,21 @@ def test_xpencil_budget_counts_the_bound_table():
 def test_prefix_sum_compiles(spec, n):
     from repro.kernels.prefix_sum import prefix_sum
     _compile(lambda x: prefix_sum(x, interpret=False), spec((n,)))
+
+
+@pytest.mark.parametrize("ncells,m_c,bounded", [
+    ((DIVISION,) * 3, M_C, True),
+    ((DIVISION,) * 3, 12, False),      # slots not whole 8-row tiles
+    ((4, 400, 400), M_C, False)])      # the table would not fit SMEM
+def test_xpencil_window_bounded_where_the_table_fits(ncells, m_c, bounded):
+    """The budget ``plan()`` checks follows the kernel's path: the bound
+    table and the tile loop's VMEM where the window is bounded, the full
+    window's VMEM and no table elsewhere."""
+    from repro.core import kernel_budget
+    from repro.kernels.xpencil import xpencil_vmem_bytes
+    dom = Domain(box=tuple(float(n) for n in ncells), ncells=ncells,
+                 cutoff=1.0)
+    p = plan(dom, KERN, m_c=m_c, strategy="xpencil", backend="pallas")
+    _, vmem, smem = kernel_budget(p)
+    assert (smem > 0) == bounded
+    assert vmem == xpencil_vmem_bytes(ncells[0], m_c, bounded)

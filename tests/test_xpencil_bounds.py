@@ -2,9 +2,13 @@
 
 Each grid step evaluates only the source slots below its source pencil's
 highest occupied slot, against only the target's 8-row tiles below its
-own. These tests check the bound table against a count of the particles,
-that bounding changes no computed bit against the full window, and the
-``slot_pair_share`` counter that says how far the bound cuts the work.
+own. The bounded path runs natively only; interpret mode sums the static full
+window. These tests check the bound table against a count of the
+particles, run the bounded path in interpret mode against the full
+window (bit-equal to the same loop at full bounds, float32-close to the
+static window), check that ``vmap`` keeps the bounded kernel one launch,
+and check the ``slot_pair_share`` counter that says how far the bound
+cuts the work.
 """
 
 import functools
@@ -117,37 +121,74 @@ def _kernel_scene(name):
     raise KeyError(name)
 
 
-def _run(variant, dom, bins, m_c):
+def _run(variant, dom, bins, m_c, bounded):
+    """One kernel variant in interpret mode, its window bounded by the
+    slot-bound table (the native path) or the static full window."""
+    kw = dict(nx=dom.nx, m_c=m_c, kernel=KERN, cutoff2=1.0, interpret=True,
+              bounded=bounded)
     if variant == "dense":
-        return XP.xpencil_forces.__wrapped__(
-            bins.planes, bins.slot_id, nx=dom.nx, m_c=m_c, kernel=KERN,
-            cutoff2=1.0, interpret=True)
+        return XP._dense_call(bins.planes, bins.slot_id, **kw)
     occ = pencil_occupancy(dom, bins.counts, dom.ny * dom.nz)
-    return XP.xpencil_sparse_forces.__wrapped__(
-        bins.planes, bins.slot_id, occ.active, nx=dom.nx, ny=dom.ny,
-        m_c=m_c, kernel=KERN, cutoff2=1.0, interpret=True)
+    return XP._sparse_call(bins.planes, bins.slot_id, occ.active, ny=dom.ny,
+                           **kw)
 
 
 @pytest.mark.parametrize("scene", ["blob", "uniform", "source_fuller"])
 @pytest.mark.parametrize("variant", ["dense", "compact"])
-def test_bounded_kernel_bit_equal_to_full_window(variant, scene, monkeypatch):
+def test_bounded_kernel_matches_full_window(variant, scene, monkeypatch):
+    """The chip's path, run in interpret mode: bounded by the real table,
+    it gives the bits it gives with every bound forced to ``m_c`` (the
+    skipped pairs are exact zeros), and the static full window's sums to
+    float32 rounding (the tile loop adds in another order)."""
     dom, pos, m_c = _kernel_scene(scene)
     bins = bin_particles(dom, pos, m_c=m_c)
     bound = np.asarray(XP.slot_bounds(bins.slot_id, m_c))
     if scene == "source_fuller":      # a target pencil sparser than its source
         assert bound.max() == 12 and bound.min() == 1
-    got = _run(variant, dom, bins, m_c)
+    got = _run(variant, dom, bins, m_c, bounded=True)
     with monkeypatch.context() as m:  # every step at the full m_c
         m.setattr(XP, "slot_bounds",
                   lambda s, m_c: jnp.full(s.shape[:2], m_c, jnp.int32))
-        full = _run(variant, dom, bins, m_c)
-    for g, f in zip(got, full):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(f))
+        forced = _run(variant, dom, bins, m_c, bounded=True)
+    full = _run(variant, dom, bins, m_c, bounded=False)
+    for g, f, w in zip(got, forced, full):
+        g, w = np.asarray(g), np.asarray(w)
+        np.testing.assert_array_equal(g, np.asarray(f))
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-5 * np.abs(w).max())
+
+
+@pytest.mark.parametrize("scene", ["blob", "uniform", "source_fuller"])
+def test_bounded_kernel_batches_under_vmap(scene):
+    """``execute_batch`` vmaps the dense kernel. The bound table is an
+    SMEM input, not a scalar prefetch, so ``vmap`` adds a grid axis to
+    the one launch (no loop of launches), bit-equal to a loop."""
+    dom, pos, m_c = _kernel_scene(scene)
+    stack = jnp.stack([pos, pos[::-1] + 0.01])
+    bins = jax.vmap(lambda p: bin_particles(dom, p, m_c=m_c))(stack)
+
+    def run(planes, slot_id):
+        return XP._dense_call(planes, slot_id, nx=dom.nx, m_c=m_c,
+                              kernel=KERN, cutoff2=1.0, interpret=True,
+                              bounded=True)
+
+    [call] = [e for e in jax.make_jaxpr(jax.vmap(run))(
+        bins.planes, bins.slot_id).eqns if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (2, dom.nz, dom.ny, 9)
+    got = jax.vmap(run)(bins.planes, bins.slot_id)
+    for i in range(2):
+        one = run({k: v[i] for k, v in bins.planes.items()}, bins.slot_id[i])
+        for g, o in zip(got, one):
+            np.testing.assert_array_equal(np.asarray(g[i]), np.asarray(o))
 
 
 @pytest.mark.parametrize("per_cell,m_c,want", [(2, 16, 1 / 4),
-                                               (8, 8, 1.0)])
+                                               (8, 8, 1.0),
+                                               (2, 12, 1.0)])
 def test_slot_pair_share_and_gauge(per_cell, m_c, want):
+    """Whole 8-row tiles at 2 per cell: a quarter of m_c 16's pairs; all
+    at full occupancy, and all where m_c is not whole tiles (the static
+    full window)."""
     dom = Domain.cubic(3, periodic=True)
     pos = jnp.asarray(_grid_points(3, per_cell), jnp.float32)
     p = plan(dom, KERN, m_c=m_c, strategy="xpencil", backend="pallas",
@@ -160,12 +201,9 @@ def test_slot_pair_share_and_gauge(per_cell, m_c, want):
 
 @pytest.mark.parametrize("m_c,n_src,n_tgt", [(8, 3, 2), (16, 16, 16),
                                              (16, 5, 9), (40, 21, 17)])
-@pytest.mark.parametrize("cpu_order", [False, True])
-def test_window_terms_bounded_matches_full(m_c, n_src, n_tgt, cpu_order):
-    """The bounded window against the static full one, in both summation
-    orders: the native order (one partial, window order) runs only on the
-    chip, so its loops are checked here to a float32 tolerance; the CPU
-    order must give the same bits."""
+def test_window_terms_bounded_matches_full(m_c, n_src, n_tgt):
+    """The bounded window (the chip's tile loop) against the static full
+    one, to a float32 tolerance: the loop adds in another order."""
     lanes = 128
     rng = np.random.default_rng(m_c + n_src)
 
@@ -180,8 +218,8 @@ def test_window_terms_bounded_matches_full(m_c, n_src, n_tgt, cpu_order):
 
     def body(bounded, *refs):
         t = tuple(shift_lanes(r[...], 1) for r in refs[0:4])
-        kw = (dict(n_src=jnp.int32(n_src), n_tgt=jnp.int32(n_tgt),
-                   cpu_order=cpu_order) if bounded else {})
+        kw = (dict(n_src=jnp.int32(n_src), n_tgt=jnp.int32(n_tgt))
+              if bounded else {})
         for o, v in zip(refs[8:12], window_terms(
                 t, refs[4:8], m_c=m_c, kernel=KERN, cutoff2=1.0, **kw)):
             o[...] = v
@@ -193,8 +231,5 @@ def test_window_terms_bounded_matches_full(m_c, n_src, n_tgt, cpu_order):
     for f, g in zip(full, bounded):
         f, g = np.asarray(f), np.asarray(g)
         assert (g[n_tgt:] == 0).all() and (f[n_tgt:] == 0).all()
-        if cpu_order:
-            np.testing.assert_array_equal(g, f)
-        else:
-            np.testing.assert_allclose(g, f, rtol=1e-5,
-                                       atol=1e-5 * np.abs(f).max())
+        np.testing.assert_allclose(g, f, rtol=1e-5,
+                                   atol=1e-5 * np.abs(f).max())
